@@ -32,7 +32,7 @@ from typing import Optional
 
 from repro import configs
 from repro.configs.base import SHAPES
-from repro.core.hw import TPU_V5E
+from repro.core.hw import tpu_spec
 
 DRYRUN_DIR = "experiments/dryrun"
 
@@ -119,9 +119,10 @@ def analyse_cell(path: str) -> Optional[dict]:
     bytes_dev = hlo_bytes_dev * scale
     coll_dev = hlo_coll_dev * scale
 
-    t_c = exec_flops_dev / TPU_V5E.peak_flops
-    t_m = bytes_dev / TPU_V5E.hbm_bw
-    t_x = coll_dev / TPU_V5E.ici_bw
+    chip = tpu_spec(r["target_device_kind"])
+    t_c = exec_flops_dev / chip.peak_flops
+    t_m = bytes_dev / chip.hbm_bw
+    t_x = coll_dev / chip.ici_bw
     terms = {"compute": t_c, "memory": t_m, "collective": t_x}
     dominant = max(terms, key=terms.get)
     mf = model_flops(r["arch"], r["shape"])
@@ -136,9 +137,9 @@ def analyse_cell(path: str) -> Optional[dict]:
                                         else 4)
             + r["memory"]["argument_size_in_bytes"] * chips * 0.5
         ) / chips
-        t_ideal = ideal_bytes / TPU_V5E.hbm_bw
+        t_ideal = ideal_bytes / chip.hbm_bw
     else:
-        t_ideal = mf / chips / TPU_V5E.peak_flops
+        t_ideal = mf / chips / chip.peak_flops
     return {
         "arch": r["arch"],
         "shape": r["shape"],

@@ -26,10 +26,12 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import subprocess
 import sys
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import trace as obs_trace
 
 
@@ -80,10 +82,12 @@ def smoke() -> None:
     # surfacing as an unexplained slowdown in the timings below.  Run in
     # a subprocess: the sweep compiles ~16 models, and that much jit-cache
     # and heap in THIS process skews the marginal (~1.0-1.3x) timing
-    # gates below.
+    # gates below.  The child runs on the CPU: static verification needs
+    # no chip, and a chip belongs to one process (this one).
     gate = subprocess.run(
         [sys.executable, "-m", "repro.verify", "--sweep-only"],
         capture_output=True, text=True,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
     )
     if gate.returncode != 0:
         print("\n== static verification (repro.verify) ==")
@@ -277,7 +281,7 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="quick CI subset -> BENCH_smoke.json")
     args = ap.parse_args()
-
+    enable_compile_cache()
     if args.smoke:
         smoke()
         return

@@ -21,6 +21,7 @@ from repro import configs, obs
 from repro.configs.base import RunConfig
 from repro.core.analog import AnalogConfig
 from repro.distributed import sharding as shd
+from repro.launch.mesh import make_mesh
 from repro.models import transformer as T
 from repro.serve.engine import Request, ServeEngine
 
@@ -51,7 +52,7 @@ def main(argv=None):
     mesh_ctx = contextlib.nullcontext()
     if a.mesh:
         n = len(jax.devices())
-        mesh_ctx = shd.use_mesh(jax.make_mesh((n, 1), ("data", "model")))
+        mesh_ctx = shd.use_mesh(make_mesh((n, 1), ("data", "model")))
     rng = np.random.default_rng(0)
     reqs = [
         Request(uid=i,
@@ -67,9 +68,11 @@ def main(argv=None):
             done = engine.serve(reqs)
         dt = sp.dur_us / 1e6
     total_new = sum(len(r.output) for r in done)
+    dev = jax.devices()[0]
     print(f"arch={a.arch} mode={a.mode}: served {len(done)} requests, "
           f"{total_new} tokens in {dt:.1f}s "
-          f"({total_new / dt:.1f} tok/s on CPU emulation)")
+          f"({total_new / dt:.1f} tok/s on {len(jax.devices())}x "
+          f"{dev.platform} {dev.device_kind})")
     for r in done[:4]:
         print(f"  req {r.uid}: prompt[:6]={r.prompt[:6].tolist()} -> "
               f"out[:8]={r.output[:8].tolist()}")

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from repro.core import noise as noise_lib
 from repro.core.hw import BSS2
 from repro.core.noise import NoiseConfig
+from repro.core.quant import ANALOG_PRECISION
 
 
 def measure_readout(
@@ -74,7 +75,7 @@ def measure_readout(
     a_c = a_code.reshape(batch + (n_chunks, chunk_rows))
     w_c = w_eff.reshape(n_chunks, chunk_rows, n)
     v = jnp.einsum(
-        "...ck,ckn->...cn", a_c, w_c,
+        "...ck,ckn->...cn", a_c, w_c, precision=ANALOG_PRECISION,
         preferred_element_type=jnp.float32,
     ) * gain
     off = fpn.get("chunk_offset")

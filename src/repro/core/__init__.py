@@ -9,6 +9,6 @@ from repro.core.analog import (  # noqa: F401
     analog_matmul,
     calibrate,
 )
-from repro.core.hw import BSS2, TPU_V5E, BSS2Spec, TPUSpec  # noqa: F401
+from repro.core.hw import BSS2, BSS2Spec, TPUSpec, tpu_spec  # noqa: F401
 from repro.core.noise import NOISELESS, NoiseConfig  # noqa: F401
 from repro.core.partition import TileGrid, plan_model, plan_tiles  # noqa: F401

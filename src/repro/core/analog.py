@@ -115,6 +115,7 @@ def analog_matmul(
         # accumulated range (C * [-128, 127]).
         total = jnp.einsum(
             "...k,kn->...n", a_code, w_eff,
+            precision=quant.ANALOG_PRECISION,
             preferred_element_type=jnp.float32,
         )
         v = total * gain
@@ -154,7 +155,8 @@ def analog_matmul(
     a_c = a_code.reshape(batch_shape + (n_chunks, cfg.chunk_rows))
     w_c = w_eff.reshape(n_chunks, cfg.chunk_rows, n)
     v = jnp.einsum(
-        "...ck,ckn->...cn", a_c, w_c, preferred_element_type=jnp.float32
+        "...ck,ckn->...cn", a_c, w_c, precision=quant.ANALOG_PRECISION,
+        preferred_element_type=jnp.float32,
     )
     v = v * gain
     if chunk_offset is not None:
@@ -179,7 +181,8 @@ def _faithful_mm(a_code, w_eff, gain, chunk_offset, chunk_rows):
     def chunk_step(acc, inp):
         a_i, w_i, off_i = inp
         v = jnp.einsum(
-            "...k,kn->...n", a_i, w_i, preferred_element_type=jnp.float32
+            "...k,kn->...n", a_i, w_i, precision=quant.ANALOG_PRECISION,
+            preferred_element_type=jnp.float32,
         ) * gain + off_i
         return acc + quant.adc_readout(v), None
 

@@ -2,8 +2,9 @@
 
 All BSS-2 numbers are taken directly from the paper (Stradmann et al., 2022,
 IEEE OJCAS, DOI 10.1109/OJCAS.2022.3208413): Section II-A, Eqs. (1)-(3) and
-Table 1.  The TPU numbers are the v5e constants prescribed by the roofline
-spec (197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI).
+Table 1.  The TPU peaks are kept per chip in :data:`TPU_SPECS`, keyed by
+the ``device_kind`` JAX reports; :func:`tpu_spec` refuses a kind that is
+not in the table instead of lending it another chip's peaks.
 """
 from __future__ import annotations
 
@@ -81,15 +82,35 @@ class BSS2Spec:
 
 @dataclasses.dataclass(frozen=True)
 class TPUSpec:
-    """Roofline constants for one TPU v5e chip (target hardware)."""
+    """Roofline constants for one TPU chip."""
 
-    peak_flops: float = 197e12     # bf16
-    hbm_bw: float = 819e9          # bytes/s
-    ici_bw: float = 50e9           # bytes/s per link
-    hbm_bytes: float = 16e9        # capacity
+    peak_flops: float              # bf16 FLOP/s
+    hbm_bw: float                  # bytes/s
+    ici_bw: float                  # bytes/s per link
+    hbm_bytes: float               # capacity
     vmem_bytes: float = 64 * 2**20   # conservative VMEM working-set budget
     mxu_dim: int = 128
 
 
+# Published per-chip peaks (Google Cloud documentation, "TPU v5e"), keyed
+# by ``jax.devices()[0].device_kind``.
+V5E_KIND = "TPU v5 lite"
+TPU_SPECS = {
+    V5E_KIND: TPUSpec(peak_flops=197e12, hbm_bw=819e9, ici_bw=50e9,
+                      hbm_bytes=16e9),
+}
+
+
+def tpu_spec(device_kind: str) -> TPUSpec:
+    """The peaks of the chip JAX names ``device_kind``; an unknown kind
+    raises (a roofline share against another chip's peaks is wrong)."""
+    try:
+        return TPU_SPECS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no TPU peaks for device kind {device_kind!r}; known: "
+            f"{sorted(TPU_SPECS)}"
+        ) from None
+
+
 BSS2 = BSS2Spec()
-TPU_V5E = TPUSpec()

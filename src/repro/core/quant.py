@@ -16,6 +16,12 @@ import jax.numpy as jnp
 
 from repro.core.hw import BSS2
 
+# Precision of every analog partial-sum matmul (a chunk's codes times its
+# effective weights).  The sums are defined in fp32 - the oracle, and what
+# the CPU computes - but a TPU contracts fp32 operands at bf16 by default,
+# which rounds the gain-folded effective weights and flips ADC codes.
+ANALOG_PRECISION = jax.lax.Precision.HIGHEST
+
 
 def _round_ste(x: jax.Array) -> jax.Array:
     """Round with a straight-through gradient."""

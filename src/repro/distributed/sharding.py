@@ -7,10 +7,12 @@ Parallelism mapping (production mesh, see launch/mesh.py):
                    analog tile grid columns
 - ``pod``  (2)   - extra DP by default; pipeline stages when PP is enabled
 
-The analog tile grid inherits the sharding of the weight it tiles: a
-[K, N] analog layer sharded ("embed", "mlp") puts whole 128 x 512 BSS-2
-tiles on each device because 512 | N/16 for every assigned config - i.e.
-tile-parallelism across emulated ASICs == TP across TPU chips.
+Pre-lowered analog plans split their output columns over ``model``
+(logical axis ``analog_cols``) and keep the contraction axis whole: a
+column's per-chunk ADC codes depend on that column alone, so the VMM
+kernels run per device on column blocks (:func:`vmm_axes`) - whole
+128-row BSS-2 chunks on each device, tile-parallelism across emulated
+ASICs == TP across TPU chips.
 """
 from __future__ import annotations
 
@@ -43,6 +45,7 @@ DEFAULT_RULES: dict[str, tuple[str, ...]] = {
     "capacity": (),
     "layers": (),              # stacked-scan leading axis
     "chunks": (),              # analog fpn chunk axis
+    "analog_cols": ("model",),  # output columns of pre-lowered plans
     "conv": (),
     "state": (),
     # decode caches: if kv_heads cannot shard (kv < model axis), the
@@ -228,12 +231,34 @@ def rules_for(run) -> dict:
 
 
 # --------------------------------------------------------------------------
-# Pre-lowered plan leaves (repro.exec plans) as first-class shardables:
-# a LayerPlan's arrays carry the SAME logical axes as the weight they were
-# baked from, so a pre-lowered params tree shards over the mesh exactly
-# like the raw params tree (ISSUE 2 - this is what retires the old
-# "no pre-lowering under a mesh" restriction in serve/engine.py).
+# Pre-lowered plan leaves (repro.exec plans) as first-class shardables: a
+# LayerPlan's arrays keep the stack prefix of the weight they were baked
+# from and split their output columns over ``analog_cols``, the layout the
+# VMM kernels run in under a mesh (:func:`vmm_axes`), so a pre-lowered
+# params tree shards over the mesh like the raw params tree and a kernel
+# call moves no weights.
 # --------------------------------------------------------------------------
+def vmm_axes(m: int, n: int):
+    """Mesh axes ``(rows, cols)`` over which an analog VMM ``[M, K] x
+    [K, N]`` splits on the active mesh: rows by the ``"batch"`` rule,
+    output columns by ``"analog_cols"`` - the axes the plan leaves carry
+    (:func:`layer_plan_specs`).  The contraction axis stays whole.  Rows
+    that do not divide stay whole (every device computes them); columns
+    that do not divide the ``analog_cols`` axis raise, since the weights
+    were placed split."""
+    mesh = _CTX.mesh
+    rows, cols = resolve_spec(("batch", "analog_cols"), (m, n))
+    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    wanted = [a for a in _CTX.rules.get("analog_cols", ())
+              if sizes.get(a, 1) > 1]
+    if wanted and cols is None:
+        raise ValueError(
+            f"an analog layer's {n} output columns do not split over the "
+            f"{sizes[wanted[0]]} devices of mesh axis {wanted[0]!r}"
+        )
+    return rows, cols
+
+
 def layer_plan_specs(lp, w_spec: Sequence[Optional[str]]):
     """Spec pytree (a LayerPlan holding logical-name tuples) for one -
     possibly scan-stacked - LayerPlan.
@@ -241,34 +266,34 @@ def layer_plan_specs(lp, w_spec: Sequence[Optional[str]]):
     ``w_spec`` is the logical spec of the master weight, e.g.
     ``("embed", "mlp")`` or ``("layers", "embed", "mlp")`` for a stacked
     layer: the trailing two names are the (in, out) axes, anything before
-    them is the stack prefix shared by every baked array.
+    them is the stack prefix shared by every baked array.  Baked arrays
+    keep the prefix, leave the input axis whole and take ``analog_cols``
+    for the output axis.
     """
     import dataclasses
 
     w_spec = tuple(w_spec)
-    prefix, in_name, out_name = w_spec[:-2], w_spec[-2], w_spec[-1]
+    prefix = w_spec[:-2]
     nd = len(prefix)         # rank of the stack prefix
+    cols = "analog_cols"
 
     def per_col(leaf):       # [*, N]-shaped leaves (gain may be scalar)
         if leaf is None:
             return None
-        return prefix + (out_name,) if leaf.ndim > nd else prefix
+        return prefix + (cols,) if leaf.ndim > nd else prefix
 
     s = lp.store
     store = dataclasses.replace(
         s,
-        # the packed codes carry the SAME logical axes as the master
-        # weight they quantize; gain tables shard by the axes they index
-        codes=w_spec,
-        w_scale=prefix + (None, out_name),
+        codes=prefix + (None, cols),
+        w_scale=prefix + (None, cols),
         gain=per_col(s.gain),
-        col_gain=None if s.col_gain is None else prefix + (out_name,),
-        row_gain=None if s.row_gain is None else prefix + (None, in_name),
+        col_gain=None if s.col_gain is None else prefix + (cols,),
+        row_gain=None if s.row_gain is None else prefix + (None, None),
         chunk_gain=(
-            None if s.chunk_gain is None
-            else prefix + ("chunks", out_name)
+            None if s.chunk_gain is None else prefix + ("chunks", cols)
         ),
-        gain_map=None if s.gain_map is None else w_spec,
+        gain_map=None if s.gain_map is None else prefix + (None, cols),
     )
     return dataclasses.replace(
         lp,
@@ -276,11 +301,10 @@ def layer_plan_specs(lp, w_spec: Sequence[Optional[str]]):
         a_scale=prefix,
         a_scale_in=None if lp.a_scale_in is None else prefix,
         chunk_offset=(
-            None if lp.chunk_offset is None
-            else prefix + ("chunks", out_name)
+            None if lp.chunk_offset is None else prefix + ("chunks", cols)
         ),
-        colsum=None if lp.colsum is None else prefix + (out_name,),
-        bias=None if lp.bias is None else prefix + (out_name,),
+        colsum=None if lp.colsum is None else prefix + (cols,),
+        bias=None if lp.bias is None else prefix + (cols,),
     )
 
 
@@ -331,15 +355,15 @@ def group_plan_specs(gp, parent_spec):
     (:class:`repro.exec.plan.GroupPlan`), derived from the members'
     master-weight specs in ``parent_spec`` (the parent node's spec dict):
 
-    - ``column_concat``: the fused plan inherits member 0's weight spec
-      (concatenated output columns keep the head axis; shape-aware
-      resolution falls back to replication when the fused width does not
-      divide the mesh axis),
+    - ``column_concat``: the fused plan takes member 0's stack prefix
+      and splits its concatenated output columns like any plan
+      (shape-aware resolution falls back to replication when the fused
+      width does not divide the mesh axis),
     - ``batch_concat``: ditto, with the member axis (replicated) spliced
       in before the (in, out) pair,
     - ``expert_stack``: the member's raw stacked-weight spec (e.g.
-      ``("expert", "embed", None)``) already carries the expert axis -
-      expert parallelism shards baked plans exactly like raw experts.
+      ``("expert", "embed", None)``) carries the expert axis, which takes
+      ``model`` only where the output columns cannot.
     """
     import dataclasses
 

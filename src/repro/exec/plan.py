@@ -156,14 +156,18 @@ class WeightStore:
             if self.col_blocks is None:
                 w = w * self.row_gain[..., 0, :, None]
             else:
-                parts, c0 = [], 0
-                for gi, nb in enumerate(self.col_blocks):
-                    parts.append(
-                        w[..., :, c0:c0 + nb]
-                        * self.row_gain[..., gi, :, None]
-                    )
+                # each column takes its block's row gain by a select over
+                # a column iota, not by slicing and concatenating: the
+                # blocks' edges need not fall on a mesh's column split,
+                # and a concatenate across them reshards the weights
+                col = jax.lax.broadcasted_iota(jnp.int32, (1, w.shape[-1]),
+                                               1)
+                rg, c0 = self.row_gain[..., 0, :, None], 0
+                for gi, nb in enumerate(self.col_blocks[:-1]):
                     c0 += nb
-                w = jnp.concatenate(parts, axis=-1)
+                    rg = jnp.where(col >= c0,
+                                   self.row_gain[..., gi + 1, :, None], rg)
+                w = w * rg
         if self.chunk_gain is not None:
             w = w * jnp.repeat(self.chunk_gain, self.chunk_rows, axis=-2)
         if self.gain_map is not None:

@@ -11,9 +11,10 @@ TPU mapping decisions (hw-codesign):
 - block sizes are MXU-aligned: bk = 128 (the BSS-2 signed-row chunk IS the
   MXU contraction tile - the paper's geometry is natively TPU-friendly),
   bm/bn multiples of 128 chosen so (a, w, acc) blocks fit VMEM.
-- operands stream as bf16 (activation codes 0..31 and weight codes +-63 are
-  exactly representable; MXU accumulates products in fp32, so the integer
-  arithmetic is exact up to 2^24).
+- operands reach the MXU as fp32 contracted at full precision
+  (:func:`mxu_dot`): activation codes 0..31 would be bf16-exact, but the
+  effective weights carry fixed-pattern and calibration gains, and bf16
+  rounding of those moves ADC codes off the fp32 oracle (PERF.md).
 - the chunk/grid-K axis is the innermost ("arbitrary") grid dimension and
   accumulates into an fp32 VMEM scratch; output is written once on the last
   chunk step.
@@ -32,7 +33,33 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.hw import BSS2
-from repro.kernels._compat import CompilerParams
+from repro.core.quant import ANALOG_PRECISION
+
+
+def mxu_dot(a: jax.Array, w: jax.Array) -> jax.Array:
+    """One chunk's MXU dot: fp32 operands contracted at full fp32
+    precision.  Mosaic's default for fp32 operands may round them to bf16,
+    which moves ADC codes off the fp32 oracle."""
+    return jnp.dot(a, w, preferred_element_type=jnp.float32,
+                   precision=ANALOG_PRECISION)
+
+
+def _offset_rows(chunk_offset, n_chunks: int, n: int, pn: int) -> jax.Array:
+    """``[C, N]`` chunk offsets (or None = zeros) as ``[C, 1, N + pn]``:
+    each chunk's offset row is its own ``(1, N)`` slab, a block shape
+    Mosaic's (8, 128) tiling accepts for any C (a ``(1, block_n)`` block
+    over ``[C, N]`` breaks the rule whenever C > 1)."""
+    if chunk_offset is None:
+        return jnp.zeros((n_chunks, 1, n + pn), jnp.float32)
+    chunk_offset = jnp.asarray(chunk_offset, jnp.float32)
+    if pn:
+        chunk_offset = jnp.pad(chunk_offset, ((0, 0), (0, pn)))
+    return chunk_offset[:, None, :]
+
+
+def _offset_spec(block_n: int):
+    # chunk c's offset row, squeezed to (1, block_n) in the kernel
+    return pl.BlockSpec((None, 1, block_n), lambda i, j, c: (c, 0, j))
 
 
 def _apply_epilogue(acc, epilogue):
@@ -52,16 +79,14 @@ def _apply_epilogue(acc, epilogue):
 
 
 def _kernel(a_ref, w_ref, gain_ref, off_ref, o_ref, acc_ref, *,
-            n_chunks: int, faithful: bool, compute_dtype, epilogue=None):
+            n_chunks: int, faithful: bool, epilogue=None):
     c = pl.program_id(2)
 
     @pl.when(c == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    a = a_ref[...].astype(compute_dtype)
-    w = w_ref[...].astype(compute_dtype)
-    v = jnp.dot(a, w, preferred_element_type=jnp.float32)
+    v = mxu_dot(a_ref[...], w_ref[...])
     v = v * gain_ref[...] + off_ref[...]
     if faithful:
         # 8-bit saturating ADC per chunk, digital accumulation
@@ -82,7 +107,7 @@ def _kernel(a_ref, w_ref, gain_ref, off_ref, o_ref, acc_ref, *,
     jax.jit,
     static_argnames=(
         "chunk_rows", "faithful", "block_m", "block_n", "interpret",
-        "compute_dtype", "epilogue",
+        "epilogue",
     ),
 )
 def analog_mvm_pallas(
@@ -96,13 +121,10 @@ def analog_mvm_pallas(
     block_m: int = 256,
     block_n: int = 512,
     interpret: bool = False,
-    compute_dtype=jnp.float32,
     epilogue=None,                        # None | ("relu_shift", shift)
 ) -> jax.Array:
-    """``compute_dtype=jnp.bfloat16`` enables the full-rate MXU path on TPU;
-    activation/weight codes are bf16-exact, only the fixed-pattern gain picks
-    up <=2^-9 relative rounding, i.e. sub-LSB extra 'analog' noise.  fp32 is
-    bit-exact vs the oracle and is used for CPU validation."""
+    """Chunked saturating analog VMM; bit-exact vs the fp32 oracle in
+    interpret mode."""
     m, k = a_code.shape
     k2, n = w_eff.shape
     assert k == k2, (k, k2)
@@ -116,27 +138,24 @@ def analog_mvm_pallas(
         a_code = jnp.pad(a_code, ((0, pm), (0, 0)))
     if pn:
         w_eff = jnp.pad(w_eff, ((0, 0), (0, pn)))
+    # [1, N] (a 2-D row block; 1-D blocks mismatch XLA's tiling for N > 1024)
     gain = jnp.broadcast_to(jnp.asarray(gain, jnp.float32), (n,))
-    if pn:
-        gain = jnp.pad(gain, (0, pn))
-    if chunk_offset is None:
-        chunk_offset = jnp.zeros((n_chunks, n + pn), jnp.float32)
-    elif pn:
-        chunk_offset = jnp.pad(chunk_offset, ((0, 0), (0, pn)))
+    gain = jnp.pad(gain, (0, pn))[None, :]
+    chunk_offset = _offset_rows(chunk_offset, n_chunks, n, pn)
     mp, np_ = m + pm, n + pn
 
     grid = (mp // block_m, np_ // block_n, n_chunks)
     out = pl.pallas_call(
         functools.partial(
             _kernel, n_chunks=n_chunks, faithful=faithful,
-            compute_dtype=compute_dtype, epilogue=epilogue,
+            epilogue=epilogue,
         ),
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_m, chunk_rows), lambda i, j, c: (i, c)),
             pl.BlockSpec((chunk_rows, block_n), lambda i, j, c: (c, j)),
-            pl.BlockSpec((block_n,), lambda i, j, c: (j,)),
-            pl.BlockSpec((1, block_n), lambda i, j, c: (c, j)),
+            pl.BlockSpec((1, block_n), lambda i, j, c: (0, j)),
+            _offset_spec(block_n),
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, c: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
@@ -144,7 +163,7 @@ def analog_mvm_pallas(
             # fp32 accumulator lives in VMEM across the chunk loop
             pltpu.VMEM((block_m, block_n), jnp.float32)
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -157,7 +176,7 @@ def analog_mvm_pallas(
 # --------------------------------------------------------------------------
 def _split_kernel(ap_ref, an_ref, w_ref, gain_ref, off_ref, o_ref,
                   accp_ref, accn_ref, *, n_chunks: int, faithful: bool,
-                  compute_dtype, epilogue=None):
+                  epilogue=None):
     """One grid pass over the shared weight tiles evaluates BOTH analog
     passes of the signed-split encoding (paper §II-A: positive and negative
     activation parts on the same synapse columns).  Each (bm, bn, c) step
@@ -173,13 +192,11 @@ def _split_kernel(ap_ref, an_ref, w_ref, gain_ref, off_ref, o_ref,
         accp_ref[...] = jnp.zeros_like(accp_ref)
         accn_ref[...] = jnp.zeros_like(accn_ref)
 
-    w = w_ref[...].astype(compute_dtype)
+    w = w_ref[...]
     gain = gain_ref[...]
     off = off_ref[...]
-    vp = jnp.dot(ap_ref[...].astype(compute_dtype), w,
-                 preferred_element_type=jnp.float32) * gain + off
-    vn = jnp.dot(an_ref[...].astype(compute_dtype), w,
-                 preferred_element_type=jnp.float32) * gain + off
+    vp = mxu_dot(ap_ref[...], w) * gain + off
+    vn = mxu_dot(an_ref[...], w) * gain + off
     if faithful:
         lo, hi = float(BSS2.adc_min), float(BSS2.adc_max)
         vp = jnp.clip(jnp.round(vp), lo, hi)
@@ -202,7 +219,7 @@ def _split_kernel(ap_ref, an_ref, w_ref, gain_ref, off_ref, o_ref,
     jax.jit,
     static_argnames=(
         "chunk_rows", "faithful", "block_m", "block_n", "interpret",
-        "compute_dtype", "epilogue",
+        "epilogue",
     ),
 )
 def analog_mvm_split_pallas(
@@ -217,7 +234,6 @@ def analog_mvm_split_pallas(
     block_m: int = 256,
     block_n: int = 512,
     interpret: bool = False,
-    compute_dtype=jnp.float32,
     epilogue=None,                        # None | ("relu_shift", shift)
 ) -> jax.Array:
     """Fused signed-split analog VMM: ``mvm(a_pos) - mvm(a_neg)`` in one
@@ -238,28 +254,25 @@ def analog_mvm_split_pallas(
         a_neg = jnp.pad(a_neg, ((0, pm), (0, 0)))
     if pn:
         w_eff = jnp.pad(w_eff, ((0, 0), (0, pn)))
+    # [1, N] (a 2-D row block; 1-D blocks mismatch XLA's tiling for N > 1024)
     gain = jnp.broadcast_to(jnp.asarray(gain, jnp.float32), (n,))
-    if pn:
-        gain = jnp.pad(gain, (0, pn))
-    if chunk_offset is None:
-        chunk_offset = jnp.zeros((n_chunks, n + pn), jnp.float32)
-    elif pn:
-        chunk_offset = jnp.pad(chunk_offset, ((0, 0), (0, pn)))
+    gain = jnp.pad(gain, (0, pn))[None, :]
+    chunk_offset = _offset_rows(chunk_offset, n_chunks, n, pn)
     mp, np_ = m + pm, n + pn
 
     grid = (mp // block_m, np_ // block_n, n_chunks)
     out = pl.pallas_call(
         functools.partial(
             _split_kernel, n_chunks=n_chunks, faithful=faithful,
-            compute_dtype=compute_dtype, epilogue=epilogue,
+            epilogue=epilogue,
         ),
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_m, chunk_rows), lambda i, j, c: (i, c)),
             pl.BlockSpec((block_m, chunk_rows), lambda i, j, c: (i, c)),
             pl.BlockSpec((chunk_rows, block_n), lambda i, j, c: (c, j)),
-            pl.BlockSpec((block_n,), lambda i, j, c: (j,)),
-            pl.BlockSpec((1, block_n), lambda i, j, c: (c, j)),
+            pl.BlockSpec((1, block_n), lambda i, j, c: (0, j)),
+            _offset_spec(block_n),
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, c: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
@@ -267,7 +280,7 @@ def analog_mvm_split_pallas(
             pltpu.VMEM((block_m, block_n), jnp.float32),
             pltpu.VMEM((block_m, block_n), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

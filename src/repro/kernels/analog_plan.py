@@ -43,8 +43,8 @@ TPU mapping:
   activation path); block plans carry a second scratch holding the fp32
   residual stream,
 - ``flatten_out`` layers (the ECG conv->fc1 im2col hand-off) merge their
-  position axis into the next layer's contraction axis by a static reshape
-  of the activation block.
+  position axis into the next layer's contraction axis by strided loads
+  from the scratch buffer (:func:`_flatten_positions`).
 
 The static layer schedule (:class:`MegaLayerMeta` tuple, plus the optional
 :class:`BlockMeta` transformer-glue geometry) is baked at lower time; the
@@ -66,7 +66,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.hw import BSS2
-from repro.kernels._compat import CompilerParams
+from repro.kernels.analog_mvm import mxu_dot
 
 # Default rows-per-grid-step budget of the batch-only grid.  The old
 # heuristic picked ``block_b = min(b, 64)`` batch elements regardless of
@@ -75,6 +75,9 @@ from repro.kernels._compat import CompilerParams
 # the VMEM working set flat across chain geometries (the small-batch ECG
 # grid/scratch fix of ISSUE 6).
 DEFAULT_ROW_BUDGET = 512
+# lane width of the flatten staging buffer: Mosaic's strided row loads
+# need a base memref exactly one vreg (128 lanes) wide
+_LANES = 128
 
 
 class MegaLayerMeta(NamedTuple):
@@ -151,8 +154,28 @@ def _pad_width(a: jax.Array, width: int) -> jax.Array:
     return a
 
 
+def _flatten_positions(stage_ref, h: jax.Array, flatten: int) -> jax.Array:
+    """im2col flatten: merge the position rows into the next layer's
+    contraction axis, ``[rows, n] -> [rows // flatten, flatten * n]``
+    (row ``e * flatten + p`` lands in columns ``p*n:(p+1)*n`` of row
+    ``e``).  Mosaic lowers no sublane->lane shape cast, so the relabel
+    goes through a 128-lane staging buffer: one strided row load per
+    position and lane block, concatenated along lanes.  A pure data
+    movement - bit-identical to the row-major ``reshape``."""
+    rows, n = h.shape
+    pieces = [[] for _ in range(flatten)]
+    for c0 in range(0, n, _LANES):
+        width = min(_LANES, n - c0)
+        stage_ref[0:rows, :] = _pad_width(h[:, c0:c0 + width], _LANES)
+        for p in range(flatten):
+            blk = stage_ref[pl.ds(p, rows // flatten, stride=flatten), :]
+            pieces[p].append(blk[:, :width])
+    return jnp.concatenate([b for per_pos in pieces for b in per_pos],
+                           axis=1)
+
+
 def _adc_accumulate(h, w_l, gain, off_rows, meta: MegaLayerMeta, *,
-                    chunk_rows: int, faithful: bool, compute_dtype):
+                    chunk_rows: int, faithful: bool):
     """Chunked saturating analog VMM for one scheduled layer (in-kernel):
     per 128-row chunk, MXU dot + gain + fixed-pattern offset, 8-bit ADC
     round/clip (faithful) and digital accumulation - the same arithmetic
@@ -160,9 +183,8 @@ def _adc_accumulate(h, w_l, gain, off_rows, meta: MegaLayerMeta, *,
     chunk count."""
     acc = jnp.zeros((h.shape[0], w_l.shape[1]), jnp.float32)
     for c in range(meta.n_chunks):
-        a_c = h[:, c * chunk_rows:(c + 1) * chunk_rows].astype(compute_dtype)
-        w_c = w_l[c * chunk_rows:(c + 1) * chunk_rows, :].astype(compute_dtype)
-        v = jnp.dot(a_c, w_c, preferred_element_type=jnp.float32)
+        v = mxu_dot(h[:, c * chunk_rows:(c + 1) * chunk_rows],
+                    w_l[c * chunk_rows:(c + 1) * chunk_rows, :])
         v = v * gain + off_rows[c]
         if faithful:
             v = jnp.clip(jnp.round(v), float(BSS2.adc_min),
@@ -187,7 +209,7 @@ def _layer_handoff(meta: MegaLayerMeta, last: bool) -> str:
 
 def _plan_kernel(*refs, schedule: Tuple[MegaLayerMeta, ...],
                  chunk_rows: int, faithful: bool, n_max: int, block_b: int,
-                 compute_dtype, block: Optional[BlockMeta],
+                 block: Optional[BlockMeta],
                  has_extras: bool):
     if has_extras:
         (x_ref, w_ref, gain_ref, off_ref,
@@ -196,10 +218,11 @@ def _plan_kernel(*refs, schedule: Tuple[MegaLayerMeta, ...],
         x_ref, w_ref, gain_ref, off_ref, *rest = refs
         deq_ref = bias_ref = enc_ref = None
     if block is not None:
-        ln_ref, o_ref, h_ref, res_ref = rest
+        ln_ref, o_ref, h_ref, res_ref, *stage = rest
     else:
-        o_ref, h_ref = rest
+        o_ref, h_ref, *stage = rest
         ln_ref = res_ref = None
+    stage_ref = stage[0] if stage else None
 
     w_all = w_ref[...]
     last = len(schedule) - 1
@@ -224,7 +247,6 @@ def _plan_kernel(*refs, schedule: Tuple[MegaLayerMeta, ...],
         mm = functools.partial(
             _adc_accumulate, w_l=w_l, gain=gain, off_rows=off_rows,
             meta=meta, chunk_rows=chunk_rows, faithful=faithful,
-            compute_dtype=compute_dtype,
         )
         if meta.encode == "codes":
             # h already holds (padded) 5-bit codes
@@ -264,10 +286,7 @@ def _plan_kernel(*refs, schedule: Tuple[MegaLayerMeta, ...],
             nxt = jnp.floor(nxt / float(1 << meta.shift))
             nxt = jnp.clip(nxt, 0.0, float(BSS2.a_max))[:, :meta.n]
             if meta.flatten > 1:
-                # im2col flatten: merge the position rows into the next
-                # layer's contraction axis (row-major relabeling)
-                nxt = nxt.reshape(rows // meta.flatten,
-                                  meta.flatten * meta.n)
+                nxt = _flatten_positions(stage_ref, nxt, meta.flatten)
         else:
             # float-domain hand-off: dequantize at the packed per-column
             # rows (a_scale * w_scale / gain) + bias, then run the glue
@@ -276,8 +295,7 @@ def _plan_kernel(*refs, schedule: Tuple[MegaLayerMeta, ...],
             if handoff == "relu":
                 nxt = jnp.maximum(y, 0.0)
                 if meta.flatten > 1:
-                    nxt = nxt.reshape(rows // meta.flatten,
-                                      meta.flatten * meta.n)
+                    nxt = _flatten_positions(stage_ref, nxt, meta.flatten)
             elif handoff == "attn":
                 # fused QKV -> RoPE + causal softmax attention; the SAME
                 # function the model path calls (parity by construction).
@@ -312,7 +330,7 @@ def _plan_kernel(*refs, schedule: Tuple[MegaLayerMeta, ...],
     jax.jit,
     static_argnames=(
         "schedule", "chunk_rows", "faithful", "block_b", "interpret",
-        "compute_dtype", "block",
+        "block",
     ),
 )
 def analog_plan_pallas(
@@ -330,7 +348,6 @@ def analog_plan_pallas(
     faithful: bool = True,
     block_b: int = 8,
     interpret: bool = False,
-    compute_dtype=jnp.float32,
     block: Optional[BlockMeta] = None,
 ) -> jax.Array:
     """Execute a packed AnalogPlan chain in ONE kernel launch.
@@ -340,9 +357,7 @@ def analog_plan_pallas(
     final layer's raw accumulated ADC codes ``[B * m_mult_last, n_last]``
     (handoff "raw"; the caller dequantizes exactly like the per-layer
     executor) or the fully-glued float block output (handoff "res_out").
-    fp32 is bit-exact against the layer-by-layer replay (tested);
-    ``bfloat16`` enables the full-rate MXU path on TPU with the same
-    sub-LSB caveat as :func:`repro.kernels.analog_mvm.analog_mvm_pallas`.
+    Bit-exact against the layer-by-layer replay (tested).
     """
     assert len(schedule) >= 1
     has_extras = deq is not None
@@ -397,12 +412,18 @@ def analog_plan_pallas(
         scratch_shapes.append(
             pltpu.VMEM((block_b * m0, n_max), jnp.float32)
         )
+    flat_rows = [m.m_mult for m in schedule if m.flatten > 1]
+    if flat_rows:
+        # the flatten layers' position rows, staged for the relabel
+        scratch_shapes.append(
+            pltpu.VMEM((block_b * max(flat_rows), _LANES), jnp.float32)
+        )
     grid = (b_pad // block_b,)
     out = pl.pallas_call(
         functools.partial(
             _plan_kernel, schedule=schedule, chunk_rows=chunk_rows,
             faithful=faithful, n_max=n_max, block_b=block_b,
-            compute_dtype=compute_dtype, block=block,
+            block=block,
             has_extras=has_extras,
         ),
         grid=grid,
@@ -410,7 +431,7 @@ def analog_plan_pallas(
         out_specs=pl.BlockSpec((block_b * m_last, n_max), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((b_pad * m_last, n_max), jnp.float32),
         scratch_shapes=scratch_shapes,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)
         ),
         interpret=interpret,

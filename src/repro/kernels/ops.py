@@ -1,9 +1,13 @@
 """Jitted public wrappers around the Pallas kernels with automatic
 platform dispatch and a custom-VJP HIL gradient.
 
-- On TPU the Mosaic kernels run natively (bf16 MXU path).
-- On CPU (this container) ``interpret=True`` executes the kernel bodies in
-  Python for bit-level validation against :mod:`repro.kernels.ref`.
+- On TPU the Mosaic kernels run natively.
+- On CPU ``interpret=True`` executes the kernel bodies in Python for
+  bit-level validation against :mod:`repro.kernels.ref`.  No other
+  backend runs them (:func:`_interpret`).
+- Under a mesh of several devices the VMM kernels split their rows over
+  the batch axes and their output columns over ``model``
+  (:func:`_column_parallel`).
 - ``analog_mvm`` carries the hardware-in-the-loop gradient (paper §III-B):
   forward through the saturating kernel, backward through the straight-
   through linearization of the ref oracle.
@@ -15,8 +19,11 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core.hw import BSS2
+from repro.core.quant import ANALOG_PRECISION
+from repro.distributed import sharding as shd
 from repro.kernels import ref as ref_lib
 from repro.kernels.analog_mvm import analog_mvm_pallas, analog_mvm_split_pallas
 from repro.kernels import analog_plan
@@ -26,6 +33,45 @@ from repro.kernels.preproc import maxmin_pool_pallas
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def _interpret() -> bool:
+    """Whether the Pallas kernels run interpreted: natively (Mosaic) on
+    TPU, interpreted on CPU for bit-level validation against the oracle.
+    Any other backend raises: interpreting there would run the kernel
+    bodies on the host and hide the device."""
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"Pallas kernels run natively on TPU or interpreted on CPU; "
+            f"the {backend!r} backend is neither"
+        )
+    return backend == "cpu"
+
+
+def _column_parallel(kernel, acts, w_eff, gain, chunk_offset, n_chunks):
+    """Call an analog VMM kernel ``kernel(*acts, w_eff, gain, offset)``,
+    under the active mesh if there is one.  Mosaic kernels cannot be
+    partitioned automatically, so on a mesh of several devices the call
+    runs in ``shard_map`` over the axes :func:`repro.distributed.sharding.
+    vmm_axes` names: rows over the batch axes, output columns over
+    ``model``, the layout the plan leaves are placed in.  A column's
+    per-chunk ADC codes and epilogue depend on that column alone, so the
+    split is exact."""
+    n = w_eff.shape[1]
+    gain = jnp.broadcast_to(jnp.asarray(gain, jnp.float32), (n,))
+    mesh = shd.get_mesh()
+    if mesh is None or mesh.size == 1:
+        return kernel(*acts, w_eff, gain, chunk_offset)
+    if chunk_offset is None:
+        chunk_offset = jnp.zeros((n_chunks, n), jnp.float32)
+    rows, cols = shd.vmm_axes(acts[0].shape[0], n)
+    return jax.shard_map(
+        kernel, mesh=mesh,
+        in_specs=(P(rows, None),) * len(acts) + (P(None, cols), P(cols),
+                                                 P(None, cols)),
+        out_specs=P(rows, cols), check_vma=False,
+    )(*acts, w_eff, gain, chunk_offset)
 
 
 def _mvm_chunk_scan(a_code, w_eff, gain, chunk_offset, chunk_rows):
@@ -51,7 +97,7 @@ def _mvm_chunk_scan(a_code, w_eff, gain, chunk_offset, chunk_rows):
 
     def step(acc, xs):
         a_i, w_i, o_i = xs
-        v = jnp.einsum("mk,kn->mn", a_i, w_i,
+        v = jnp.einsum("mk,kn->mn", a_i, w_i, precision=ANALOG_PRECISION,
                        preferred_element_type=jnp.float32) * gain + o_i
         return acc + jnp.clip(jnp.round(v), BSS2.adc_min, BSS2.adc_max), None
 
@@ -84,9 +130,9 @@ def _mvm_split_chunk_scan(a_pos, a_neg, w_eff, gain, chunk_offset,
 
     def step(acc, xs):
         ap_i, an_i, w_i, o_i = xs
-        vp = jnp.einsum("mk,kn->mn", ap_i, w_i,
+        vp = jnp.einsum("mk,kn->mn", ap_i, w_i, precision=ANALOG_PRECISION,
                         preferred_element_type=jnp.float32) * gain + o_i
-        vn = jnp.einsum("mk,kn->mn", an_i, w_i,
+        vn = jnp.einsum("mk,kn->mn", an_i, w_i, precision=ANALOG_PRECISION,
                         preferred_element_type=jnp.float32) * gain + o_i
         adc_p = jnp.clip(jnp.round(vp), BSS2.adc_min, BSS2.adc_max)
         adc_n = jnp.clip(jnp.round(vn), BSS2.adc_min, BSS2.adc_max)
@@ -112,11 +158,11 @@ def analog_mvm(
     """[M, K] x [K, N] chunked saturating analog VMM (forward = hardware)."""
     use = _on_tpu() if use_pallas is None else use_pallas
     if use:
-        return analog_mvm_pallas(
-            a_code, w_eff, gain, chunk_offset,
-            chunk_rows=chunk_rows, faithful=faithful,
-            interpret=not _on_tpu(),
-            compute_dtype=jnp.bfloat16 if _on_tpu() else jnp.float32,
+        return _column_parallel(
+            functools.partial(analog_mvm_pallas, chunk_rows=chunk_rows,
+                              faithful=faithful, interpret=_interpret()),
+            (a_code,), w_eff, gain, chunk_offset,
+            a_code.shape[1] // chunk_rows,
         )
     return ref_lib.analog_mvm_ref(
         a_code, w_eff, gain, chunk_offset,
@@ -185,11 +231,11 @@ def analog_mvm_split(
             chunk_rows=chunk_rows, faithful=faithful,
         )
     if use:
-        return analog_mvm_split_pallas(
-            a_pos, a_neg, w_eff, gain, chunk_offset,
-            chunk_rows=chunk_rows, faithful=faithful,
-            interpret=not _on_tpu(),
-            compute_dtype=jnp.bfloat16 if _on_tpu() else jnp.float32,
+        return _column_parallel(
+            functools.partial(analog_mvm_split_pallas, chunk_rows=chunk_rows,
+                              faithful=faithful, interpret=_interpret()),
+            (a_pos, a_neg), w_eff, gain, chunk_offset,
+            a_pos.shape[1] // chunk_rows,
         )
     # fused jnp path, faithful: stream the chunks through a scan that
     # shares each weight chunk between the pos/neg passes and subtracts
@@ -250,13 +296,12 @@ def analog_mvm_infer(
     use = _on_tpu() if use_pallas is None else use_pallas
     if use:
         kw = dict(chunk_rows=chunk_rows, faithful=faithful,
-                  interpret=not _on_tpu(),
-                  compute_dtype=jnp.bfloat16 if _on_tpu() else jnp.float32,
-                  epilogue=epilogue)
-        if a_neg is None:
-            return analog_mvm_pallas(a_pos, w_eff, gain, chunk_offset, **kw)
-        return analog_mvm_split_pallas(
-            a_pos, a_neg, w_eff, gain, chunk_offset, **kw
+                  epilogue=epilogue, interpret=_interpret())
+        kernel, acts = ((analog_mvm_pallas, (a_pos,)) if a_neg is None
+                        else (analog_mvm_split_pallas, (a_pos, a_neg)))
+        return _column_parallel(
+            functools.partial(kernel, **kw), acts, w_eff, gain,
+            chunk_offset, a_pos.shape[1] // chunk_rows,
         )
     if a_neg is None:
         y = (_mvm_chunk_scan(a_pos, w_eff, gain, chunk_offset, chunk_rows)
@@ -333,9 +378,7 @@ def _plan_codes(x_in, w_cat, gain_all, off_cat, extras, schedule,
         return analog_plan_pallas(
             x_in, w_cat, gain_all, off_cat, deq, bias, enc, ln,
             schedule=schedule, chunk_rows=chunk_rows, faithful=faithful,
-            block_b=bb, interpret=not _on_tpu(),
-            compute_dtype=jnp.bfloat16 if _on_tpu() else jnp.float32,
-            block=block,
+            block_b=bb, block=block, interpret=_interpret(),
         )
     return ref_lib.analog_plan_ref(
         x_in, w_cat, gain_all, off_cat, schedule,
@@ -379,7 +422,7 @@ def maxmin_pool(x: jax.Array, window: int = 32,
     shape = x.shape
     x2 = x.reshape(-1, shape[-1])
     if use:
-        y = maxmin_pool_pallas(x2, window=window, interpret=not _on_tpu())
+        y = maxmin_pool_pallas(x2, window=window, interpret=_interpret())
     else:
         y = ref_lib.maxmin_pool_ref(x2, window=window)
     return y.reshape(shape[:-1] + (shape[-1] // window,))

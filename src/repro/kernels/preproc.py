@@ -3,8 +3,10 @@ non-overlapping max-min window pooling over the derivative signal.
 
 On the real system this runs in FPGA fabric at line rate; on TPU it is a
 bandwidth-bound streaming reduce, so the kernel tiles the time axis into
-VMEM-resident blocks and emits one output element per 32-sample window
-without materializing the [.., T/32, 32] reshape in HBM.
+VMEM-resident blocks and emits one output element per 32-sample window.
+The windows arrive as the trailing axis of a ``[B, T/32, 32]`` view made
+outside the kernel: Mosaic lowers a reduction over that axis, but not an
+in-kernel reshape of a flat ``[B, T]`` block into windows.
 """
 from __future__ import annotations
 
@@ -14,13 +16,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels._compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(x_ref, o_ref, *, window: int):
-    x = x_ref[...]                       # [bb, bt * window]
-    bb, btw = x.shape
-    xw = x.reshape(bb, btw // window, window)
+def _kernel(x_ref, o_ref):
+    xw = x_ref[...]                      # [bb, bt, window]
     o_ref[...] = xw.max(axis=-1) - xw.min(axis=-1)
 
 
@@ -43,15 +43,15 @@ def maxmin_pool_pallas(
         x = jnp.pad(x, ((0, pb), (0, pt * window)))
     bb, tt_out = b + pb, t_out + pt
     out = pl.pallas_call(
-        functools.partial(_kernel, window=window),
+        _kernel,
         grid=(bb // block_b, tt_out // block_t),
-        in_specs=[pl.BlockSpec((block_b, block_t * window),
-                               lambda i, j: (i, j))],
+        in_specs=[pl.BlockSpec((block_b, block_t, window),
+                               lambda i, j: (i, j, 0))],
         out_specs=pl.BlockSpec((block_b, block_t), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((bb, tt_out), x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")
         ),
         interpret=interpret,
-    )(x)
+    )(x.reshape(bb, tt_out, window))
     return out[:b, :t_out]

@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.hw import BSS2
+from repro.core.quant import ANALOG_PRECISION
 
 
 def analog_mvm_ref(
@@ -27,7 +28,8 @@ def analog_mvm_ref(
     c = k // chunk_rows
     a_c = a_code.reshape(m, c, chunk_rows).astype(jnp.float32)
     w_c = w_eff.reshape(c, chunk_rows, n).astype(jnp.float32)
-    v = jnp.einsum("mck,ckn->mcn", a_c, w_c, preferred_element_type=jnp.float32)
+    v = jnp.einsum("mck,ckn->mcn", a_c, w_c, precision=ANALOG_PRECISION,
+                   preferred_element_type=jnp.float32)
     v = v * gain
     if chunk_offset is not None:
         v = v + chunk_offset[None, :, :]
@@ -129,6 +131,7 @@ def analog_plan_ref(
                 a_c = a[:, c * chunk_rows:(c + 1) * chunk_rows]
                 w_c = w_l[c * chunk_rows:(c + 1) * chunk_rows, :]
                 v = jnp.einsum("...k,kn->...n", a_c, w_c,
+                               precision=ANALOG_PRECISION,
                                preferred_element_type=jnp.float32)
                 v = v * gain + offs[c]
                 if faithful:

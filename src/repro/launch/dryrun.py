@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from repro import configs
 from repro.configs.base import RunConfig, SHAPES
 from repro.core.analog import AnalogConfig
+from repro.core.hw import V5E_KIND
 from repro.core.noise import NoiseConfig
 from repro.distributed import sharding as shd
 from repro.launch.mesh import make_production_mesh
@@ -197,6 +198,8 @@ def run_cell(arch: str, shape: str, mesh_kind: str, mode: str,
         "mode": mode,
         "kind": sh.kind,
         "n_devices": mesh.devices.size,
+        # the chip the CPU-lowered program is sized for (roofline peaks)
+        "target_device_kind": V5E_KIND,
         "lower_s": round(t_lower, 1),
         "compile_s": round(t_compile, 1),
         "memory": {

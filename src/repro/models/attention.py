@@ -7,6 +7,7 @@ synapse array holds static weights only (DESIGN.md §5.1).
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import NamedTuple, Optional
 
@@ -177,63 +178,72 @@ def attention_apply(params, x, *, positions, acfg: AnalogConfig, n_heads,
     v = constrain(v, "batch", "seq", "kv_heads", None)
     qg = q.reshape(b, s, n_kv_heads, g, head_dim)
 
-    if cache is not None:
-        # decode: append to the cache, attend over the valid prefix
-        length = cache["len"]                      # scalar int32
-        quantized = cache["k"].dtype == jnp.int8
-        new_cache = {"len": length + s}
-        if quantized:
-            # int8 KV cache ("store at ADC resolution", beyond-paper):
-            # per-(position, head) symmetric scales; halves the decode
-            # memory-roofline term vs bf16 at <1% logit error
-            ks_new = jnp.abs(k).max(axis=-1).astype(jnp.float32) / 127.0
-            vs_new = jnp.abs(v).max(axis=-1).astype(jnp.float32) / 127.0
-            ks_new = jnp.maximum(ks_new, 1e-9)
-            vs_new = jnp.maximum(vs_new, 1e-9)
-            kq = jnp.clip(jnp.round(k / ks_new[..., None]), -127, 127)
-            vq = jnp.clip(jnp.round(v / vs_new[..., None]), -127, 127)
-            ck = jax.lax.dynamic_update_slice(
-                cache["k"], kq.astype(jnp.int8), (0, length, 0, 0))
-            cv = jax.lax.dynamic_update_slice(
-                cache["v"], vq.astype(jnp.int8), (0, length, 0, 0))
-            cks = jax.lax.dynamic_update_slice(
-                cache["k_scale"], ks_new, (0, length, 0))
-            cvs = jax.lax.dynamic_update_slice(
-                cache["v_scale"], vs_new, (0, length, 0))
-            ck_f = ck.astype(jnp.float32) * cks[..., None]
-            cv_f = cv.astype(jnp.float32) * cvs[..., None]
-            new_cache.update(k_scale=cks, v_scale=cvs)
+    # analog modes: the glue between the QKV and output projections
+    # (scores, mixing) contracts at full precision.  A TPU rounds fp32
+    # operands to bf16 by default, and the output projection's 5-bit
+    # re-quantization amplifies that: on a TPU v5e it moved a 4-layer
+    # stablelm-3b-width model's prefill logits 0.298 (relative L2) from
+    # the fp32 forward; an un-quantised float forward is 0.357 away.
+    glue_precision = (contextlib.nullcontext() if acfg.mode == "digital"
+                      else jax.default_matmul_precision("highest"))
+    with glue_precision:
+        if cache is not None:
+            # decode: append to the cache, attend over the valid prefix
+            length = cache["len"]                      # scalar int32
+            quantized = cache["k"].dtype == jnp.int8
+            new_cache = {"len": length + s}
+            if quantized:
+                # int8 KV cache ("store at ADC resolution", beyond-paper):
+                # per-(position, head) symmetric scales; halves the decode
+                # memory-roofline term vs bf16 at <1% logit error
+                ks_new = jnp.abs(k).max(axis=-1).astype(jnp.float32) / 127.0
+                vs_new = jnp.abs(v).max(axis=-1).astype(jnp.float32) / 127.0
+                ks_new = jnp.maximum(ks_new, 1e-9)
+                vs_new = jnp.maximum(vs_new, 1e-9)
+                kq = jnp.clip(jnp.round(k / ks_new[..., None]), -127, 127)
+                vq = jnp.clip(jnp.round(v / vs_new[..., None]), -127, 127)
+                ck = jax.lax.dynamic_update_slice(
+                    cache["k"], kq.astype(jnp.int8), (0, length, 0, 0))
+                cv = jax.lax.dynamic_update_slice(
+                    cache["v"], vq.astype(jnp.int8), (0, length, 0, 0))
+                cks = jax.lax.dynamic_update_slice(
+                    cache["k_scale"], ks_new, (0, length, 0))
+                cvs = jax.lax.dynamic_update_slice(
+                    cache["v_scale"], vs_new, (0, length, 0))
+                ck_f = ck.astype(jnp.float32) * cks[..., None]
+                cv_f = cv.astype(jnp.float32) * cvs[..., None]
+                new_cache.update(k_scale=cks, v_scale=cvs)
+            else:
+                ck = jax.lax.dynamic_update_slice(
+                    cache["k"], k.astype(cache["k"].dtype), (0, length, 0, 0)
+                )
+                cv = jax.lax.dynamic_update_slice(
+                    cache["v"], v.astype(cache["v"].dtype), (0, length, 0, 0)
+                )
+                ck_f, cv_f = ck.astype(jnp.float32), cv.astype(jnp.float32)
+            smax = ck.shape[1]
+            kpos = jnp.arange(smax)
+            qpos = length + jnp.arange(s)
+            mask = qpos[:, None] >= kpos[None, :]
+            mask &= (kpos < length + s)[None, :]
+            if window is not None:
+                mask &= (qpos[:, None] - kpos[None, :]) < window
+            sc = jnp.einsum(
+                "bqhgd,bkhd->bhgqk", qg.astype(jnp.float32), ck_f
+            ) / jnp.sqrt(head_dim)
+            sc = jnp.where(mask[None, None, None], sc, NEG_INF)
+            p = jax.nn.softmax(sc, axis=-1)
+            o = jnp.einsum("bhgqk,bkhd->bqhgd", p, cv_f)
+            o = o.astype(x.dtype)
+            new_cache.update(k=ck, v=cv)
         else:
-            ck = jax.lax.dynamic_update_slice(
-                cache["k"], k.astype(cache["k"].dtype), (0, length, 0, 0)
-            )
-            cv = jax.lax.dynamic_update_slice(
-                cache["v"], v.astype(cache["v"].dtype), (0, length, 0, 0)
-            )
-            ck_f, cv_f = ck.astype(jnp.float32), cv.astype(jnp.float32)
-        smax = ck.shape[1]
-        kpos = jnp.arange(smax)
-        qpos = length + jnp.arange(s)
-        mask = qpos[:, None] >= kpos[None, :]
-        mask &= (kpos < length + s)[None, :]
-        if window is not None:
-            mask &= (qpos[:, None] - kpos[None, :]) < window
-        sc = jnp.einsum(
-            "bqhgd,bkhd->bhgqk", qg.astype(jnp.float32), ck_f
-        ) / jnp.sqrt(head_dim)
-        sc = jnp.where(mask[None, None, None], sc, NEG_INF)
-        p = jax.nn.softmax(sc, axis=-1)
-        o = jnp.einsum("bhgqk,bkhd->bqhgd", p, cv_f)
-        o = o.astype(x.dtype)
-        new_cache.update(k=ck, v=cv)
-    else:
-        if _cp_wanted(attn_cp, n_heads):
-            o = flash_attention_cp(qg, k, v, causal=True, window=window)
-        elif s <= flash_threshold:
-            o = _dense_attention(qg, k, v, causal=True, window=window)
-        else:
-            o = flash_attention(qg, k, v, causal=True, window=window)
-        new_cache = None
+            if _cp_wanted(attn_cp, n_heads):
+                o = flash_attention_cp(qg, k, v, causal=True, window=window)
+            elif s <= flash_threshold:
+                o = _dense_attention(qg, k, v, causal=True, window=window)
+            else:
+                o = flash_attention(qg, k, v, causal=True, window=window)
+            new_cache = None
 
     o = o.reshape(b, s, n_heads * head_dim)
     out = L.linear_apply(params["wo"], o, acfg, key=ks[3])

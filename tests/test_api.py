@@ -19,6 +19,7 @@ from repro.distributed import sharding as shd
 from repro.exec.run import dispatch_count, reset_dispatch_count
 from repro.models import ecg as ECG
 from repro.models import transformer as T
+from repro.launch.mesh import make_mesh
 
 KEY = jax.random.PRNGKey(7)
 ACFG = AnalogConfig(noise=NOISELESS)
@@ -40,7 +41,7 @@ def _lm_batch(cfg, b=2, s=8, seed=1):
 
 @pytest.fixture()
 def mesh11():
-    with shd.use_mesh(jax.make_mesh((1, 1), ("data", "model"))) as m:
+    with shd.use_mesh(make_mesh((1, 1), ("data", "model"))) as m:
         yield m
 
 
@@ -263,7 +264,7 @@ class TestMeshShardedPlans:
         prompt = np.arange(6) % TINY.vocab_size
         r_plain = ServeEngine(TINY, run, params, batch_size=2, max_len=32) \
             .serve([Request(0, prompt, 4)])[0]
-        with shd.use_mesh(jax.make_mesh((1, 1), ("data", "model"))):
+        with shd.use_mesh(make_mesh((1, 1), ("data", "model"))):
             r_mesh = ServeEngine(TINY, run, params, batch_size=2,
                                  max_len=32) \
                 .serve([Request(0, prompt, 4)])[0]

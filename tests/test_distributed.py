@@ -10,13 +10,14 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed import sharding as shd
+from repro.launch.mesh import make_mesh
 
 
 @pytest.fixture()
 def mesh22():
     if len(jax.devices()) < 4:
         pytest.skip("needs 4 devices (run under forced host device count)")
-    with shd.use_mesh(jax.make_mesh((2, 2), ("data", "model"))) as m:
+    with shd.use_mesh(make_mesh((2, 2), ("data", "model"))) as m:
         yield m
 
 
@@ -47,7 +48,7 @@ class TestSpecResolution:
         if len(jax.devices()) < 4:
             pytest.skip("needs 4 devices")
         with shd.use_mesh(
-            jax.make_mesh((2, 2, 1), ("pod", "data", "model"))
+            make_mesh((2, 2, 1), ("pod", "data", "model"))
         ):
             spec = shd.resolve_spec(("batch", None), (8, 4))
             assert spec == P(("pod", "data"), None)
@@ -69,7 +70,7 @@ class TestMeshInvariance:
 
         p1 = analog_linear_init(jax.random.PRNGKey(3), 256, 64)
         if len(jax.devices()) >= 4:
-            with shd.use_mesh(jax.make_mesh((2, 2), ("data", "model"))):
+            with shd.use_mesh(make_mesh((2, 2), ("data", "model"))):
                 p2 = analog_linear_init(jax.random.PRNGKey(3), 256, 64)
         else:
             p2 = analog_linear_init(jax.random.PRNGKey(3), 256, 64)
@@ -112,7 +113,7 @@ class TestShardedTrainStep:
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, 32)).astype(
             jnp.bfloat16
         )
-        with shd.use_mesh(jax.make_mesh((2, 2), ("data", "model"))):
+        with shd.use_mesh(make_mesh((2, 2), ("data", "model"))):
             y_sm, aux1 = M.moe_apply(
                 params, x, acfg=DIGITAL, top_k=2, dispatch="shard_map"
             )
@@ -134,11 +135,81 @@ class TestShardedTrainStep:
         k = jax.random.normal(jax.random.PRNGKey(1), (2, 64, 2, 16))
         v = jax.random.normal(jax.random.PRNGKey(2), (2, 64, 2, 16))
         plain = flash_attention(q, k, v, block_q=16, block_kv=16)
-        with shd.use_mesh(jax.make_mesh((2, 2), ("data", "model"))):
+        with shd.use_mesh(make_mesh((2, 2), ("data", "model"))):
             cp = flash_attention_cp(q, k, v, block_q=16, block_kv=16)
         np.testing.assert_allclose(
             np.asarray(plain), np.asarray(cp), atol=3e-5
         )
+
+
+_COLUMN_PARALLEL_SCRIPT = """
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.distributed import sharding as shd
+from repro.kernels import ops
+from repro.launch.mesh import make_mesh
+k = jax.random.PRNGKey(0)
+a_p = jnp.round(jax.random.uniform(k, (8, 256)) * 31)
+a_n = jnp.round(jax.random.uniform(jax.random.fold_in(k, 1), (8, 256)) * 31)
+w = jax.random.normal(jax.random.fold_in(k, 2), (256, 96)) * 20
+g = jnp.full((96,), 0.02)
+off = jax.random.normal(jax.random.fold_in(k, 3), (2, 96))
+def both(a_p, a_n, w, g, off):
+    return (ops.analog_mvm_split(a_p, a_n, w, g, off, 128, True, True),
+            ops.analog_mvm_infer(a_p, None, w, g, off, use_pallas=True,
+                                 epilogue=("relu_shift", 3)))
+one = both(a_p, a_n, w, g, off)
+for shape in ((1, 4), (2, 2), (4, 1)):
+    mesh = make_mesh(shape, ("data", "model"))
+    with shd.use_mesh(mesh):
+        rows, cols = shd.vmm_axes(8, 96)
+        assert (rows, cols) == ("data", "model")
+        put = lambda x, *spec: jax.device_put(x, NamedSharding(mesh, P(*spec)))
+        args = (a_p, a_n, put(w, None, cols), put(g, cols),
+                put(off, None, cols))
+        f = jax.jit(both)
+        hlo = f.lower(*args).compile().as_text()
+        got = f(*args)
+    # weights placed as the plan leaves are: no collective at all
+    for op in ("all-gather", "all-to-all", "collective-permute",
+               "all-reduce", "reduce-scatter"):
+        assert op not in hlo, (shape, op)
+    assert got[0].sharding.device_set == set(mesh.devices.flat)
+    for x, y in zip(one, got):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+with shd.use_mesh(make_mesh((1, 4), ("data", "model"))):
+    try:
+        jax.jit(both)(a_p, a_n, w[:, :90], g[:90], off[:, :90])
+    except ValueError as e:
+        assert "output columns" in str(e), e
+    else:
+        raise AssertionError("90 columns ran split over 4 devices")
+print("OK")
+"""
+
+
+class TestColumnParallelKernels:
+    def test_pallas_vmm_on_4_device_mesh_matches_one_device(self):
+        """Mosaic kernels cannot be partitioned automatically: under a
+        mesh the VMM kernels split rows over ``data`` and output columns
+        over ``model`` (kernels.ops._column_parallel), which must
+        reproduce the one-device result exactly on (1, 4), (2, 2) and
+        (4, 1) meshes, move nothing between devices when the weights sit
+        where the plan leaves are placed, and refuse a column count the
+        mesh does not divide.  Run in a child with 4 host devices (this
+        process's device count is fixed at JAX start-up)."""
+        import subprocess
+        import sys
+
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        env = {**os.environ, "JAX_PLATFORMS": "cpu",
+               "XLA_FLAGS": "--xla_force_host_platform_device_count=4",
+               "PYTHONPATH": os.path.join(root, "src")}
+        proc = subprocess.run([sys.executable, "-c", _COLUMN_PARALLEL_SCRIPT],
+                              env=env, capture_output=True, text=True,
+                              timeout=300)
+        assert proc.returncode == 0, proc.stderr[-3000:]
+        assert proc.stdout.strip().endswith("OK")
 
 
 class TestServeEngine:

@@ -444,9 +444,18 @@ class TestMegakernel:
         g_per = jax.grad(loss)(stack, False)
         g_mk = jax.grad(loss)(stack, True)
         for i, (gp, gm) in enumerate(zip(g_per, g_mk)):
+            # Each dW entry sums the STE backward over the batch rows, and
+            # the two routes sum in different orders (per-layer custom-VJP
+            # matmuls vs the ref chain's per-chunk einsums).  A
+            # mathematically neutral batch permutation moves ONE route's
+            # conv dW by up to 2 fp32 ulps of its largest entry (the
+            # megakernel route by up to 4.5), so entries that cancel to
+            # near zero cannot meet a pure rtol: the absolute floor is 16
+            # ulps of the array's largest entry
+            ulp = np.finfo(np.float32).eps * np.abs(np.asarray(gp["w"])).max()
             np.testing.assert_allclose(
                 np.asarray(gp["w"]), np.asarray(gm["w"]),
-                rtol=1e-6, atol=1e-6,
+                rtol=1e-6, atol=16 * ulp,
             )
             # gain is frozen INSIDE the analog passes on both paths; the
             # only gain gradient is the last layer's differentiable

@@ -28,6 +28,7 @@ from repro.models import attention as A
 from repro.models import moe as M
 from repro.models import rwkv as R
 from repro.models import transformer as T
+from repro.launch.mesh import make_mesh
 
 KEY = jax.random.PRNGKey(7)
 ACFG = AnalogConfig(noise=NOISELESS)
@@ -42,7 +43,7 @@ def _cfg(mode, pallas, **kw):
 
 @pytest.fixture()
 def mesh11():
-    with shd.use_mesh(jax.make_mesh((1, 1), ("data", "model"))) as m:
+    with shd.use_mesh(make_mesh((1, 1), ("data", "model"))) as m:
         yield m
 
 

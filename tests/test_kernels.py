@@ -7,7 +7,9 @@ import pytest
 
 from repro.kernels import ops
 from repro.kernels import ref as R
-from repro.kernels.analog_mvm import analog_mvm_pallas
+from repro.kernels.analog_mvm import (
+    analog_mvm_pallas, analog_mvm_split_pallas,
+)
 from repro.kernels.preproc import maxmin_pool_pallas
 
 KEY = jax.random.PRNGKey(0)
@@ -20,6 +22,17 @@ MVM_SHAPES = [
     (17, 512, 129),
     (64, 1024, 256),
 ]
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr``, nested ones (jit, pallas_call) too."""
+    for e in jaxpr.eqns:
+        yield e
+        for v in e.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
 
 
 def _mvm_inputs(m, k, n, dtype=jnp.float32, with_noise=True):
@@ -47,18 +60,24 @@ class TestAnalogMVMKernel:
         tol = 0.0 if faithful else 1.0   # fast mode: summation-order LSB
         assert float(jnp.abs(got - want).max()) <= tol
 
-    @pytest.mark.parametrize("m,k,n", [(8, 128, 64), (64, 256, 256)])
-    def test_bf16_within_one_lsb(self, m, k, n):
-        """bf16 MXU path: codes are exact; fpn gain rounding costs <= 1 ADC
-        LSB per chunk vs the fp32 oracle."""
-        a, w, gain, off = _mvm_inputs(m, k, n)
-        got = analog_mvm_pallas(
-            a, w, gain, off, faithful=True, interpret=True,
-            compute_dtype=jnp.bfloat16,
-        )
-        want = R.analog_mvm_ref(a, w, gain, off, faithful=True)
-        n_chunks = k // 128
-        assert float(jnp.abs(got - want).max()) <= n_chunks
+    @pytest.mark.parametrize("kernel", ["single", "split"])
+    def test_kernel_dots_contract_at_full_precision(self, kernel):
+        """The effective weights carry fixed-pattern and calibration gains
+        that bf16 cannot hold, and Mosaic may round fp32 dot operands to
+        bf16 unless the dot states its precision: every in-kernel dot must
+        take fp32 operands at HIGHEST."""
+        a, w, gain, off = _mvm_inputs(8, 256, 64)
+        if kernel == "single":
+            fn = lambda: analog_mvm_pallas(a, w, gain, off)
+        else:
+            fn = lambda: analog_mvm_split_pallas(a, a, w, gain, off)
+        dots = [e for e in _eqns(jax.make_jaxpr(fn)().jaxpr)
+                if e.primitive.name == "dot_general"]
+        assert len(dots) == (1 if kernel == "single" else 2)
+        for e in dots:
+            hi = jax.lax.Precision.HIGHEST
+            assert e.params["precision"] == (hi, hi)
+            assert all(v.aval.dtype == jnp.float32 for v in e.invars)
 
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     def test_input_dtypes(self, dtype):

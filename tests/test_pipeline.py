@@ -7,6 +7,7 @@ import pytest
 
 from repro.distributed import sharding as shd
 from repro.distributed.pipeline import pipeline_apply, split_stages
+from repro.launch.mesh import make_mesh
 
 
 def _stage_fn(p, x):
@@ -27,7 +28,7 @@ class TestPipeline:
         n_stages, n_micro, mb, d = 2, 4, 3, 8
         params = _make_params(jax.random.PRNGKey(0), n_stages, d)
         x = jax.random.normal(jax.random.PRNGKey(1), (n_micro, mb, d))
-        with shd.use_mesh(jax.make_mesh((2,), ("pod",))):
+        with shd.use_mesh(make_mesh((2,), ("pod",))):
             out = pipeline_apply(_stage_fn, params, x)
         # sequential reference
         want = x
@@ -44,7 +45,7 @@ class TestPipeline:
         x = jax.random.normal(jax.random.PRNGKey(3), (n_micro, mb, d))
 
         def loss(params):
-            with shd.use_mesh(jax.make_mesh((2,), ("pod",))):
+            with shd.use_mesh(make_mesh((2,), ("pod",))):
                 return (pipeline_apply(_stage_fn, params, x) ** 2).sum()
 
         g = jax.grad(loss)(params)
