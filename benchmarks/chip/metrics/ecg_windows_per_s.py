@@ -1,0 +1,7 @@
+"""ecg_windows_per_s: every window classified over all of the window's
+time, up to the end of the last request started inside it."""
+
+
+def read(run):
+    rec = run.record
+    return rec.units / rec.elapsed_s if rec.elapsed_s > 0 else None
