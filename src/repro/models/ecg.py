@@ -82,8 +82,9 @@ def _im2col(x, taps, stride):
     b, c, t = x.shape
     npos = (t - taps) // stride + 1
     idx = jnp.arange(npos)[:, None] * stride + jnp.arange(taps)[None, :]
-    cols = x[:, :, idx]                      # [B, C, npos, taps]
-    return cols.transpose(0, 2, 3, 1).reshape(b, npos, taps * c)
+    with jax.named_scope("ecg.im2col"):
+        cols = x[:, :, idx]                  # [B, C, npos, taps]
+        return cols.transpose(0, 2, 3, 1).reshape(b, npos, taps * c)
 
 
 def ecg_module_spec(cfg: ECGConfig = ECGConfig(), *,
@@ -111,7 +112,8 @@ def ecg_module_spec(cfg: ECGConfig = ECGConfig(), *,
     def _apply(model, x, *, train: bool = False, key=None,
                megakernel="auto"):
         cols = _im2col(x, cfg.conv_taps, cfg.conv_stride)
-        out = model.run_stack(cols, key=key, megakernel=megakernel)
+        with jax.named_scope("ecg.analog_chain"):
+            out = model.run_stack(cols, key=key, megakernel=megakernel)
         return _pool_class_copies(out, cfg, train)
 
     return api.ModuleSpec(
@@ -155,8 +157,9 @@ def ecg_lower(params, acfg: AnalogConfig, cfg: ECGConfig = ECGConfig(), *,
 def _pool_class_copies(out, cfg: ECGConfig, train: bool):
     """§III-B: max pooling over the class-copy neurons during training
     (robustness); average pooling at inference (noise averaging)."""
-    out = out.reshape(out.shape[0], cfg.classes, cfg.class_copies)
-    return out.max(axis=-1) if train else out.mean(axis=-1)
+    with jax.named_scope("ecg.class_pool"):
+        out = out.reshape(out.shape[0], cfg.classes, cfg.class_copies)
+        return out.max(axis=-1) if train else out.mean(axis=-1)
 
 
 def ecg_apply_plan(plan, x, cfg: ECGConfig = ECGConfig(), *,
@@ -166,7 +169,8 @@ def ecg_apply_plan(plan, x, cfg: ECGConfig = ECGConfig(), *,
     from repro.exec.run import run as run_plan
 
     cols = _im2col(x, cfg.conv_taps, cfg.conv_stride)
-    out = run_plan(plan, cols, key=key)
+    with jax.named_scope("ecg.analog_chain"):
+        out = run_plan(plan, cols, key=key)
     return _pool_class_copies(out, cfg, train)
 
 

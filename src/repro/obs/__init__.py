@@ -20,11 +20,12 @@ Render with ``python -m repro.obs run.jsonl``.
 from . import energy, metrics, report, trace
 from .energy import PAPER_UJ_PER_INFERENCE, PAPER_US_PER_INFERENCE, energy_report
 from .metrics import counter, gauge, histogram, registry, reset_metrics
-from .trace import Trace, active_trace, collect, event, log, span, time_block, timeit
+from .trace import (Trace, active_trace, collect, event, log, observed, span,
+                    time_block, timeit)
 
 __all__ = [
     "trace", "metrics", "energy", "report",
-    "Trace", "collect", "active_trace", "span", "event", "log",
+    "Trace", "collect", "active_trace", "observed", "span", "event", "log",
     "timeit", "time_block",
     "counter", "gauge", "histogram", "registry", "reset_metrics",
     "energy_report", "PAPER_US_PER_INFERENCE", "PAPER_UJ_PER_INFERENCE",

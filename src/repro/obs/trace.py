@@ -9,6 +9,13 @@ Design constraints (see ISSUE 9):
 - Recording is opt-in: ``span()`` / ``event()`` are no-ops (beyond two
   ``perf_counter`` calls) unless a collector opened by ``collect()`` is
   active, so instrumented library code costs ~nothing in normal runs.
+- While a profiler session is open (``jax.profiler.start_trace``, or a
+  capture through the profiler server) every span is also a
+  ``jax.profiler.TraceAnnotation`` of the same name: it lands in the
+  trace's ``/host:CPU`` plane, on the device ops' clock, so a program
+  span can name what the host did in a device idle gap.  With no session
+  open no annotation is made.  ``observed()`` tells instrumentation that
+  only feeds a collector or a profiler whether either is listening.
 - One timing implementation: ``timeit()`` is the best-of-blocks loop the
   benchmarks gate on, so bench entries and serve telemetry share it.
 
@@ -24,11 +31,14 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Iterator, Optional
 
+from jax.profiler import TraceAnnotation
+
 __all__ = [
     "Trace",
     "Span",
     "collect",
     "active_trace",
+    "observed",
     "span",
     "event",
     "log",
@@ -124,6 +134,14 @@ def active_trace() -> Optional[Trace]:
     return _ACTIVE
 
 
+def observed() -> bool:
+    """True while a collector or a profiler session is recording."""
+    return _ACTIVE is not None or TraceAnnotation.is_enabled()
+
+
+_NO_ANNOTATION = contextlib.nullcontext()
+
+
 @contextlib.contextmanager
 def collect(name: str = "trace") -> Iterator[Trace]:
     """Open a collector: spans/events inside the block are recorded."""
@@ -159,21 +177,25 @@ def end(tr: Optional[Trace] = None) -> Optional[Trace]:
 @contextlib.contextmanager
 def span(name: str, **meta: Any) -> Iterator[Span]:
     """Time a region.  Always yields a Span (so callers can read
-    ``sp.dur_us`` or ``sp.add(...)``); records only when collecting."""
+    ``sp.dur_us`` or ``sp.add(...)``); records only when collecting, and
+    writes ``name`` into the profiler's trace only while one is open."""
     tr = _ACTIVE
     if tr is not None:
         tr._stack.append(name)
         path = "/".join(tr._stack)
     else:
         path = name
-    sp = Span(name=name, path=path, t_us=clock_us(), meta=dict(meta))
-    try:
-        yield sp
-    finally:
-        sp.dur_us = clock_us() - sp.t_us
-        if tr is not None:
-            tr._stack.pop()
-            tr.record_span(sp)
+    ann = TraceAnnotation(name) if TraceAnnotation.is_enabled() \
+        else _NO_ANNOTATION
+    with ann:
+        sp = Span(name=name, path=path, t_us=clock_us(), meta=dict(meta))
+        try:
+            yield sp
+        finally:
+            sp.dur_us = clock_us() - sp.t_us
+            if tr is not None:
+                tr._stack.pop()
+                tr.record_span(sp)
 
 
 def event(name: str, **meta: Any) -> None:

@@ -304,6 +304,14 @@ class TestMegakernelKnob:
         np.testing.assert_array_equal(np.asarray(y_auto), np.asarray(y_off))
         np.testing.assert_array_equal(np.asarray(y_auto), np.asarray(y_on))
 
+    def test_ecg_layers_carry_named_scopes(self):
+        """The chain's stages are named in the lowered program's op
+        metadata, where a device trace can attribute time to them."""
+        model, x = self._model()
+        text = jax.jit(model.apply).lower(x).as_text(debug_info=True)
+        for scope in ("ecg.im2col", "ecg.analog_chain", "ecg.class_pool"):
+            assert scope in text, scope
+
     def test_float_glue_spec_not_packed(self):
         cfg = ECG.ECGConfig()
         params = ECG.ecg_init(jax.random.PRNGKey(0), cfg)

@@ -1,5 +1,6 @@
 """Data-substrate tests: ECG synthesis statistics, bit-exact preprocessing
 chain, pipeline determinism/shardability (hypothesis property tests)."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -10,9 +11,10 @@ hypothesis = pytest.importorskip(
 import hypothesis.strategies as st  # noqa: E402
 from hypothesis import given, settings  # noqa: E402
 
+from repro import obs
 from repro.data.ecg_synth import ECGDatasetConfig, make_dataset, synth_record
 from repro.data.lm_data import DataConfig, SyntheticLM
-from repro.data.preprocess import preprocess
+from repro.data.preprocess import _preprocess, preprocess
 
 
 class TestECGSynth:
@@ -84,6 +86,76 @@ class TestPreprocess:
         raw = np.random.default_rng(1).normal(2048, 300, (2, 2, 4033))
         out = np.asarray(preprocess(jnp.asarray(raw.astype(np.float32))))
         assert out.min() >= 0
+
+
+class TestPreprocessHostStage:
+    """``preprocess`` is a host stage around the jitted chain: one span
+    and one ``ecg.preprocess_us`` sample per eager call while a collector
+    or a profiler records, none otherwise or at trace time, and the same
+    codes as the chain."""
+
+    RAW = np.random.default_rng(2).integers(0, 4096, (3, 2, 4033)).astype(
+        np.float32)
+
+    @staticmethod
+    def _samples():
+        h = obs.registry().get("ecg.preprocess_us")
+        return 0 if h is None else h.count
+
+    @pytest.mark.parametrize("use_pallas", [False, True])
+    def test_equals_jitted_chain_bit_for_bit(self, use_pallas):
+        raw = jnp.asarray(self.RAW)
+        want = _preprocess(raw, use_pallas=use_pallas)
+        got = preprocess(raw, use_pallas=use_pallas)
+        with obs.collect():
+            got_observed = preprocess(raw, use_pallas=use_pallas)
+        for g in (got, got_observed):
+            assert g.dtype == want.dtype and g.shape == want.shape
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(want))
+
+    def test_each_eager_call_adds_one_sample(self):
+        raw = jnp.asarray(self.RAW)
+        before = self._samples()
+        with obs.collect() as tr:
+            for _ in range(3):
+                preprocess(raw)
+        assert self._samples() == before + 3
+        h = obs.registry().get("ecg.preprocess_us")
+        assert all(v > 0 for v in h.samples[-3:])
+        assert [s["path"] for s in tr.spans()] == ["ecg.preprocess"] * 3
+
+    def test_unobserved_call_adds_no_sample(self):
+        raw = jnp.asarray(self.RAW)
+        assert not obs.observed()
+        before = self._samples()
+        preprocess(raw)
+        assert self._samples() == before
+
+    def test_inside_jit_adds_no_sample(self):
+        raw = jnp.asarray(self.RAW)
+        before = self._samples()
+        with obs.collect():
+            got = jax.jit(lambda r: preprocess(r) + 0)(raw)
+        assert self._samples() == before
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.asarray(_preprocess(raw)))
+
+    def test_repeated_calls_do_not_grow_jit_cache(self):
+        raw = jnp.asarray(self.RAW)
+        preprocess(raw)
+        size = _preprocess._cache_size()
+        for _ in range(3):
+            preprocess(raw)
+        with obs.collect():
+            for _ in range(3):
+                preprocess(raw)
+        assert _preprocess._cache_size() == size
+
+    def test_stages_carry_named_scopes(self):
+        text = _preprocess.lower(jnp.asarray(self.RAW)).as_text(
+            debug_info=True)
+        for scope in ("ecg.derivative", "ecg.maxmin_pool", "ecg.quantize"):
+            assert scope in text, scope
 
 
 class TestLMData:

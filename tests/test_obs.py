@@ -110,6 +110,64 @@ class TestTrace:
         assert ev["meta"]["us_per_call"] == pytest.approx(us, abs=0.001)
 
 
+def _host_events(trace_dir, names):
+    """``{name: [(thread line, start_ns, end_ns)]}`` of the ``/host:CPU``
+    events named in ``names`` in the profile written under ``trace_dir``."""
+    from jax.profiler import ProfileData
+
+    (path,) = trace_dir.glob("plugins/profile/*/*.xplane.pb")
+    out = {n: [] for n in names}
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name in out:
+                    out[ev.name].append(
+                        (line.name, ev.start_ns, ev.start_ns + ev.duration_ns))
+    return out
+
+
+class TestProfilerBridge:
+    """Every ``obs.span`` is a host span in the profiler's trace."""
+
+    def test_span_lands_in_profile_inside_enclosing_annotation(self, tmp_path):
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            with jax.profiler.TraceAnnotation("bench.outer"):
+                with obs_trace.span("obs.bridge") as sp:
+                    jax.block_until_ready(jnp.ones(8) + 1)
+        finally:
+            jax.profiler.stop_trace()
+        got = _host_events(tmp_path, ("bench.outer", "obs.bridge"))
+        (outer,), (inner,) = got["bench.outer"], got["obs.bridge"]
+        assert inner[0] == outer[0]                   # one host thread
+        assert outer[1] <= inner[1] < inner[2] <= outer[2]
+        # the span's own clock agrees with the profiler's extent
+        assert sp.dur_us <= (inner[2] - inner[1]) / 1e3 + 50.0
+
+    def test_span_without_profiler_or_collector_still_times(self):
+        assert obs_trace.active_trace() is None
+        assert not obs_trace.observed()
+        with obs_trace.span("obs.bridge") as sp:
+            jax.block_until_ready(jnp.ones(8) + 1)
+        assert sp.dur_us > 0.0 and sp.path == "obs.bridge"
+
+    def test_observed_while_collector_or_profiler_records(self, tmp_path):
+        assert not obs_trace.observed()
+        with obs_trace.collect():
+            assert obs_trace.observed()
+        assert not obs_trace.observed()
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            assert obs_trace.observed()
+        finally:
+            jax.profiler.stop_trace()
+        assert not obs_trace.observed()
+
+
 # ---------------------------------------------------------------------------
 # metrics: counters/gauges/histograms + JSONL round-trip
 # ---------------------------------------------------------------------------
