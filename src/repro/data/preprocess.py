@@ -48,17 +48,22 @@ def preprocess(raw: jax.Array, *, window: int = POOL_WINDOW,
     return codes
 
 
-@functools.partial(jax.jit, static_argnames=("window", "use_pallas"))
+@functools.partial(jax.jit, static_argnames=("window", "quant_shift",
+                                             "use_pallas"))
 def _preprocess(raw: jax.Array, *, window: int = POOL_WINDOW,
                 quant_shift: int = 4, use_pallas: bool = False) -> jax.Array:
-    """The jitted chain of :func:`preprocess`, one named scope a stage."""
+    """The jitted chain of :func:`preprocess`, one named scope a stage;
+    the Pallas path is one kernel for all three."""
+    if use_pallas:
+        with jax.named_scope("ecg.maxmin_pool"):
+            return kernel_ops.preprocess_codes(raw, window, quant_shift)
     with jax.named_scope("ecg.derivative"):
         deriv = jnp.diff(raw, axis=-1)                   # discrete derivative
         t = deriv.shape[-1]
         t_trunc = (t // window) * window
         deriv = deriv[..., :t_trunc]
     with jax.named_scope("ecg.maxmin_pool"):
-        pooled = kernel_ops.maxmin_pool(deriv, window, use_pallas=use_pallas)
+        pooled = kernel_ops.maxmin_pool(deriv, window, use_pallas=False)
     with jax.named_scope("ecg.quantize"):
         codes = jnp.floor(pooled / (1 << quant_shift))
         return jnp.clip(codes, 0, BSS2.a_max).astype(jnp.float32)

@@ -28,7 +28,7 @@ from repro.kernels import ref as ref_lib
 from repro.kernels.analog_mvm import analog_mvm_pallas, analog_mvm_split_pallas
 from repro.kernels import analog_plan
 from repro.kernels.analog_plan import analog_plan_pallas
-from repro.kernels.preproc import maxmin_pool_pallas
+from repro.kernels.preproc import maxmin_pool_2d_pallas, preprocess_pallas
 
 
 def _on_tpu() -> bool:
@@ -422,7 +422,17 @@ def maxmin_pool(x: jax.Array, window: int = 32,
     shape = x.shape
     x2 = x.reshape(-1, shape[-1])
     if use:
-        y = maxmin_pool_pallas(x2, window=window, interpret=_interpret())
+        y = maxmin_pool_2d_pallas(x2, window=window, interpret=_interpret())
     else:
         y = ref_lib.maxmin_pool_ref(x2, window=window)
     return y.reshape(shape[:-1] + (shape[-1] // window,))
+
+
+def preprocess_codes(raw: jax.Array, window: int, quant_shift: int
+                     ) -> jax.Array:
+    """[..., C, T] raw samples -> [..., C, (T-1)//window] 5-bit codes:
+    the whole pre-processing chain in the fused Pallas kernel."""
+    shape = raw.shape
+    y = preprocess_pallas(raw.reshape((-1,) + shape[-2:]), window=window,
+                          quant_shift=quant_shift, interpret=_interpret())
+    return y.reshape(shape[:-1] + y.shape[-1:])

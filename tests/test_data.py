@@ -15,6 +15,33 @@ from repro import obs
 from repro.data.ecg_synth import ECGDatasetConfig, make_dataset, synth_record
 from repro.data.lm_data import DataConfig, SyntheticLM
 from repro.data.preprocess import _preprocess, preprocess
+from repro.kernels.preproc import preprocess_pallas
+
+
+def fig7_codes(raw, window=32, quant_shift=4):
+    """The Fig. 7 chain in numpy: derivative, max-min over each whole
+    window, floor(. / 2**quant_shift) clipped to [0, 31]."""
+    deriv = np.diff(raw, axis=-1)
+    n = deriv.shape[-1] // window
+    win = deriv[..., :n * window].reshape(deriv.shape[:-1] + (n, window))
+    pooled = win.max(-1) - win.min(-1)
+    return np.clip(np.floor(pooled / 2.0 ** quant_shift), 0, 31)
+
+
+def ramp_windows(b, c, t, window, quant_shift, samples, seed):
+    """f32[b, c, t] raw samples whose windows' max-min spans 0 to about
+    40 codes: some windows quantize to 0, some clip at 31.  ``samples``:
+    "integer" (integer-valued, as ADC counts) or "fraction" (non-integer
+    fp32)."""
+    rng = np.random.default_rng(seed)
+    amp = rng.uniform(0, 40, (b, c, t // window + 1)) * 2.0 ** quant_shift
+    amp = np.repeat(amp, window, axis=-1)[..., :t - 1]
+    step = amp * rng.uniform(-0.5, 0.5, (b, c, t - 1))
+    if samples == "integer":
+        step = np.round(step)
+    start = rng.integers(1024, 3072, (b, c, 1)).astype(np.float64)
+    raw = np.concatenate([start, start + np.cumsum(step, -1)], -1)
+    return raw.astype(np.float32)
 
 
 class TestECGSynth:
@@ -74,11 +101,53 @@ class TestPreprocess:
         rng = np.random.default_rng(0)
         raw = rng.integers(0, 4096, (3, 2, 4033)).astype(np.float32)
         got = np.asarray(preprocess(jnp.asarray(raw)))
-        deriv = np.diff(raw, axis=-1)[..., : 126 * 32]
-        win = deriv.reshape(3, 2, 126, 32)
-        pooled = win.max(-1) - win.min(-1)
-        want = np.clip(np.floor(pooled / 16.0), 0, 31)
-        np.testing.assert_array_equal(got, want)
+        np.testing.assert_array_equal(got, fig7_codes(raw))
+
+    # (B, C, T, window, quant_shift, samples): partial blocks (B = 1, 3,
+    # 9), one and two channels, (T-1) % window != 0, every shift
+    FUSED_CASES = [
+        (1, 2, 4033, 32, 4, "integer"),
+        (3, 1, 4033, 32, 0, "fraction"),
+        (9, 2, 4033, 32, 6, "integer"),
+        (3, 2, 1000, 32, 4, "fraction"),
+        (9, 1, 700, 16, 0, "integer"),
+        (1, 2, 4033, 16, 6, "fraction"),
+        (3, 2, 513, 16, 4, "integer"),
+    ]
+
+    @pytest.mark.parametrize("b,c,t,window,shift,samples", FUSED_CASES)
+    def test_fused_kernel_bit_exact(self, b, c, t, window, shift, samples):
+        """The fused Pallas kernel (interpreted) against the numpy chain,
+        on windows that quantize to 0, into [1, 30] and past 31."""
+        raw = ramp_windows(b, c, t, window, shift, samples,
+                           seed=b * t + window + shift)
+        got = preprocess_pallas(jnp.asarray(raw), window=window,
+                                quant_shift=shift, interpret=True)
+        want = fig7_codes(raw, window, shift)
+        assert got.dtype == jnp.float32 and got.shape == want.shape
+        np.testing.assert_array_equal(np.asarray(got), want)
+        assert want.min() == 0 and want.max() == 31   # both clips hit
+
+    @pytest.mark.parametrize("shape", [(3, 2, 4033), (2, 4033),
+                                       (2, 3, 1, 1000)])
+    def test_pallas_path_equals_jnp_path(self, shape):
+        raw = ramp_windows(int(np.prod(shape[:-2])), shape[-2], shape[-1],
+                           32, 4, "fraction", seed=len(shape))
+        raw = jnp.asarray(raw.reshape(shape))
+        got = preprocess(raw, use_pallas=True)
+        want = preprocess(raw, use_pallas=False)
+        assert got.dtype == want.dtype and got.shape == want.shape
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    @pytest.mark.parametrize("use_pallas", [False, True])
+    def test_call_moves_nothing_but_its_samples(self, use_pallas):
+        """A call on samples already on the device transfers nothing
+        more: ``window`` and ``quant_shift`` are compile-time constants."""
+        raw = jax.device_put(ramp_windows(2, 2, 4033, 32, 4, "integer", 3))
+        with jax.transfer_guard_host_to_device("disallow"):
+            for _ in range(2):      # the compiling call, then a cached one
+                out = preprocess(raw, use_pallas=use_pallas)
+            jax.block_until_ready(out)
 
     def test_positive_activations(self):
         """max-min pooling guarantees non-negative activations (paper:

@@ -10,7 +10,7 @@ from repro.kernels import ref as R
 from repro.kernels.analog_mvm import (
     analog_mvm_pallas, analog_mvm_split_pallas,
 )
-from repro.kernels.preproc import maxmin_pool_pallas
+from repro.kernels.preproc import maxmin_pool_2d_pallas
 
 KEY = jax.random.PRNGKey(0)
 
@@ -227,7 +227,7 @@ class TestMaxMinPoolKernel:
                                             (16, 1024, 16), (3, 96, 32)])
     def test_vs_oracle(self, b, t, window):
         x = jax.random.normal(jax.random.fold_in(KEY, b * t), (b, t))
-        got = maxmin_pool_pallas(x, window=window, interpret=True)
+        got = maxmin_pool_2d_pallas(x, window=window, interpret=True)
         want = R.maxmin_pool_ref(x, window=window)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -239,7 +239,7 @@ class TestMaxMinPoolKernel:
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.int32])
     def test_dtypes(self, dtype):
         x = (jax.random.normal(KEY, (2, 256)) * 100).astype(dtype)
-        got = maxmin_pool_pallas(x, window=32, interpret=True)
+        got = maxmin_pool_2d_pallas(x, window=32, interpret=True)
         want = R.maxmin_pool_ref(x, window=32)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
         assert got.dtype == dtype

@@ -13,15 +13,19 @@ TPU library, so no module-import-time code touches it.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+# the pattern by which the chip benchmark's trace reader finds the
+# pre-processing kernel (``maxmin_pool_roofline``)
+from benchmarks.chip.chipbench.tracing import MAXMIN_POOL
 from repro.kernels.analog_mvm import analog_mvm_pallas, analog_mvm_split_pallas
 from repro.kernels.analog_plan import analog_plan_pallas, default_block_b
-from repro.kernels.preproc import maxmin_pool_pallas
+from repro.kernels.preproc import maxmin_pool_2d_pallas, preprocess_pallas
 
 
 @pytest.fixture(scope="module")
@@ -111,5 +115,27 @@ def test_ecg_megakernel_compiles(one_chip, ecg_pack, batch):
 
 def test_maxmin_pool_compiles(one_chip):
     # 256 two-channel ECG windows: 4032 derivative samples per channel
-    hlo = _hlo(maxmin_pool_pallas, one_chip, (512, 4032))
+    hlo = _hlo(maxmin_pool_2d_pallas, one_chip, (512, 4032))
     assert _kernel_calls(hlo) == 1
+
+
+@pytest.mark.parametrize("batch", [6426, 1], ids=["holter", "window"])
+def test_fused_preprocess_reads_raw_samples_as_they_arrive(one_chip, batch):
+    """A 24-hour recording (6,426 two-channel windows of 4,033 samples)
+    and one window: one kernel, named for the trace reader, takes the raw
+    parameter itself, and no copy, pad or transpose touches it first."""
+    hlo = _hlo(functools.partial(preprocess_pallas, window=32,
+                                 quant_shift=4, interpret=False),
+               one_chip, (batch, 2, 4033))
+    assert _kernel_calls(hlo) == 1
+    entry = hlo.split("\nENTRY ", 1)[1]
+    raw = re.search(r"(%[\w.]+) = f32\[\d+,2,4033\]\S* parameter\(0\)",
+                    entry).group(1)
+    (call,) = [ln for ln in entry.splitlines() if "tpu_custom_call" in ln]
+    name = call.strip().removeprefix("ROOT ").split(" = ")[0]
+    assert MAXMIN_POOL.match(name), name
+    assert f"custom-call({raw})" in call
+    relayout = re.compile(r" (copy|pad|transpose)\(([^)]*)\)")
+    for line in entry.splitlines():
+        for _, operands in relayout.findall(line):
+            assert raw not in re.split(r",\s*", operands), line
