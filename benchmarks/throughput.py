@@ -32,12 +32,15 @@ def analog_layer_shapes(cfg) -> list[tuple[int, int]]:
     shapes = []
     for i in range(cfg.n_layers):
         kind = cfg.layer_kind(i)
-        if kind in ("attn_mlp", "attn_moe"):
-            shapes += [
-                (d, cfg.n_heads * hd), (d, cfg.n_kv_heads * hd),
-                (d, cfg.n_kv_heads * hd), (cfg.n_heads * hd, d),
-            ]
-            if kind == "attn_mlp":
+        if kind in ("attn_mlp", "attn_moe", "conv_mlp", "conv_moe"):
+            if kind.startswith("conv_"):        # LFM2 short-conv in/out
+                shapes += [(d, 3 * d), (d, d)]
+            else:
+                shapes += [
+                    (d, cfg.n_heads * hd), (d, cfg.n_kv_heads * hd),
+                    (d, cfg.n_kv_heads * hd), (cfg.n_heads * hd, d),
+                ]
+            if kind.endswith("_mlp"):
                 ff = cfg.moe_dense_d_ff or cfg.d_ff
                 n_m = 3 if cfg.act == "swiglu" else 2
                 shapes += [(d, ff)] * (n_m - 1) + [(ff, d)]

@@ -1,4 +1,4 @@
-"""Config registry: ``get_arch(name)`` / ``get_smoke(name)`` for the 10
+"""Config registry: ``get_arch(name)`` / ``get_smoke(name)`` for the
 assigned architectures (+ the paper's own ECG network via
 repro.models.ecg.ECGConfig), and the 4 canonical input shapes."""
 from __future__ import annotations
@@ -18,6 +18,7 @@ _MODULES = {
     "qwen3-moe-30b-a3b": "qwen3_moe_30b",
     "zamba2-2.7b": "zamba2_2p7b",
     "musicgen-medium": "musicgen_medium",
+    "lfm2-8b-a1b": "lfm2_8b_a1b",
 }
 
 ARCH_NAMES = list(_MODULES)

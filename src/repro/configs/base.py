@@ -39,6 +39,15 @@ class ArchConfig:
     block: str = "attn"              # attn | rwkv | mamba
     ssm_state: int = 0
     attn_every: int = 0              # Zamba2: shared attn block every k layers
+    # --- per-layer kinds (LFM2) ---
+    # one kind a layer, in place of the block/moe_every rule: token mixer
+    # "attn" | "conv" (gated short convolution), then "_mlp" | "_moe"
+    layer_kinds: tuple = ()
+    conv_taps: int = 3               # short-conv kernel (LFM2 conv_L_cache)
+    qk_norm: bool = False            # RMSNorm over the head dim of q and k
+    # experts one chip holds, routed by LFM2's sigmoid router with a
+    # selection bias (models.moe.held_moe_apply); 0: the capacity MoE
+    held_experts: int = 0
     # --- execution ---
     param_dtype: str = "float32"     # "bfloat16" for the 400B config
     remat: bool = True
@@ -63,6 +72,8 @@ class ArchConfig:
         return self.block in ("rwkv", "mamba")
 
     def layer_kind(self, i: int) -> str:
+        if self.layer_kinds:
+            return self.layer_kinds[i]
         if self.block == "rwkv":
             return "rwkv"
         if self.block == "mamba":
@@ -71,24 +82,42 @@ class ArchConfig:
             return "attn_moe"
         return "attn_mlp"
 
-    def param_count(self) -> int:
-        """Analytic parameter count (embeddings included once if tied)."""
+    def _mixer_params(self, kind: str) -> int:
+        d = self.d_model
+        if kind.startswith("conv_"):
+            # in_proj d -> 3d, out_proj d -> d, depthwise taps
+            return 3 * d * d + d * d + self.conv_taps * d
+        qk = 2 * self.hd if self.qk_norm else 0
+        return (d * self.hd * (self.n_heads + 2 * self.n_kv_heads)
+                + self.n_heads * self.hd * d + qk)
+
+    def _expert_params(self) -> int:
+        return (3 if self.act == "swiglu" else 2) * self.d_model \
+            * self.moe_d_ff
+
+    def param_count(self, held: bool = False) -> int:
+        """Analytic parameter count (embeddings included once if tied).
+        ``held=True`` counts what one chip holds: ``held_experts`` of each
+        expert layer's ``n_experts`` (the router keeps all of them)."""
         d, v = self.d_model, self.vocab_size
         total = v * d * (1 if self.tie_embeddings else 2)
+        n_exp = self.n_experts
+        if held and self.held_experts:
+            n_exp = self.held_experts
         for i in range(self.n_layers):
             kind = self.layer_kind(i)
-            if kind in ("attn_mlp", "attn_moe"):
-                total += d * self.hd * (self.n_heads + 2 * self.n_kv_heads)
-                total += self.n_heads * self.hd * d
-                if kind == "attn_mlp":
+            if kind in ("attn_mlp", "attn_moe", "conv_mlp", "conv_moe"):
+                total += self._mixer_params(kind)
+                if kind.endswith("_mlp"):
                     ff = self.moe_dense_d_ff or self.d_ff
                     total += (3 if self.act == "swiglu" else 2) * d * ff
                 else:
-                    nm = 3 if self.act == "swiglu" else 2
-                    total += self.n_experts * nm * d * self.moe_d_ff
+                    total += n_exp * self._expert_params()
                     total += d * self.n_experts  # router
+                    if self.held_experts:
+                        total += self.n_experts  # selection bias
                     if self.n_shared_experts:
-                        total += nm * d * self.moe_d_ff * self.n_shared_experts
+                        total += self._expert_params() * self.n_shared_experts
             elif kind == "rwkv":
                 total += 5 * d * d + 2 * d * 64 + d * self.d_ff * 2
             elif kind == "mamba":
@@ -104,13 +133,11 @@ class ArchConfig:
         """Params touched per token (MoE: top_k + shared experts only)."""
         if not self.n_experts:
             return self.param_count()
-        d = self.d_model
-        nm = 3 if self.act == "swiglu" else 2
         moe_layers = sum(
             1 for i in range(self.n_layers)
-            if self.layer_kind(i) == "attn_moe"
+            if self.layer_kind(i).endswith("_moe")
         )
-        inactive = moe_layers * nm * d * self.moe_d_ff * (
+        inactive = moe_layers * self._expert_params() * (
             self.n_experts - self.top_k
         )
         return self.param_count() - inactive

@@ -33,7 +33,7 @@ path issues half the dispatches of the two-pass path.
 """
 from __future__ import annotations
 
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -291,6 +291,71 @@ def run_expert_stack(
     )
     y = y_int * (a_scale * lp.w_scale / gain[:, None, None])
     return y.astype(in_dtype)
+
+
+class ExpertRows(NamedTuple):
+    """Routed rows sorted into per-expert groups of whole row tiles (the
+    layout :func:`repro.models.moe.held_rows` builds): tile ``i``
+    (``block_m`` rows) is expert ``tile_expert[i]``'s, the first
+    ``live_tiles[0]`` tiles hold every routed row, and row ``r`` carries
+    a routed token where ``row_live[r]``."""
+
+    row_live: jax.Array          # [R] bool
+    tile_expert: jax.Array       # [R // block_m] int32
+    live_tiles: jax.Array        # [1] int32
+    block_m: int
+
+    @property
+    def row_expert(self) -> jax.Array:
+        return jnp.repeat(self.tile_expert, self.block_m)
+
+
+def run_expert_rows(
+    lp: LayerPlan,
+    x: jax.Array,
+    rows: ExpertRows,
+    cfg: AnalogConfig,
+) -> jax.Array:
+    """Replay an expert-stacked layer plan (leaves ``[E, ...]``, one
+    analog layer per held expert, each with its own fixed pattern, gain
+    and static input LSB) over expert-sorted rows ``x [R, K]`` as ONE
+    grouped dispatch: each row is encoded, run and dequantized as its
+    own expert's :func:`run_layer` would (signed-split input).  Rows that
+    carry no routed token come back as zeros.  Under dynamic calibration
+    the LSB is the abs-max over every routed row of the call."""
+    if lp.signed_input != "split":
+        raise ValueError(
+            f"expert rows run signed-split; the plan is {lp.signed_input!r}"
+        )
+    e = rows.row_expert
+    n_exp = lp.store.codes.shape[0]
+    x = jnp.where(rows.row_live[:, None], x.astype(jnp.float32), 0.0)
+    if cfg.act_calib == "dynamic":
+        a_scale = jnp.broadcast_to(quant.act_scale_from_max(
+            jax.lax.stop_gradient(jnp.abs(x)).max() + 1e-9
+        ), e.shape)
+    else:
+        a_scale = lp.a_scale[e]
+    a_scale = a_scale[:, None]
+    a_pos = _pad_codes(quant.quantize_act(x, a_scale), lp.k_pad)
+    a_neg = _pad_codes(quant.quantize_act(-x, a_scale), lp.k_pad)
+    gain = jnp.broadcast_to(
+        lp.gain.reshape(n_exp, -1), (n_exp, lp.n)).astype(jnp.float32)
+    off = lp.chunk_offset
+    if off is None:
+        off = jnp.zeros((n_exp, lp.k_pad // lp.chunk_rows, lp.n),
+                        jnp.float32)
+    from repro.kernels import ops as kernel_ops
+
+    _count()
+    y_int = kernel_ops.expert_mvm(
+        a_pos, a_neg, lp.w_eff, gain, off, rows.tile_expert,
+        rows.live_tiles, block_m=rows.block_m, chunk_rows=lp.chunk_rows,
+        faithful=cfg.mode != "analog_fast", use_pallas=cfg.use_pallas,
+    )
+    w_scale = lp.w_scale.reshape(n_exp, lp.n)
+    y = y_int * (a_scale * w_scale[e] / gain[e])
+    return jnp.where(rows.row_live[:, None], y, 0.0)
 
 
 def run_group(

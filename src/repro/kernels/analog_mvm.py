@@ -289,3 +289,150 @@ def analog_mvm_split_pallas(
         w_eff.astype(jnp.float32), gain, chunk_offset,
     )
     return out[:m, :n]
+
+
+# --------------------------------------------------------------------------
+# grouped signed-split kernel (held experts)
+# --------------------------------------------------------------------------
+def _grouped_kernel(te_ref, live_ref, ap_ref, an_ref, w_ref, gain_ref,
+                    off_ref, o_ref, accp_ref, accn_ref, *, n_chunks: int,
+                    faithful: bool):
+    """:func:`_split_kernel` over one row tile of one expert's group; a
+    tile past the live ones does nothing."""
+    del te_ref
+    i, c = pl.program_id(0), pl.program_id(2)
+
+    @pl.when(i < live_ref[0])
+    def _live():
+        @pl.when(c == 0)
+        def _init():
+            accp_ref[...] = jnp.zeros_like(accp_ref)
+            accn_ref[...] = jnp.zeros_like(accn_ref)
+
+        w = w_ref[...]
+        gain = gain_ref[...]
+        off = off_ref[...]
+        vp = mxu_dot(ap_ref[...], w) * gain + off
+        vn = mxu_dot(an_ref[...], w) * gain + off
+        if faithful:
+            lo, hi = float(BSS2.adc_min), float(BSS2.adc_max)
+            vp = jnp.clip(jnp.round(vp), lo, hi)
+            vn = jnp.clip(jnp.round(vn), lo, hi)
+        accp_ref[...] += vp
+        accn_ref[...] += vn
+
+        @pl.when(c == n_chunks - 1)
+        def _done():
+            accp, accn = accp_ref[...], accn_ref[...]
+            if not faithful:
+                lo = float(BSS2.adc_min) * n_chunks
+                hi = float(BSS2.adc_max) * n_chunks
+                accp = jnp.clip(jnp.round(accp), lo, hi)
+                accn = jnp.clip(jnp.round(accn), lo, hi)
+            o_ref[...] = accp - accn
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("chunk_rows", "faithful", "block_m", "block_n",
+                     "interpret"),
+)
+def expert_mvm_pallas(
+    a_pos: jax.Array,                     # [G * block_m, K] grouped rows
+    a_neg: jax.Array,                     # [G * block_m, K]
+    w_eff: jax.Array,                     # [E, K, N] held experts
+    gain: jax.Array,                      # [E, N]
+    chunk_offset: jax.Array,              # [E, C, N]
+    tile_expert: jax.Array,               # [G] int32: each tile's expert
+    live_tiles: jax.Array,                # [1] int32: tiles that hold rows
+    *,
+    chunk_rows: int = BSS2.signed_rows,
+    faithful: bool = True,
+    block_m: int = 128,
+    block_n: int = 512,
+    interpret: bool = False,
+) -> jax.Array:
+    """Grouped signed-split analog VMM: row tile ``i`` of the
+    expert-sorted rows runs through expert ``tile_expert[i]``'s weights,
+    with the per-chunk ADC of :func:`analog_mvm_split_pallas`.  The
+    expert ids and the number of live tiles reach the kernel by scalar
+    prefetch, so the weight blocks follow the groups and a tile past
+    ``live_tiles`` fetches nothing new and computes nothing: its output
+    rows are left unwritten.  The chunking is along K, so the zero rows
+    that pad each group to whole tiles change no live row."""
+    r, k = a_pos.shape
+    e, k2, n = w_eff.shape
+    assert k == k2 and k % chunk_rows == 0, (k, k2, chunk_rows)
+    assert r % block_m == 0, (r, block_m)
+    g = r // block_m
+    n_chunks = k // chunk_rows
+    pn = (-n) % block_n
+    if pn:
+        w_eff = jnp.pad(w_eff, ((0, 0), (0, 0), (0, pn)))
+    gain = jnp.pad(jnp.asarray(gain, jnp.float32), ((0, 0), (0, pn)))
+    off = jnp.pad(jnp.asarray(chunk_offset, jnp.float32),
+                  ((0, 0), (0, 0), (0, pn)))[:, :, None, :]
+    np_ = n + pn
+    nj = np_ // block_n
+
+    def last(lv):
+        return jnp.maximum(lv[0], 1) - 1
+
+    def a_map(i, j, c, te, lv):
+        on = i < lv[0]
+        return jnp.where(on, i, last(lv)), jnp.where(on, c, n_chunks - 1)
+
+    def w_map(i, j, c, te, lv):
+        on = i < lv[0]
+        return (jnp.where(on, te[i], te[last(lv)]),
+                jnp.where(on, c, n_chunks - 1), jnp.where(on, j, nj - 1))
+
+    def gain_map(i, j, c, te, lv):
+        on = i < lv[0]
+        return (jnp.where(on, te[i], te[last(lv)]), 0,
+                jnp.where(on, j, nj - 1))
+
+    def off_map(i, j, c, te, lv):
+        on = i < lv[0]
+        return (jnp.where(on, te[i], te[last(lv)]),
+                jnp.where(on, c, n_chunks - 1), 0, jnp.where(on, j, nj - 1))
+
+    def out_map(i, j, c, te, lv):
+        # a dead tile parks on one spare tile past the end, so no live
+        # output block is revisited or overwritten
+        on = i < lv[0]
+        return jnp.where(on, i, g), jnp.where(on, j, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(g, nj, n_chunks),
+        in_specs=[
+            pl.BlockSpec((block_m, chunk_rows), a_map),
+            pl.BlockSpec((block_m, chunk_rows), a_map),
+            pl.BlockSpec((None, chunk_rows, block_n), w_map),
+            pl.BlockSpec((None, 1, block_n), gain_map),
+            pl.BlockSpec((None, None, 1, block_n), off_map),
+        ],
+        out_specs=pl.BlockSpec((block_m, block_n), out_map),
+        scratch_shapes=[
+            pltpu.VMEM((block_m, block_n), jnp.float32),
+            pltpu.VMEM((block_m, block_n), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_grouped_kernel, n_chunks=n_chunks,
+                          faithful=faithful),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(((g + 1) * block_m, np_),
+                                       jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")
+        ),
+        interpret=interpret,
+        name="expert_mvm_pallas",
+    )(
+        tile_expert.astype(jnp.int32), live_tiles.astype(jnp.int32),
+        a_pos.astype(jnp.float32), a_neg.astype(jnp.float32),
+        w_eff.astype(jnp.float32), gain[:, None, :], off,
+    )
+    return out[:r, :n]

@@ -132,10 +132,13 @@ def default_block_b(b: int, m_mult0: int,
 
 def _rmsnorm(h: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
     """RMSNorm over the trailing axis - the exact op order of
-    :func:`repro.models.layers.norm_apply` (rsqrt of the mean square, then
-    the learned scale), so the in-kernel glue is bit-identical to the
-    model path."""
-    y = h * jax.lax.rsqrt(jnp.mean(h * h, axis=-1, keepdims=True) + eps)
+    :func:`repro.models.layers.norm_apply` (rsqrt of the mean square
+    summed in the stated order, then the learned scale), so the in-kernel
+    glue is bit-identical to the model path.  Imported lazily, as the
+    attention glue below: kernels sit below models."""
+    from repro.models.layers import ordered_sum
+
+    y = h * jax.lax.rsqrt(ordered_sum(h * h) / h.shape[-1] + eps)
     return y * scale
 
 

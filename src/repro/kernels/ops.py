@@ -25,7 +25,11 @@ from repro.core.hw import BSS2
 from repro.core.quant import ANALOG_PRECISION
 from repro.distributed import sharding as shd
 from repro.kernels import ref as ref_lib
-from repro.kernels.analog_mvm import analog_mvm_pallas, analog_mvm_split_pallas
+from repro.kernels.analog_mvm import (
+    analog_mvm_pallas,
+    analog_mvm_split_pallas,
+    expert_mvm_pallas,
+)
 from repro.kernels import analog_plan
 from repro.kernels.analog_plan import analog_plan_pallas
 from repro.kernels.preproc import maxmin_pool_2d_pallas, preprocess_pallas
@@ -252,6 +256,49 @@ def analog_mvm_split(
         chunk_rows=chunk_rows, faithful=faithful,
     )
     return y2[:m] - y2[m:]
+
+
+def expert_mvm(
+    a_pos: jax.Array,
+    a_neg: jax.Array,
+    w_eff: jax.Array,
+    gain: jax.Array,
+    chunk_offset: jax.Array,
+    tile_expert: jax.Array,
+    live_tiles: jax.Array,
+    *,
+    block_m: int,
+    chunk_rows: int = BSS2.signed_rows,
+    faithful: bool = True,
+    use_pallas: Optional[bool] = None,
+) -> jax.Array:
+    """Signed-split analog VMM of expert-sorted rows: rows
+    ``[i * block_m, (i + 1) * block_m)`` run through expert
+    ``tile_expert[i]`` of ``w_eff [E, K, N]`` (``gain [E, N]``,
+    ``chunk_offset [E, C, N]``).  Rows of tiles past ``live_tiles[0]``
+    are unspecified.  On the Pallas path one grouped kernel
+    (:func:`repro.kernels.analog_mvm.expert_mvm_pallas`); the jnp path
+    runs every expert over every row and keeps each row's own - the
+    oracle, for small shapes."""
+    use = _on_tpu() if use_pallas is None else use_pallas
+    if use:
+        return expert_mvm_pallas(
+            a_pos, a_neg, w_eff, gain, chunk_offset, tile_expert,
+            live_tiles, chunk_rows=chunk_rows, faithful=faithful,
+            block_m=block_m, interpret=_interpret(),
+        )
+    row_expert = jnp.repeat(tile_expert, block_m)
+    y = jnp.zeros((a_pos.shape[0], w_eff.shape[-1]), jnp.float32)
+    for e in range(w_eff.shape[0]):
+        if faithful:
+            ye = _mvm_split_chunk_scan(a_pos, a_neg, w_eff[e], gain[e],
+                                       chunk_offset[e], chunk_rows)
+        else:
+            ye = ref_lib.analog_mvm_split_ref(
+                a_pos, a_neg, w_eff[e], gain[e], chunk_offset[e],
+                chunk_rows=chunk_rows, faithful=faithful)
+        y = jnp.where((row_expert == e)[:, None], ye, y)
+    return y
 
 
 def _analog_mvm_split_fwd(a_pos, a_neg, w_eff, gain, chunk_offset,

@@ -24,9 +24,10 @@ NEG_INF = -1e30
 
 
 def attention_init(key, d_model, n_heads, n_kv_heads, head_dim, *,
-                   noise: NoiseConfig = NoiseConfig(), dtype=jnp.float32):
+                   noise: NoiseConfig = NoiseConfig(), dtype=jnp.float32,
+                   qk_norm: bool = False):
     ks = jax.random.split(key, 4)
-    return {
+    p = {
         "wq": L.linear_init(ks[0], d_model, n_heads * head_dim,
                             noise=noise, dtype=dtype),
         "wk": L.linear_init(ks[1], d_model, n_kv_heads * head_dim,
@@ -36,15 +37,24 @@ def attention_init(key, d_model, n_heads, n_kv_heads, head_dim, *,
         "wo": L.linear_init(ks[3], n_heads * head_dim, d_model,
                             noise=noise, dtype=dtype),
     }
+    if qk_norm:      # RMSNorm over the head dim of q and of k (LFM2)
+        p["q_norm"] = L.norm_init(head_dim)
+        p["k_norm"] = L.norm_init(head_dim)
+    return p
 
 
-def attention_specs(noise: NoiseConfig = NoiseConfig()):
-    return {
+def attention_specs(noise: NoiseConfig = NoiseConfig(),
+                    qk_norm: bool = False):
+    p = {
         "wq": L.linear_specs("embed", "heads", noise=noise),
         "wk": L.linear_specs("embed", "heads", noise=noise),
         "wv": L.linear_specs("embed", "heads", noise=noise),
         "wo": L.linear_specs("heads", "embed", noise=noise),
     }
+    if qk_norm:
+        p["q_norm"] = L.norm_specs()
+        p["k_norm"] = L.norm_specs()
+    return p
 
 
 # ----------------------------------------------------------- soft attention
@@ -66,7 +76,7 @@ def _dense_attention(q, k, v, *, causal: bool, q_offset=0,
         if window is not None:
             mask &= (qpos - kpos) < window
         s = jnp.where(mask[None, None, None], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
+    p = L.softmax(s)
     o = jnp.einsum("bhgqk,bkhd->bqhgd", p, v.astype(jnp.float32))
     return o.astype(q.dtype)
 
@@ -170,6 +180,9 @@ def attention_apply(params, x, *, positions, acfg: AnalogConfig, n_heads,
     q = q.reshape(b, s, n_heads, head_dim)
     k = k.reshape(b, s, n_kv_heads, head_dim)
     v = v.reshape(b, s, n_kv_heads, head_dim)
+    if "q_norm" in params:
+        q = L.norm_apply(params["q_norm"], q)
+        k = L.norm_apply(params["k_norm"], k)
     rope = L.apply_mrope if mrope else L.apply_rope
     q = rope(q, positions, rope_theta)
     k = rope(k, positions, rope_theta)
@@ -221,19 +234,26 @@ def attention_apply(params, x, *, positions, acfg: AnalogConfig, n_heads,
                     cache["v"], v.astype(cache["v"].dtype), (0, length, 0, 0)
                 )
                 ck_f, cv_f = ck.astype(jnp.float32), cv.astype(jnp.float32)
-            smax = ck.shape[1]
-            kpos = jnp.arange(smax)
-            qpos = length + jnp.arange(s)
-            mask = qpos[:, None] >= kpos[None, :]
-            mask &= (kpos < length + s)[None, :]
-            if window is not None:
-                mask &= (qpos[:, None] - kpos[None, :]) < window
-            sc = jnp.einsum(
-                "bqhgd,bkhd->bhgqk", qg.astype(jnp.float32), ck_f
-            ) / jnp.sqrt(head_dim)
-            sc = jnp.where(mask[None, None, None], sc, NEG_INF)
-            p = jax.nn.softmax(sc, axis=-1)
-            o = jnp.einsum("bhgqk,bkhd->bqhgd", p, cv_f)
+            if s > flash_threshold:
+                # a long prompt into the cache: blockwise, as without one;
+                # causality masks every slot past the prompt's end
+                o = flash_attention(qg.astype(jnp.float32), ck_f, cv_f,
+                                    causal=True, q_offset=length,
+                                    window=window)
+            else:
+                smax = ck.shape[1]
+                kpos = jnp.arange(smax)
+                qpos = length + jnp.arange(s)
+                mask = qpos[:, None] >= kpos[None, :]
+                mask &= (kpos < length + s)[None, :]
+                if window is not None:
+                    mask &= (qpos[:, None] - kpos[None, :]) < window
+                sc = jnp.einsum(
+                    "bqhgd,bkhd->bhgqk", qg.astype(jnp.float32), ck_f
+                ) / jnp.sqrt(head_dim)
+                sc = jnp.where(mask[None, None, None], sc, NEG_INF)
+                p = L.softmax(sc)
+                o = jnp.einsum("bhgqk,bkhd->bqhgd", p, cv_f)
             o = o.astype(x.dtype)
             new_cache.update(k=ck, v=cv)
         else:

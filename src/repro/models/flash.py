@@ -19,6 +19,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.models.layers import ordered_sum
+
 NEG_INF = -1e30
 
 
@@ -67,7 +69,8 @@ def _fwd_blocks(q, k, v, qpos0, *, causal, block_q, block_kv, window):
             m_new = jnp.maximum(m_run, s.max(axis=-1))
             corr = jnp.exp(m_run - m_new)
             p = jnp.exp(s - m_new[..., None])
-            l_new = l_run * corr + p.sum(axis=-1)
+            # each block's sum in the stated order of the glue sums
+            l_new = l_run * corr + ordered_sum(p)[..., 0]
             acc = acc * corr[..., None] + jnp.einsum(
                 "bqhgk,bkhd->bqhgd", p, vi.astype(jnp.float32))
             return (m_new, l_new, acc), None

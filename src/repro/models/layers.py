@@ -81,6 +81,35 @@ def linear_specs(in_name: Optional[str], out_name: Optional[str],
     return specs
 
 
+# ------------------------------------------------------- ordered sums
+def ordered_sum(x: jax.Array) -> jax.Array:
+    """Sum over the last axis in one fixed pairwise order (halves added
+    elementwise, an odd last element carried), keeping the axis.
+
+    This is the order of every digital glue sum of the models: the norms'
+    means, the softmax denominators (dense, decode and each flash block)
+    and the routing weights' totals.  A ``reduce`` leaves its order to
+    the compiler, which picks it per fusion: two programs of the same
+    fp32 math then part in the last bit, and a 5-bit input quantizer
+    downstream turns such a bit into a code.  These adds are elementwise,
+    so the order is part of the arithmetic: every program that keeps it
+    gets the same bits, and a rewrite that sums another way computes a
+    different result."""
+    while x.shape[-1] > 1:
+        h = x.shape[-1] // 2
+        head = x[..., :h] + x[..., h:2 * h]
+        x = head if x.shape[-1] == 2 * h else jnp.concatenate(
+            [head, x[..., 2 * h:]], axis=-1)
+    return x
+
+
+def softmax(x: jax.Array) -> jax.Array:
+    """Softmax over the last axis, its denominator summed in
+    :func:`ordered_sum`'s order."""
+    e = jnp.exp(x - jax.lax.stop_gradient(x.max(-1, keepdims=True)))
+    return e / ordered_sum(e)
+
+
 # ----------------------------------------------------------------- norms
 def norm_init(dim, kind="rmsnorm"):
     p = {"scale": jnp.ones((dim,), jnp.float32)}
@@ -90,13 +119,15 @@ def norm_init(dim, kind="rmsnorm"):
 
 
 def norm_apply(params, x, kind="rmsnorm", eps=1e-5):
+    """RMSNorm or LayerNorm over the last axis, every mean summed in
+    :func:`ordered_sum`'s order."""
     xf = x.astype(jnp.float32)
+    n = xf.shape[-1]
     if kind == "rmsnorm":
-        y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+        y = xf * jax.lax.rsqrt(ordered_sum(xf * xf) / n + eps)
     elif kind == "layernorm":
-        mu = xf.mean(axis=-1, keepdims=True)
-        var = jnp.var(xf, axis=-1, keepdims=True)
-        y = (xf - mu) * jax.lax.rsqrt(var + eps)
+        xc = xf - ordered_sum(xf) / n
+        y = xc * jax.lax.rsqrt(ordered_sum(xc * xc) / n + eps)
     else:
         raise ValueError(kind)
     y = y * params["scale"]

@@ -1,20 +1,37 @@
-"""Mixture-of-Experts layer: top-k routing, capacity-bounded sort-based
-dispatch, batched expert GEMMs with experts sharded over the ``model`` mesh
-axis (expert parallelism).
+"""Mixture-of-Experts layers.
+
+Two layers live here:
+
+- ``moe_apply``: top-k softmax routing with capacity-bounded sort-based
+  dispatch and batched expert GEMMs, experts sharded over the ``model``
+  mesh axis (expert parallelism).  It DROPS tokens over capacity
+  (capacity factor c), so it serves training and the dry-run cells:
+
+    1. router logits -> top-k experts + normalized weights per token
+    2. position-in-expert via a stable sort over expert ids
+    3. scatter tokens into a [E, C, d] buffer (over-capacity tokens drop)
+    4. einsum expert GEMMs, gather back with combine weights
+
+  A dense einsum fallback (``dense=True``) exists for tiny smoke configs
+  where sort/scatter overhead dwarfs the compute.
+
+- ``held_moe_apply``: the served layer of one chip's share of an
+  expert-parallel deployment (LFM2).  It is told which experts it holds
+  (``params["held"]``, data), routes over ALL experts with the published
+  router (sigmoid scores, top-k on score plus a selection bias, weights
+  normalized over the chosen k), and computes only its held experts'
+  part of the result.  It is dropless: the routed (token, held expert)
+  pairs are sorted into per-expert groups of whole row tiles
+  (:func:`held_rows`) and every pair is computed, by one grouped analog
+  dispatch per expert matrix (:func:`repro.exec.run.run_expert_rows`).
 
 Analog mapping (DESIGN.md §5): each expert's FFN matrices are analog tile
 grids; EP places whole experts (= disjoint tile sets) on distinct devices,
 exactly the paper's "individual layers partitioned into chip-sized chunks
-executed in parallel" (§II-D) generalized to the expert dimension.
-
-Dispatch algorithm (dropping, capacity factor c):
-  1. router logits -> top-k experts + normalized weights per token
-  2. position-in-expert via a stable sort over expert ids
-  3. scatter tokens into a [E, C, d] buffer (over-capacity tokens drop)
-  4. einsum expert GEMMs, gather back with combine weights
-
-A dense einsum fallback (``dense=True``) exists for tiny smoke configs where
-sort/scatter overhead dwarfs the compute.
+executed in parallel" (§II-D) generalized to the expert dimension.  Held
+experts are ordinary analog layers stacked on a leading expert axis, each
+with its own fixed pattern, gain and static input LSB; the capacity
+layer's raw expert arrays keep no fixed pattern.
 """
 from __future__ import annotations
 
@@ -268,9 +285,9 @@ def moe_apply(params, x, *, acfg: AnalogConfig, top_k: int,
     e = params["up"].shape[0]
 
     logits = x.astype(jnp.float32) @ params["router"]["w"]        # [B, S, E]
-    probs = jax.nn.softmax(logits, axis=-1)
+    probs = L.softmax(logits)
     topw, topi = jax.lax.top_k(probs, top_k)                      # [B, S, k]
-    topw = topw / jnp.maximum(topw.sum(-1, keepdims=True), 1e-9)
+    topw = topw / jnp.maximum(L.ordered_sum(topw), 1e-9)
 
     # load-balancing aux loss (Switch): E * sum_e f_e * p_e
     me = probs.mean(axis=(0, 1))
@@ -356,3 +373,191 @@ def moe_apply(params, x, *, acfg: AnalogConfig, top_k: int,
     if "shared" in params:
         y = y + L.mlp_apply(params["shared"], x, acfg, act=act, key=key)
     return y, aux
+
+
+# ------------------------------------------------------------------
+# held-expert layer (one chip's share, dropless)
+# ------------------------------------------------------------------
+_EXPERT_MATS = ("up", "gate", "down")
+
+
+def held_moe_init(key, d_model, d_ff, n_experts, n_held, *,
+                  noise: NoiseConfig = NoiseConfig(), dtype=jnp.float32):
+    """Router over all ``n_experts``, a zero selection bias, and the first
+    ``n_held`` experts as stacked analog SwiGLU layers (``held`` names
+    their ids)."""
+    ks = jax.random.split(key, 4)
+
+    def stack(k, din, dout):
+        return jax.vmap(lambda kk: L.linear_init(
+            kk, din, dout, noise=noise, dtype=dtype))(
+                jax.random.split(k, n_held))
+
+    return {
+        "router": {"w": (jax.random.normal(ks[0], (d_model, n_experts))
+                         / jnp.sqrt(d_model)).astype(jnp.float32)},
+        "expert_bias": jnp.zeros((n_experts,), jnp.float32),
+        # ids as float32 (exact below 2**24): a params leaf the gradient
+        # passes over (the optimizer freezes it)
+        "held": jnp.arange(n_held, dtype=jnp.float32),
+        "experts": {"up": stack(ks[1], d_model, d_ff),
+                    "gate": stack(ks[2], d_model, d_ff),
+                    "down": stack(ks[3], d_ff, d_model)},
+    }
+
+
+def held_moe_specs(noise: NoiseConfig = NoiseConfig()):
+    def stacked(a, b):
+        return jax.tree.map(
+            lambda s: ("expert",) + s, L.linear_specs(a, b, noise=noise),
+            is_leaf=lambda x: isinstance(x, tuple))
+
+    return {
+        "router": {"w": (None, None)},
+        "expert_bias": (None,),
+        "held": (None,),
+        "experts": {"up": stacked("embed", "mlp"),
+                    "gate": stacked("embed", "mlp"),
+                    "down": stacked("mlp", "embed")},
+    }
+
+
+def held_moe_module_spec(d_model, d_ff, n_held, *, top_k: int,
+                         noise: NoiseConfig = NoiseConfig()):
+    """Declare one held-expert layer for the api front door: each of the
+    three expert matrices is an analog layer stacked over the held
+    experts (lowered once, per expert, with its own fixed pattern), and
+    ``CompiledModel.apply(x)`` is :func:`held_moe_apply` over the
+    pre-lowered tree."""
+    from repro import api
+
+    def _apply(model, x, **kw):
+        return held_moe_apply(model.lower(), x, acfg=model.acfg,
+                              top_k=top_k, **kw)
+
+    dims = {"up": (d_model, d_ff), "gate": (d_model, d_ff),
+            "down": (d_ff, d_model)}
+    return api.ModuleSpec(
+        name=f"held_moe_{d_model}x{d_ff}x{n_held}",
+        kind="tree",
+        apply_fn=_apply,
+        layers=tuple(api.LayerSpec(f"experts.{m}", *dims[m], stacked=n_held)
+                     for m in _EXPERT_MATS),
+        param_axes=held_moe_specs(noise),
+    )
+
+
+def route(params, u, top_k: int):
+    """Published LFM2 routing over every expert, fp32 at ``HIGHEST``:
+    ``s = sigmoid(u W_r)``, the top-k of ``s + expert_bias`` chosen, their
+    weights ``s / (sum of the chosen s + 1e-6)`` (summed in
+    :func:`repro.models.layers.ordered_sum`'s order).  ``u [T, d]`` ->
+    (chosen expert ids ``[T, k]``, weights ``[T, k]``)."""
+    logits = jnp.einsum("td,de->te", u.astype(jnp.float32),
+                        params["router"]["w"].astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    s = jax.nn.sigmoid(logits)
+    _, sel = jax.lax.top_k(s + params["expert_bias"], top_k)
+    w = jnp.take_along_axis(s, sel, axis=-1)
+    return sel, w / (L.ordered_sum(w) + 1e-6)
+
+
+def tile_rows(n_tokens: int, top_k: int, n_held: int) -> int:
+    """Rows per tile of the grouped expert dispatch: the expected group
+    size rounded up to a power of two, between 8 and 128 (prefill groups
+    fill 128-row tiles, a decode step's few rows take 8-row tiles)."""
+    per = -(-n_tokens * min(top_k, n_held) // n_held)
+    return int(min(128, max(8, 1 << max(per - 1, 0).bit_length())))
+
+
+def held_rows(sel, held, block_m: int):
+    """Sort the routed (token, held expert) pairs into per-expert groups
+    padded to whole ``block_m``-row tiles.  ``sel [T, k]`` chosen ids,
+    ``held [H]`` held ids.  Returns (:class:`repro.exec.run.ExpertRows`,
+    each pair's row ``[T, k]`` (meaningful where held), each pair's held
+    mask ``[T, k]``, the token of each row ``[R]``, pairs per held expert
+    ``[H]``).  The row count is static: every pair of every token could
+    be held, plus one partial tile per expert."""
+    from repro.exec.run import ExpertRows
+
+    t, k = sel.shape
+    h = held.shape[0]
+    match = sel[:, :, None] == held[None, None, :]           # [T, k, H]
+    is_held = match.any(-1)
+    hidx = jnp.argmax(match, axis=-1).reshape(-1)           # [T*k]
+    onehot = match.reshape(t * k, h).astype(jnp.int32)
+    counts = onehot.sum(0)                                   # [H]
+    rank = jnp.take_along_axis(jnp.cumsum(onehot, 0) - onehot,
+                               hidx[:, None], axis=1)[:, 0]
+    tiles = (counts + block_m - 1) // block_m
+    tile_end = jnp.cumsum(tiles)
+    tile_start = tile_end - tiles
+    live = tile_end[-1]
+    g = -(-t * min(k, h) // block_m) + h
+    r = g * block_m
+    row = jnp.where(is_held.reshape(-1), tile_start[hidx] * block_m + rank,
+                    r)
+    token = jnp.zeros((r,), jnp.int32).at[row].set(
+        jnp.arange(t * k, dtype=jnp.int32) // k, mode="drop")
+    row_live = jnp.zeros((r,), bool).at[row].set(True, mode="drop")
+    last = jnp.maximum(live, 1) - 1
+    tile_expert = jnp.searchsorted(
+        tile_end, jnp.minimum(jnp.arange(g), last), side="right")
+    tile_expert = jnp.minimum(tile_expert, h - 1).astype(jnp.int32)
+    rows = ExpertRows(row_live=row_live, tile_expert=tile_expert,
+                      live_tiles=live.reshape(1).astype(jnp.int32),
+                      block_m=block_m)
+    return rows, row.reshape(t, k), is_held, token, counts
+
+
+def _expert_plan(node, acfg: AnalogConfig):
+    """The stacked layer plan of one expert matrix: the compile-time bake
+    where ``api.compile`` left one, else lowered here (per call, the
+    training contract)."""
+    lp = node.get("_plan")
+    if lp is not None:
+        return lp
+    from repro.exec.lower import lower_layer
+
+    return jax.vmap(lambda p: lower_layer(p, acfg))(node)
+
+
+def held_moe_apply(params, x, *, acfg: AnalogConfig, top_k: int,
+                   key=None):
+    """x: [B, S, d] -> (y, stats).  ``stats``: ``rows`` [H] routed pairs
+    per held expert, ``tiles`` row tiles the grouped dispatch ran.
+
+    Digital mode runs every held expert over every token (exact, for the
+    training and smoke paths); analog modes run only the routed rows."""
+    del key
+    b, s, d = x.shape
+    t = b * s
+    u = x.reshape(t, d)
+    held = params["held"].astype(jnp.int32)
+    ex = params["experts"]
+    with jax.named_scope("moe.route"):
+        sel, w = route(params, u, top_k)
+        block_m = tile_rows(t, top_k, held.shape[0])
+        rows, row, is_held, token, counts = held_rows(sel, held, block_m)
+    with jax.named_scope("moe.experts"):
+        if acfg.mode == "digital":
+            ye = jax.vmap(lambda p: L.mlp_apply(p, u, acfg))(ex)  # [H,T,d]
+            hidx = jnp.argmax(sel[:, :, None] == held[None, None, :], -1)
+            parts = ye[hidx, jnp.arange(t)[:, None]]           # [T, k, d]
+        else:
+            from repro.exec.run import run_expert_rows as run_rows
+
+            xr = u[token]
+            up = run_rows(_expert_plan(ex["up"], acfg), xr, rows, acfg)
+            gate = run_rows(_expert_plan(ex["gate"], acfg), xr, rows, acfg)
+            hr = jax.nn.silu(gate) * up
+            yr = run_rows(_expert_plan(ex["down"], acfg), hr, rows, acfg)
+            parts = yr[jnp.minimum(row, yr.shape[0] - 1)]      # [T, k, d]
+        y = jnp.zeros((t, d), jnp.float32)
+        for j in range(top_k):        # in selection order, as the reference
+            y = y + jnp.where(is_held[:, j, None],
+                              w[:, j, None] * parts[:, j].astype(
+                                  jnp.float32), 0.0)
+    stats = {"rows": counts.astype(jnp.int32),
+             "tiles": rows.live_tiles[0]}
+    return y.reshape(b, s, d).astype(x.dtype), stats

@@ -191,7 +191,7 @@ def rwkv_apply(params, x, *, acfg: AnalogConfig, n_heads, cache=None,
     y = y.reshape(b, t, d)
     # group norm over heads, then output gate + projection
     yh = y.reshape(b, t, n_heads, hd)
-    yh = yh * jax.lax.rsqrt(jnp.mean(yh * yh, axis=-1, keepdims=True) + 1e-5)
+    yh = yh * jax.lax.rsqrt(L.ordered_sum(yh * yh) / hd + 1e-5)
     y = (yh.reshape(b, t, d) * jax.nn.silu(g.astype(jnp.float32))).astype(
         x.dtype
     )

@@ -3,7 +3,11 @@
 Layers are grouped into homogeneous *scan groups* (dense: 1 layer/group;
 Llama-4: [dense, moe] pairs; Zamba2: shared-attn + 6 mamba layers) and
 scanned with stacked parameters, so HLO size and compile time are O(1) in
-depth - mandatory for the 40-cell dry-run on one host.
+depth - mandatory for the 40-cell dry-run on one host.  A config with a
+per-layer kind list (``ArchConfig.layer_kinds``: LFM2's short-conv and
+attention layers, dense and held-expert feed-forwards) has no repeating
+group: its layers are kept unstacked (``params["layers"]["l<i>"]``) and
+applied in order, each with its own kind of cache state.
 
 Every parameter matmul dispatches through the analog backend
 (repro.core.analog); the execution mode (digital / analog_faithful /
@@ -25,6 +29,7 @@ from repro.models import attention as A
 from repro.models import layers as L
 from repro.models import moe as M
 from repro.models import rwkv as R
+from repro.models import shortconv as C
 from repro.models import ssm as S
 
 NOISE = NoiseConfig()  # module-level default; configs may override later
@@ -33,6 +38,8 @@ NOISE = NoiseConfig()  # module-level default; configs may override later
 # ----------------------------------------------------------- group layout
 def group_def(cfg: ArchConfig) -> list[str]:
     """Kinds of the layers inside one scan group."""
+    if cfg.layer_kinds:
+        return list(cfg.layer_kinds)
     if cfg.block == "mamba" and cfg.attn_every:
         return ["mamba"] * cfg.attn_every          # + shared attn at entry
     if cfg.n_experts and cfg.moe_every > 1:
@@ -47,20 +54,32 @@ def n_groups(cfg: ArchConfig) -> int:
 
 
 # ------------------------------------------------------------------ init
+_MIXED = ("attn_mlp", "attn_moe", "conv_mlp", "conv_moe")
+
+
 def _layer_init(key, kind: str, cfg: ArchConfig):
     dtype = cfg.dtype
     ks = jax.random.split(key, 2)
     p = {"ln1": L.norm_init(cfg.d_model, cfg.norm)}
-    if kind in ("attn_mlp", "attn_moe"):
-        p["attn"] = A.attention_init(
-            ks[0], cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.hd,
-            noise=NOISE, dtype=dtype,
-        )
+    if kind in _MIXED:
+        if kind.startswith("conv_"):
+            p["conv"] = C.shortconv_init(ks[0], cfg.d_model, cfg.conv_taps,
+                                         noise=NOISE, dtype=dtype)
+        else:
+            p["attn"] = A.attention_init(
+                ks[0], cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.hd,
+                noise=NOISE, dtype=dtype, qk_norm=cfg.qk_norm,
+            )
         p["ln2"] = L.norm_init(cfg.d_model, cfg.norm)
-        if kind == "attn_mlp":
+        if kind.endswith("_mlp"):
             ff = cfg.moe_dense_d_ff or cfg.d_ff
             p["mlp"] = L.mlp_init(ks[1], cfg.d_model, ff, act=cfg.act,
                                   noise=NOISE, dtype=dtype)
+        elif cfg.held_experts:
+            p["moe"] = M.held_moe_init(
+                ks[1], cfg.d_model, cfg.moe_d_ff, cfg.n_experts,
+                cfg.held_experts, noise=NOISE, dtype=dtype,
+            )
         else:
             p["moe"] = M.moe_init(
                 ks[1], cfg.d_model, cfg.moe_d_ff, cfg.n_experts,
@@ -83,11 +102,16 @@ def _layer_init(key, kind: str, cfg: ArchConfig):
 
 def _layer_specs(kind: str, cfg: ArchConfig):
     p = {"ln1": L.norm_specs(cfg.norm)}
-    if kind in ("attn_mlp", "attn_moe"):
-        p["attn"] = A.attention_specs(NOISE)
+    if kind in _MIXED:
+        if kind.startswith("conv_"):
+            p["conv"] = C.shortconv_specs(NOISE)
+        else:
+            p["attn"] = A.attention_specs(NOISE, qk_norm=cfg.qk_norm)
         p["ln2"] = L.norm_specs(cfg.norm)
-        if kind == "attn_mlp":
+        if kind.endswith("_mlp"):
             p["mlp"] = L.mlp_specs(act=cfg.act, noise=NOISE)
+        elif cfg.held_experts:
+            p["moe"] = M.held_moe_specs(NOISE)
         else:
             p["moe"] = M.moe_specs(act=cfg.act,
                                    n_shared=cfg.n_shared_experts, noise=NOISE)
@@ -114,9 +138,12 @@ def lm_init(key, cfg: ArchConfig):
     if cfg.embed_inputs:
         params["embed"] = L.embedding_init(k_emb, cfg.vocab_size, cfg.d_model,
                                            dtype=cfg.dtype)
-    params["layers"] = jax.vmap(
-        lambda k: _group_init(k, cfg)
-    )(jax.random.split(k_layers, ng))
+    if cfg.layer_kinds:
+        params["layers"] = _group_init(k_layers, cfg)     # unstacked
+    else:
+        params["layers"] = jax.vmap(
+            lambda k: _group_init(k, cfg)
+        )(jax.random.split(k_layers, ng))
     if cfg.attn_every:   # zamba2 shared attention block (single param set)
         params["shared_attn"] = {
             "ln": L.norm_init(cfg.d_model, cfg.norm),
@@ -165,7 +192,7 @@ def lm_specs(cfg: ArchConfig):
     if cfg.embed_inputs:
         specs["embed"] = L.embedding_specs()
     group = {f"l{i}": _layer_specs(kind, cfg) for i, kind in enumerate(kinds)}
-    specs["layers"] = _prepend(group)
+    specs["layers"] = group if cfg.layer_kinds else _prepend(group)
     if cfg.attn_every:
         specs["shared_attn"] = {
             "ln": L.norm_specs(cfg.norm),
@@ -193,20 +220,36 @@ def _layer_apply(p, kind, x, *, cfg, run, positions, cache, key, window=None):
             from repro.exec.run import run as run_plan
 
             return run_plan(bp, x, key=key), None, 0.0
-    if kind in ("attn_mlp", "attn_moe"):
+    aux = 0.0
+    if kind in _MIXED:
         h = L.norm_apply(p["ln1"], x, cfg.norm)
-        attn_out, c = A.attention_apply(
-            p["attn"], h, positions=positions, acfg=acfg,
-            n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads, head_dim=cfg.hd,
-            rope_theta=cfg.rope_theta, mrope=cfg.mrope,
-            cache=None if cache is None else cache["attn"],
-            window=window, attn_cp=getattr(run, "attn_cp", "auto"), key=key,
-        )
-        x = x + attn_out.astype(x.dtype)
+        if kind.startswith("conv_"):
+            y, c = C.shortconv_apply(
+                p["conv"], h, acfg=acfg,
+                state=None if cache is None else cache["conv"], key=key,
+            )
+            mixer = "conv"
+        else:
+            y, c = A.attention_apply(
+                p["attn"], h, positions=positions, acfg=acfg,
+                n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
+                head_dim=cfg.hd, rope_theta=cfg.rope_theta, mrope=cfg.mrope,
+                cache=None if cache is None else cache["attn"],
+                window=window, attn_cp=getattr(run, "attn_cp", "auto"),
+                key=key,
+            )
+            mixer = "attn"
+        x = x + y.astype(x.dtype)
+        if cache is not None:
+            new_cache[mixer] = c
         h = L.norm_apply(p["ln2"], x, cfg.norm)
-        if kind == "attn_mlp":
+        if kind.endswith("_mlp"):
             y = L.mlp_apply(p["mlp"], h, acfg, act=cfg.act, key=key)
-            aux = 0.0
+        elif cfg.held_experts:
+            y, stats = M.held_moe_apply(p["moe"], h, acfg=acfg,
+                                        top_k=cfg.top_k, key=key)
+            if cache is not None:
+                new_cache["moe"] = stats
         else:
             y, aux = M.moe_apply(
                 p["moe"], h, acfg=acfg, top_k=cfg.top_k,
@@ -214,8 +257,6 @@ def _layer_apply(p, kind, x, *, cfg, run, positions, cache, key, window=None):
                 dispatch=getattr(run, "moe_dispatch", "gspmd_ep"), key=key,
             )
         x = x + y.astype(x.dtype)
-        if cache is not None:
-            new_cache["attn"] = c
     elif kind == "rwkv":
         h = L.norm_apply(p["ln1"], x, cfg.norm)
         y, c1 = R.rwkv_apply(
@@ -240,9 +281,7 @@ def _layer_apply(p, kind, x, *, cfg, run, positions, cache, key, window=None):
         x = x + y.astype(x.dtype)
         if cache is not None:
             new_cache["mamba"] = c
-    return x, (new_cache if cache is not None else None), (
-        aux if kind == "attn_moe" else 0.0
-    )
+    return x, (new_cache if cache is not None else None), aux
 
 
 def _group_apply(gp, x, *, cfg, run, positions, shared_attn, cache, key):
@@ -273,31 +312,10 @@ def _group_apply(gp, x, *, cfg, run, positions, shared_attn, cache, key):
     return x, new_cache, aux_total
 
 
-def lm_apply(params, batch, cfg: ArchConfig, run: RunConfig, *,
-             cache=None, rng=None):
-    """batch: {"tokens": [B,S] int32} or {"embeds": [B,S,d]}, optional
-    {"positions": [B,S] or [B,S,3]}.  Returns (logits, new_cache, aux)."""
-    acfg = run.analog
-    adt = jnp.bfloat16 if run.activation_dtype == "bfloat16" else jnp.float32
-    if cfg.embed_inputs:
-        x = L.embedding_apply(params["embed"], batch["tokens"])
-    else:
-        x = batch["embeds"]
-    x = x.astype(adt)
-    b, s = x.shape[:2]
-    x = constrain(x, "batch", "seq", None)
-
-    if "positions" in batch:
-        positions = batch["positions"]
-    else:
-        start = cache["step"] if cache is not None else 0
-        pos = start + jnp.arange(s, dtype=jnp.int32)[None, :]
-        positions = jnp.broadcast_to(pos, (b, s))
-        if cfg.mrope:
-            positions = jnp.broadcast_to(positions[..., None], (b, s, 3))
-
+def _scan_groups(params, x, *, cfg, run, positions, layer_cache, rng,
+                 cached):
+    """The stacked scan groups of ``params["layers"]`` over ``x``."""
     shared = params.get("shared_attn")
-    layer_cache = None if cache is None else cache["layers"]
     keys = (
         None
         if rng is None
@@ -308,7 +326,7 @@ def lm_apply(params, batch, cfg: ArchConfig, run: RunConfig, *,
         x, aux = carry
         gp, gc, gk = inp
         fn = _group_apply
-        if cfg.remat and cache is None:
+        if cfg.remat and not cached:
             fn = jax.checkpoint(
                 functools.partial(
                     _group_apply, cfg=cfg, run=run, positions=positions,
@@ -330,6 +348,47 @@ def lm_apply(params, batch, cfg: ArchConfig, run: RunConfig, *,
         body, (x, jnp.zeros((), jnp.float32)),
         (params["layers"], layer_cache, keys),
     )
+    return x, new_layer_cache, aux
+
+
+def lm_apply(params, batch, cfg: ArchConfig, run: RunConfig, *,
+             cache=None, rng=None, last_only: bool = False):
+    """batch: {"tokens": [B,S] int32} or {"embeds": [B,S,d]}, optional
+    {"positions": [B,S] or [B,S,3]}.  Returns (logits, new_cache, aux).
+    ``last_only`` computes the logits of the last position alone
+    (``[B, 1, vocab]``)."""
+    acfg = run.analog
+    adt = jnp.bfloat16 if run.activation_dtype == "bfloat16" else jnp.float32
+    if cfg.embed_inputs:
+        x = L.embedding_apply(params["embed"], batch["tokens"])
+    else:
+        x = batch["embeds"]
+    x = x.astype(adt)
+    b, s = x.shape[:2]
+    x = constrain(x, "batch", "seq", None)
+
+    if "positions" in batch:
+        positions = batch["positions"]
+    else:
+        start = cache["step"] if cache is not None else 0
+        pos = start + jnp.arange(s, dtype=jnp.int32)[None, :]
+        positions = jnp.broadcast_to(pos, (b, s))
+        if cfg.mrope:
+            positions = jnp.broadcast_to(positions[..., None], (b, s, 3))
+
+    layer_cache = None if cache is None else cache["layers"]
+    if cfg.layer_kinds:      # unstacked layers of mixed kinds, in order
+        x, new_layer_cache, aux = _group_apply(
+            params["layers"], x, cfg=cfg, run=run, positions=positions,
+            shared_attn=None, cache=layer_cache, key=rng,
+        )
+    else:
+        x, new_layer_cache, aux = _scan_groups(
+            params, x, cfg=cfg, run=run, positions=positions,
+            layer_cache=layer_cache, rng=rng, cached=cache is not None,
+        )
+    if last_only:
+        x = x[:, -1:]
 
     x = L.norm_apply(params["final_norm"], x, cfg.norm)
     if cfg.tie_embeddings:
@@ -393,9 +452,20 @@ def attach_block_plans(params, cfg: ArchConfig, acfg, *, seq: int):
 
 # ------------------------------------------------------------------ cache
 def _layer_cache(kind, cfg: ArchConfig, batch, max_len, dtype=jnp.bfloat16):
-    if kind in ("attn_mlp", "attn_moe"):
-        return {"attn": A.init_cache(batch, max_len, cfg.n_kv_heads, cfg.hd,
-                                     dtype)}
+    if kind in _MIXED:
+        if kind.startswith("conv_"):
+            # the last taps-1 gated inputs of the short convolution
+            c = {"conv": jnp.zeros((batch, cfg.conv_taps - 1, cfg.d_model),
+                                   jnp.float32)}
+        else:
+            c = {"attn": A.init_cache(batch, max_len, cfg.n_kv_heads, cfg.hd,
+                                      dtype)}
+        if kind.endswith("_moe") and cfg.held_experts:
+            # the call's routed pairs per held expert and the grouped
+            # dispatch's row tiles (read by the serve engine)
+            c["moe"] = {"rows": jnp.zeros((cfg.held_experts,), jnp.int32),
+                        "tiles": jnp.zeros((), jnp.int32)}
+        return c
     if kind == "rwkv":
         hd = cfg.d_model // cfg.n_heads
         return {
@@ -428,6 +498,8 @@ def init_lm_cache(cfg: ArchConfig, batch: int, max_len: int,
         f"l{i}": _layer_cache(kind, cfg, batch, max_len, dtype)
         for i, kind in enumerate(kinds)
     }
+    if cfg.layer_kinds:
+        return {"layers": group, "step": jnp.zeros((), jnp.int32)}
     if cfg.attn_every:
         group["shared_attn"] = A.init_cache(batch, max_len, cfg.n_kv_heads,
                                             cfg.hd, dtype)
@@ -438,9 +510,13 @@ def init_lm_cache(cfg: ArchConfig, batch: int, max_len: int,
     return {"layers": stacked, "step": jnp.zeros((), jnp.int32)}
 
 
-def _layer_cache_specs(kind, dtype=jnp.bfloat16):
-    if kind in ("attn_mlp", "attn_moe"):
-        return {"attn": A.cache_specs(dtype)}
+def _layer_cache_specs(kind, dtype=jnp.bfloat16, cfg=None):
+    if kind in _MIXED:
+        c = ({"conv": ("batch", None, None)} if kind.startswith("conv_")
+             else {"attn": A.cache_specs(dtype)})
+        if kind.endswith("_moe") and cfg is not None and cfg.held_experts:
+            c["moe"] = {"rows": (None,), "tiles": ()}
+        return c
     if kind == "rwkv":
         return {"tmix": R.rwkv_cache_specs(),
                 "cmix": {"x_prev": ("batch", None)}}
@@ -451,8 +527,10 @@ def _layer_cache_specs(kind, dtype=jnp.bfloat16):
 
 def lm_cache_specs(cfg: ArchConfig, dtype=jnp.bfloat16):
     kinds = group_def(cfg)
-    group = {f"l{i}": _layer_cache_specs(kind, dtype)
+    group = {f"l{i}": _layer_cache_specs(kind, dtype, cfg)
              for i, kind in enumerate(kinds)}
+    if cfg.layer_kinds:
+        return {"layers": group, "step": ()}
     if cfg.attn_every:
         group["shared_attn"] = A.cache_specs(dtype)
     return {"layers": _prepend(group), "step": ()}

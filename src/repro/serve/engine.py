@@ -3,6 +3,18 @@ batched decode with per-sequence stopping.  Deliberately simple continuous-
 batching-lite: requests are grouped into fixed decode slots; finished slots
 are refilled between decode steps (the cache "len" is global, so refills
 restart a slot's cache region - documented simplification).
+
+Any LM of :mod:`repro.models.transformer` serves here, its cache holding
+each layer's own kind of state side by side: keys and values on attention
+layers, the last gated inputs ``[B, taps - 1, d]`` on short-conv layers
+(LFM2), the recurrent state on RWKV and Mamba layers.  Prompts are
+left-padded, so a prefill's last positions are every row's last real
+tokens.  On held-expert layers (one chip's share of an expert-parallel
+deployment) every routed row is computed; while a collector or a profiler
+is listening (``obs.observed()``) the engine keeps each step's routed
+pairs and expert row tiles on the device and reads them back once a
+batch, after its last step, to count them (``lm.moe.held_rows``,
+``lm.moe.row_tiles``, ``lm.moe.tile_rows``).
 """
 from __future__ import annotations
 
@@ -17,11 +29,20 @@ import numpy as np
 from repro import api
 from repro.configs.base import ArchConfig, RunConfig
 from repro.distributed import sharding as shd
+from repro.models import moe as M
 from repro.models import transformer as T
 from repro.obs import energy as obs_energy
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 from repro.serve.serve_step import make_serve_steps
+
+
+@jax.jit
+def _moe_totals(stats) -> jax.Array:
+    """[routed pairs, row tiles] summed over the held-expert layers of one
+    step (``stats``: each layer's ``{"rows": [H], "tiles": []}``)."""
+    return jnp.stack([sum(st["rows"].sum() for st in stats),
+                      sum(st["tiles"] for st in stats)]).astype(jnp.int32)
 
 
 @dataclasses.dataclass
@@ -127,7 +148,40 @@ class ServeEngine:
         self.max_len = max_len
         self.greedy = greedy
         self.prefill, self.decode = make_serve_steps(cfg, run, **step_kw)
+        # a batch's empty cache, made by one compiled call: eager zeros
+        # dispatch a primitive each, which JAX re-traces whenever its
+        # primitive cache has evicted them
+        self.new_cache = jax.jit(
+            lambda b: T.init_lm_cache(cfg, b, max_len, dtype=jnp.float32),
+            static_argnums=0)
         self.rng = jax.random.PRNGKey(seed)
+
+    def _expert_stats(self, cache, tokens: int, pending: list) -> None:
+        """While observed, keep one step's routed (token, held expert)
+        pairs and the row tiles the grouped expert dispatch ran: totals
+        taken on the device (the cache they sit in is donated to the
+        next step), not read back yet."""
+        if not self.cfg.held_experts or not obs_trace.observed():
+            return
+        stats = [c["moe"] for c in cache["layers"].values()
+                 if isinstance(c, dict) and "moe" in c]
+        if stats:
+            block_m = M.tile_rows(tokens, self.cfg.top_k,
+                                  self.cfg.held_experts)
+            pending.append((_moe_totals(stats), block_m))
+
+    @staticmethod
+    def _count_experts(pending: list) -> None:
+        """Read a batch's kept step totals back in one transfer and count
+        them."""
+        if not pending:
+            return
+        with obs_trace.span("lm.moe_stats"):
+            got = jax.device_get([totals for totals, _ in pending])
+        for (rows, tiles), (_, block_m) in zip(got, pending):
+            obs_metrics.counter("lm.moe.held_rows").inc(int(rows))
+            obs_metrics.counter("lm.moe.row_tiles").inc(int(tiles))
+            obs_metrics.counter("lm.moe.tile_rows").inc(int(tiles) * block_m)
 
     def _sample(self, logits):
         if self.greedy:
@@ -209,8 +263,7 @@ class ServeEngine:
             toks = np.zeros((b, prompt_len), np.int32)
             for i, r in enumerate(requests):
                 toks[i, prompt_len - len(r.prompt):] = r.prompt  # left-pad
-            cache = T.init_lm_cache(self.cfg, b, self.max_len,
-                                    dtype=jnp.float32)
+            cache = self.new_cache(b)
             with obs_trace.span("serve.prefill", batch=b,
                                 prompt_len=prompt_len) as psp:
                 logits, cache = self.prefill(
@@ -218,6 +271,8 @@ class ServeEngine:
                 )
                 next_tok = jax.block_until_ready(self._sample(logits))
             obs_metrics.histogram("serve.prefill_us").record(psp.dur_us)
+            moe_stats = []
+            self._expert_stats(cache, b * prompt_len, moe_stats)
             max_new = max(r.max_new_tokens for r in requests)
             outs = [[] for _ in range(b)]
             done = np.zeros(b, bool)
@@ -248,8 +303,10 @@ class ServeEngine:
                     obs_metrics.histogram("serve.decode_us").record(
                         obs_trace.clock_us() - t_step
                     )
+                    self._expert_stats(cache, b, moe_stats)
                     steps += 1
                 dsp.add(steps=steps)
+            self._count_experts(moe_stats)
             _bsp.add(tokens=int(sum(len(o) for o in outs)))
         for i, r in enumerate(requests):
             r.output = np.asarray(outs[i], np.int32)

@@ -16,8 +16,13 @@ from repro.models import transformer as T
 
 
 def serve_prefill(params, batch, cache, *, cfg: ArchConfig, run: RunConfig):
-    """Prompt pass: fills the cache, returns last-position logits."""
-    logits, cache, _ = T.lm_apply(params, batch, cfg, run, cache=cache)
+    """Prompt pass: fills the cache, returns last-position logits.  Under
+    static activation calibration an analog layer's result for a row does
+    not depend on the other rows of the call, so the head runs at the
+    last position alone."""
+    static = run.analog.mode != "digital" and run.analog.act_calib == "static"
+    logits, cache, _ = T.lm_apply(params, batch, cfg, run, cache=cache,
+                                  last_only=static)
     return logits[:, -1], cache
 
 

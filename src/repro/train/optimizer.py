@@ -2,7 +2,9 @@
 schedule with linear warmup, global-norm clipping, and a trainable-mask that
 freezes the analog calibration buffers (fpn, scales, gain) - those are
 hardware properties, not weights (paper §III-B trains only the synaptic
-weights through the HIL loop).
+weights through the HIL loop) - and the held-expert layer's routing
+buffers (``expert_bias``, kept by load balancing outside the gradient,
+and the ``held`` expert ids).
 """
 from __future__ import annotations
 
@@ -12,7 +14,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-FROZEN_KEYS = ("fpn", "a_scale", "w_scale", "gain")
+FROZEN_KEYS = ("fpn", "a_scale", "w_scale", "gain", "expert_bias", "held")
 
 
 def trainable_mask(params) -> dict:

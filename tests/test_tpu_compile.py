@@ -139,3 +139,43 @@ def test_fused_preprocess_reads_raw_samples_as_they_arrive(one_chip, batch):
     for line in entry.splitlines():
         for _, operands in relayout.findall(line):
             assert raw not in re.split(r",\s*", operands), line
+
+
+# (rows, K, N, block_m): LFM2-8B-A1B's held experts (8 of 32, width 1792)
+# over a 4 x 1024-token prefill's worst case (every pair held, a partial
+# tile per expert) and a 4-row decode step
+EXPERT_SHAPES = {
+    "prefill_up": (136 * 128, 2048, 1792, 128),
+    "prefill_down": (136 * 128, 1792, 2048, 128),
+    "decode_up": (10 * 8, 2048, 1792, 8),
+}
+
+
+@pytest.mark.parametrize("shape", list(EXPERT_SHAPES))
+def test_expert_mvm_compiles(one_chip, shape):
+    """The grouped held-expert kernel, named as the chip benchmark's
+    ``expert_mvm_roofline`` reader finds it."""
+    import pathlib
+    import sys
+
+    from repro.kernels.analog_mvm import expert_mvm_pallas
+
+    bench = pathlib.Path(__file__).resolve().parents[1] / "benchmarks/chip"
+    if str(bench) not in sys.path:
+        sys.path.insert(0, str(bench))     # the readers import chipbench
+    from chipbench import spec
+
+    r, k, n, bm = EXPERT_SHAPES[shape]
+    e, c, g = 8, k // 128, r // bm
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+            for s in ((r, k), (r, k), (e, k, n), (e, n), (e, c, n))]
+    args += [jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+             for s in ((g,), (1,))]
+    fn = functools.partial(expert_mvm_pallas, block_m=bm)
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    assert _kernel_calls(hlo) == 1
+    (call,) = [ln for ln in hlo.split("\nENTRY ", 1)[1].splitlines()
+               if "tpu_custom_call" in ln]
+    name = call.strip().removeprefix("ROOT ").split(" = ")[0]
+    reader = spec.reader("expert_mvm_roofline.lfm2_prefill1k")
+    assert reader.EXPERT_MVM.match(name), name
