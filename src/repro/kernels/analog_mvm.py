@@ -8,13 +8,18 @@ chunk instead of materializing ``[M, C, N]`` partials in HBM like the naive
 lowering does (memory-roofline win: the chunk axis never leaves VMEM).
 
 TPU mapping decisions (hw-codesign):
-- block sizes are MXU-aligned: bk = 128 (the BSS-2 signed-row chunk IS the
-  MXU contraction tile - the paper's geometry is natively TPU-friendly),
-  bm/bn multiples of 128 chosen so (a, w, acc) blocks fit VMEM.
-- operands reach the MXU as fp32 contracted at full precision
-  (:func:`mxu_dot`): activation codes 0..31 would be bf16-exact, but the
-  effective weights carry fixed-pattern and calibration gains, and bf16
-  rounding of those moves ADC codes off the fp32 oracle (PERF.md).
+- bk = 128 (the BSS-2 signed-row chunk IS the MXU contraction tile - the
+  paper's geometry is natively TPU-friendly); bm and bn are chosen from
+  the shapes (:func:`block_rows`, :func:`block_cols`) so the blocks fit
+  VMEM, few rows are not padded to a large block and no columns are
+  padded where a multiple of 128 divides N.
+- each chunk's contraction is :func:`code_dot`: three bf16 MXU passes,
+  the activation codes against three bf16 parts of the fp32 effective
+  weights, where an fp32 dot at full precision takes six.  Activations
+  must be integer codes of magnitude at most 256 (the kernels see 5-bit
+  codes), which bf16 holds exactly; the weights keep their fixed-pattern
+  and calibration gains to the last fp32 bit, so the result is the fp32
+  dot's, bit for bit (PERF.md).
 - the chunk/grid-K axis is the innermost ("arbitrary") grid dimension and
   accumulates into an fp32 VMEM scratch; output is written once on the last
   chunk step.
@@ -34,6 +39,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.hw import BSS2
 from repro.core.quant import ANALOG_PRECISION
+from repro.obs import metrics as _obs_metrics
 
 
 def mxu_dot(a: jax.Array, w: jax.Array) -> jax.Array:
@@ -42,6 +48,46 @@ def mxu_dot(a: jax.Array, w: jax.Array) -> jax.Array:
     which moves ADC codes off the fp32 oracle."""
     return jnp.dot(a, w, preferred_element_type=jnp.float32,
                    precision=ANALOG_PRECISION)
+
+
+def _truncate(x: jax.Array) -> jax.Array:
+    """``x`` cut to its leading 8 significant bits (a bf16 number)."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32) & jnp.int32(-65536)
+    return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+
+def split_bf16x3(w: jax.Array):
+    """``(hi, mid, lo)``: the fp32 ``w`` as three bf16 parts whose sum is
+    ``w`` exactly, each cut by truncation: ``hi`` is ``w``'s leading 8
+    significant bits, ``mid`` the leading 8 of the rest ``w - hi``, ``lo``
+    what remains (at most 8 significant bits).  These are the parts an
+    fp32 MXU dot at full precision latches (probed bit for bit on a v5e,
+    PERF.md)."""
+    w = w.astype(jnp.float32)
+    hi = _truncate(w)
+    rest = w - hi
+    mid = _truncate(rest)
+    return tuple(p.astype(jnp.bfloat16) for p in (hi, mid, rest - mid))
+
+
+def code_dot(a: jax.Array, parts) -> jax.Array:
+    """One chunk's contraction of activation codes ``a`` with fp32
+    weights, given as their :func:`split_bf16x3` ``parts``, in three bf16
+    MXU passes added as an fp32 dot at full precision adds its passes:
+    ``(a·lo + a·mid) + a·hi``.  (Written with ``a·hi`` first, which the
+    commutative last add allows, Mosaic pops all three into one running
+    sum.)  ``a`` must hold integers of magnitude at most 256, which bf16
+    holds exactly: the three passes that dot spends on the activation's
+    lower bits then multiply by zero, and the result is that dot's, bit
+    for bit."""
+    _obs_metrics.counter("analog.mvm.bf16x3_dots").inc()
+    a = a.astype(jnp.bfloat16)
+
+    def one(part):
+        return jnp.dot(a, part, preferred_element_type=jnp.float32)
+
+    hi, mid, lo = parts
+    return one(hi) + (one(lo) + one(mid))
 
 
 def _offset_rows(chunk_offset, n_chunks: int, n: int, pn: int) -> jax.Array:
@@ -60,6 +106,31 @@ def _offset_rows(chunk_offset, n_chunks: int, n: int, pn: int) -> jax.Array:
 def _offset_spec(block_n: int):
     # chunk c's offset row, squeezed to (1, block_n) in the kernel
     return pl.BlockSpec((None, 1, block_n), lambda i, j, c: (c, 0, j))
+
+
+# the most output elements one grid step holds: (512, 1024) fits the two
+# fp32 accumulators, the double-buffered output block and the operand
+# blocks in the default scoped VMEM
+_BLOCK_ELEMS = 512 * 1024
+
+
+def block_rows(m: int) -> int:
+    """The row block for ``m`` rows: 512, or ``m`` rounded up to whole
+    sublanes when fewer - a decode step's 4 rows pad to 8, not 512."""
+    return min(512, -(-m // 8) * 8)
+
+
+def block_cols(n: int, block_m: int) -> int:
+    """The column block for ``n`` columns at ``block_m`` rows: all of
+    ``n`` (in whole lanes) if it fits, else the largest multiple of 128
+    that divides it, so no column is padded, unless that is under half
+    the largest block that fits, which is then taken."""
+    cap = min(2048, _BLOCK_ELEMS // block_m // 128 * 128)
+    n128 = -(-n // 128) * 128
+    if n128 <= cap:
+        return n128
+    div = max(b for b in range(128, cap + 1, 128) if n128 % b == 0)
+    return div if 2 * div >= cap else cap
 
 
 def _apply_epilogue(acc, epilogue):
@@ -86,7 +157,7 @@ def _kernel(a_ref, w_ref, gain_ref, off_ref, o_ref, acc_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    v = mxu_dot(a_ref[...], w_ref[...])
+    v = code_dot(a_ref[...], split_bf16x3(w_ref[...]))
     v = v * gain_ref[...] + off_ref[...]
     if faithful:
         # 8-bit saturating ADC per chunk, digital accumulation
@@ -118,18 +189,21 @@ def analog_mvm_pallas(
     *,
     chunk_rows: int = BSS2.signed_rows,
     faithful: bool = True,
-    block_m: int = 256,
-    block_n: int = 512,
+    block_m: Optional[int] = None,        # default: block_rows(M)
+    block_n: Optional[int] = None,        # default: block_cols(N, block_m)
     interpret: bool = False,
     epilogue=None,                        # None | ("relu_shift", shift)
 ) -> jax.Array:
     """Chunked saturating analog VMM; bit-exact vs the fp32 oracle in
-    interpret mode."""
+    interpret mode.  ``a_code`` holds integer codes of magnitude at most
+    256, the contract of :func:`code_dot`."""
     m, k = a_code.shape
     k2, n = w_eff.shape
     assert k == k2, (k, k2)
     assert k % chunk_rows == 0, (k, chunk_rows)
     n_chunks = k // chunk_rows
+    block_m = block_m or block_rows(m)
+    block_n = block_n or block_cols(n, block_m)
 
     # pad M and N to block multiples (K is already chunk-aligned)
     pm = (-m) % block_m
@@ -180,11 +254,12 @@ def _split_kernel(ap_ref, an_ref, w_ref, gain_ref, off_ref, o_ref,
     """One grid pass over the shared weight tiles evaluates BOTH analog
     passes of the signed-split encoding (paper §II-A: positive and negative
     activation parts on the same synapse columns).  Each (bm, bn, c) step
-    streams the weight tile from HBM once and issues two MXU dots against
-    it - halving weight traffic and kernel dispatches vs. two independent
-    ``analog_mvm`` calls.  ADC saturation is applied to each pass
-    independently (each is a physical analog run), then the difference is
-    formed digitally on the last chunk step."""
+    streams the weight tile from HBM once, splits it into its bf16 parts
+    once and contracts both passes against them - halving weight traffic
+    and kernel dispatches vs. two independent ``analog_mvm`` calls.  ADC
+    saturation is applied to each pass independently (each is a physical
+    analog run), then the difference is formed digitally on the last
+    chunk step."""
     c = pl.program_id(2)
 
     @pl.when(c == 0)
@@ -192,11 +267,11 @@ def _split_kernel(ap_ref, an_ref, w_ref, gain_ref, off_ref, o_ref,
         accp_ref[...] = jnp.zeros_like(accp_ref)
         accn_ref[...] = jnp.zeros_like(accn_ref)
 
-    w = w_ref[...]
+    w = split_bf16x3(w_ref[...])
     gain = gain_ref[...]
     off = off_ref[...]
-    vp = mxu_dot(ap_ref[...], w) * gain + off
-    vn = mxu_dot(an_ref[...], w) * gain + off
+    vp = code_dot(ap_ref[...], w) * gain + off
+    vn = code_dot(an_ref[...], w) * gain + off
     if faithful:
         lo, hi = float(BSS2.adc_min), float(BSS2.adc_max)
         vp = jnp.clip(jnp.round(vp), lo, hi)
@@ -231,21 +306,24 @@ def analog_mvm_split_pallas(
     *,
     chunk_rows: int = BSS2.signed_rows,
     faithful: bool = True,
-    block_m: int = 256,
-    block_n: int = 512,
+    block_m: Optional[int] = None,        # default: block_rows(M)
+    block_n: Optional[int] = None,        # default: block_cols(N, block_m)
     interpret: bool = False,
     epilogue=None,                        # None | ("relu_shift", shift)
 ) -> jax.Array:
     """Fused signed-split analog VMM: ``mvm(a_pos) - mvm(a_neg)`` in one
     kernel launch with single weight streaming.  Bit-exact (fp32) against
     the two-pass oracle because per-pass arithmetic is unchanged - only the
-    tile schedule is shared (tested in tests/test_exec.py)."""
+    tile schedule is shared (tested in tests/test_exec.py).  Both passes'
+    codes are integers of magnitude at most 256 (:func:`code_dot`)."""
     m, k = a_pos.shape
     assert a_neg.shape == (m, k), (a_neg.shape, a_pos.shape)
     k2, n = w_eff.shape
     assert k == k2, (k, k2)
     assert k % chunk_rows == 0, (k, chunk_rows)
     n_chunks = k // chunk_rows
+    block_m = block_m or block_rows(m)
+    block_n = block_n or block_cols(n, block_m)
 
     pm = (-m) % block_m
     pn = (-n) % block_n
@@ -309,11 +387,11 @@ def _grouped_kernel(te_ref, live_ref, ap_ref, an_ref, w_ref, gain_ref,
             accp_ref[...] = jnp.zeros_like(accp_ref)
             accn_ref[...] = jnp.zeros_like(accn_ref)
 
-        w = w_ref[...]
+        w = split_bf16x3(w_ref[...])
         gain = gain_ref[...]
         off = off_ref[...]
-        vp = mxu_dot(ap_ref[...], w) * gain + off
-        vn = mxu_dot(an_ref[...], w) * gain + off
+        vp = code_dot(ap_ref[...], w) * gain + off
+        vn = code_dot(an_ref[...], w) * gain + off
         if faithful:
             lo, hi = float(BSS2.adc_min), float(BSS2.adc_max)
             vp = jnp.clip(jnp.round(vp), lo, hi)
@@ -349,7 +427,7 @@ def expert_mvm_pallas(
     chunk_rows: int = BSS2.signed_rows,
     faithful: bool = True,
     block_m: int = 128,
-    block_n: int = 512,
+    block_n: Optional[int] = None,        # default: block_cols(N, block_m)
     interpret: bool = False,
 ) -> jax.Array:
     """Grouped signed-split analog VMM: row tile ``i`` of the
@@ -359,13 +437,15 @@ def expert_mvm_pallas(
     prefetch, so the weight blocks follow the groups and a tile past
     ``live_tiles`` fetches nothing new and computes nothing: its output
     rows are left unwritten.  The chunking is along K, so the zero rows
-    that pad each group to whole tiles change no live row."""
+    that pad each group to whole tiles change no live row.  Both passes'
+    codes are integers of magnitude at most 256 (:func:`code_dot`)."""
     r, k = a_pos.shape
     e, k2, n = w_eff.shape
     assert k == k2 and k % chunk_rows == 0, (k, k2, chunk_rows)
     assert r % block_m == 0, (r, block_m)
     g = r // block_m
     n_chunks = k // chunk_rows
+    block_n = block_n or block_cols(n, block_m)
     pn = (-n) % block_n
     if pn:
         w_eff = jnp.pad(w_eff, ((0, 0), (0, 0), (0, pn)))

@@ -22,13 +22,14 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core.hw import BSS2
-from repro.core.quant import ANALOG_PRECISION
 from repro.distributed import sharding as shd
 from repro.kernels import ref as ref_lib
 from repro.kernels.analog_mvm import (
     analog_mvm_pallas,
     analog_mvm_split_pallas,
+    code_dot,
     expert_mvm_pallas,
+    split_bf16x3,
 )
 from repro.kernels import analog_plan
 from repro.kernels.analog_plan import analog_plan_pallas
@@ -78,38 +79,6 @@ def _column_parallel(kernel, acts, w_eff, gain, chunk_offset, n_chunks):
     )(*acts, w_eff, gain, chunk_offset)
 
 
-def _mvm_chunk_scan(a_code, w_eff, gain, chunk_offset, chunk_rows):
-    """Faithful chunked VMM with an O(M, N) live set: ``lax.scan`` over
-    the row chunks, accumulating each chunk's clipped ADC codes, instead
-    of materializing the oracle's full [M, C, N] per-chunk tensor (the
-    fused split path doubles M, so that tensor is what made the fused
-    jnp dispatch SLOWER than per-call at bench shapes).  Faithful-only:
-    per-chunk ADC codes are integer-valued f32, so the scan's running
-    sum is bit-exact against the oracle's ``sum(axis=1)`` under any
-    order; fast mode sums pre-round reals, where accumulation order
-    matters at the ulp, and keeps the oracle path."""
-    m, k = a_code.shape
-    n = w_eff.shape[1]
-    assert k % chunk_rows == 0, (k, chunk_rows)
-    c = k // chunk_rows
-    a_c = jnp.moveaxis(
-        a_code.reshape(m, c, chunk_rows).astype(jnp.float32), 1, 0
-    )
-    w_c = w_eff.reshape(c, chunk_rows, n).astype(jnp.float32)
-    off = (jnp.zeros((c, 1), jnp.float32) if chunk_offset is None
-           else chunk_offset.astype(jnp.float32))
-
-    def step(acc, xs):
-        a_i, w_i, o_i = xs
-        v = jnp.einsum("mk,kn->mn", a_i, w_i, precision=ANALOG_PRECISION,
-                       preferred_element_type=jnp.float32) * gain + o_i
-        return acc + jnp.clip(jnp.round(v), BSS2.adc_min, BSS2.adc_max), None
-
-    y, _ = jax.lax.scan(step, jnp.zeros((m, n), jnp.float32),
-                        (a_c, w_c, off))
-    return y
-
-
 def _mvm_split_chunk_scan(a_pos, a_neg, w_eff, gain, chunk_offset,
                           chunk_rows):
     """Faithful fused-split VMM as one chunk scan: both passes share each
@@ -134,10 +103,9 @@ def _mvm_split_chunk_scan(a_pos, a_neg, w_eff, gain, chunk_offset,
 
     def step(acc, xs):
         ap_i, an_i, w_i, o_i = xs
-        vp = jnp.einsum("mk,kn->mn", ap_i, w_i, precision=ANALOG_PRECISION,
-                        preferred_element_type=jnp.float32) * gain + o_i
-        vn = jnp.einsum("mk,kn->mn", an_i, w_i, precision=ANALOG_PRECISION,
-                        preferred_element_type=jnp.float32) * gain + o_i
+        w_i = split_bf16x3(w_i)
+        vp = code_dot(ap_i, w_i) * gain + o_i
+        vn = code_dot(an_i, w_i) * gain + o_i
         adc_p = jnp.clip(jnp.round(vp), BSS2.adc_min, BSS2.adc_max)
         adc_n = jnp.clip(jnp.round(vn), BSS2.adc_min, BSS2.adc_max)
         return acc + (adc_p - adc_n), None
@@ -351,11 +319,8 @@ def analog_mvm_infer(
             chunk_offset, a_pos.shape[1] // chunk_rows,
         )
     if a_neg is None:
-        y = (_mvm_chunk_scan(a_pos, w_eff, gain, chunk_offset, chunk_rows)
-             if faithful else
-             ref_lib.analog_mvm_ref(a_pos, w_eff, gain, chunk_offset,
-                                    chunk_rows=chunk_rows,
-                                    faithful=faithful))
+        y = ref_lib.analog_mvm_ref(a_pos, w_eff, gain, chunk_offset,
+                                   chunk_rows=chunk_rows, faithful=faithful)
     elif faithful:
         y = _mvm_split_chunk_scan(a_pos, a_neg, w_eff, gain,
                                   chunk_offset, chunk_rows)

@@ -10,6 +10,7 @@ import jax.numpy as jnp
 
 from repro.core.hw import BSS2
 from repro.core.quant import ANALOG_PRECISION
+from repro.kernels.analog_mvm import code_dot, split_bf16x3
 
 
 def analog_mvm_ref(
@@ -21,23 +22,35 @@ def analog_mvm_ref(
     chunk_rows: int = BSS2.signed_rows,
     faithful: bool = True,
 ) -> jax.Array:
-    """Chunked saturating analog VMM oracle.  K must divide into chunks."""
+    """Chunked saturating analog VMM oracle.  K must divide into chunks.
+
+    A scan over the chunks whose body is one chunk's whole chain, as the
+    kernel's grid step has it: the CPU backend may fuse ``v * gain + off``
+    into one rounding or not depending on what surrounds it, and the same
+    body keeps the oracle and the interpreted kernel on one side of that
+    choice.  The live set stays ``[M, N]``."""
     m, k = a_code.shape
     n = w_eff.shape[1]
     assert k % chunk_rows == 0, (k, chunk_rows)
     c = k // chunk_rows
-    a_c = a_code.reshape(m, c, chunk_rows).astype(jnp.float32)
+    a_c = jnp.moveaxis(
+        a_code.reshape(m, c, chunk_rows).astype(jnp.float32), 1, 0)
     w_c = w_eff.reshape(c, chunk_rows, n).astype(jnp.float32)
-    v = jnp.einsum("mck,ckn->mcn", a_c, w_c, precision=ANALOG_PRECISION,
-                   preferred_element_type=jnp.float32)
-    v = v * gain
-    if chunk_offset is not None:
-        v = v + chunk_offset[None, :, :]
+    off = (jnp.zeros((c, 1), jnp.float32) if chunk_offset is None
+           else jnp.asarray(chunk_offset, jnp.float32))
+
+    def step(acc, xs):
+        a_i, w_i, o_i = xs
+        v = code_dot(a_i, split_bf16x3(w_i)) * gain + o_i
+        if faithful:
+            v = jnp.clip(jnp.round(v), BSS2.adc_min, BSS2.adc_max)
+        return acc + v, None
+
+    acc, _ = jax.lax.scan(step, jnp.zeros((m, n), jnp.float32),
+                          (a_c, w_c, off))
     if faithful:
-        adc = jnp.clip(jnp.round(v), BSS2.adc_min, BSS2.adc_max)
-        return adc.sum(axis=1)
-    total = v.sum(axis=1)
-    return jnp.clip(jnp.round(total), BSS2.adc_min * c, BSS2.adc_max * c)
+        return acc
+    return jnp.clip(jnp.round(acc), BSS2.adc_min * c, BSS2.adc_max * c)
 
 
 def analog_mvm_split_ref(

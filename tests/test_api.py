@@ -304,6 +304,18 @@ class TestMegakernelKnob:
         np.testing.assert_array_equal(np.asarray(y_auto), np.asarray(y_off))
         np.testing.assert_array_equal(np.asarray(y_auto), np.asarray(y_on))
 
+    def test_megakernel_runs_no_three_pass_dot(self):
+        """The ECG chain's megakernel keeps its own in-kernel dots: a
+        compile of the chain with the Pallas kernels traces no
+        ``code_dot``."""
+        from repro.obs import metrics as obs_metrics
+
+        model, x = self._model(AnalogConfig(use_pallas=True))
+        obs_metrics.reset_metrics()
+        jax.block_until_ready(jax.jit(model.apply).lower(x).compile()(x))
+        assert obs_metrics.counter("exec.run.megakernel").value > 0
+        assert obs_metrics.counter("analog.mvm.bf16x3_dots").value == 0
+
     def test_ecg_layers_carry_named_scopes(self):
         """The chain's stages are named in the lowered program's op
         metadata, where a device trace can attribute time to them."""

@@ -9,6 +9,7 @@ from repro.kernels import ops
 from repro.kernels import ref as R
 from repro.kernels.analog_mvm import (
     analog_mvm_pallas, analog_mvm_split_pallas, expert_mvm_pallas,
+    split_bf16x3,
 )
 from repro.kernels.preproc import maxmin_pool_2d_pallas
 
@@ -48,6 +49,41 @@ def _mvm_inputs(m, k, n, dtype=jnp.float32, with_noise=True):
     return a, w, gain, off
 
 
+def _split_case(case):
+    ks = jax.random.split(jax.random.fold_in(KEY, 7), 4)
+    codes = jnp.round(jax.random.uniform(ks[0], (256, 384), minval=-63,
+                                         maxval=63))
+    col = 1 + 0.02 * jax.random.normal(ks[1], (1, 384))
+    row = 1 + 0.02 * jax.random.normal(ks[2], (256, 1))
+    if case == "rank1":
+        return (codes * col) * row
+    if case == "full_gain_map":
+        return codes * (1 + 0.02 * jax.random.normal(ks[3], codes.shape))
+    if case == "negative":
+        return (-jnp.abs(codes) * col) * row
+    if case == "zeros":
+        return jnp.where(jnp.arange(384) % 3 == 0, 0.0, (codes * col) * row)
+    return jnp.where(codes >= 0, 63.0, -63.0) * col * row     # largest code
+
+
+@pytest.mark.parametrize("case", ["rank1", "full_gain_map", "negative",
+                                  "zeros", "largest_code"])
+def test_weight_split_is_exact(case):
+    """The three bf16 parts of the effective weights add back to them bit
+    for bit, in the order the contraction adds its passes (a zero comes
+    back as +0 whatever its sign, which no sum can tell apart)."""
+    w = _split_case(case)
+    hi, mid, lo = (p.astype(jnp.float32) for p in split_bf16x3(w))
+    back = (lo + mid) + hi
+    bits = lambda x: np.asarray(jax.lax.bitcast_convert_type(x, jnp.int32))
+    nz = np.asarray(w != 0)
+    assert nz.mean() > 0.5
+    np.testing.assert_array_equal(bits(back)[nz], bits(w)[nz])
+    np.testing.assert_array_equal(bits(back)[~nz], 0)
+    # each part is a bf16 number, and the leading part carries the sign
+    assert bool(jnp.all((hi == 0) | (jnp.sign(hi) == jnp.sign(w))))
+
+
 class TestAnalogMVMKernel:
     @pytest.mark.parametrize("m,k,n", MVM_SHAPES)
     @pytest.mark.parametrize("faithful", [True, False])
@@ -60,24 +96,31 @@ class TestAnalogMVMKernel:
         tol = 0.0 if faithful else 1.0   # fast mode: summation-order LSB
         assert float(jnp.abs(got - want).max()) <= tol
 
-    @pytest.mark.parametrize("kernel", ["single", "split"])
-    def test_kernel_dots_contract_at_full_precision(self, kernel):
-        """The effective weights carry fixed-pattern and calibration gains
-        that bf16 cannot hold, and Mosaic may round fp32 dot operands to
-        bf16 unless the dot states its precision: every in-kernel dot must
-        take fp32 operands at HIGHEST."""
+    @pytest.mark.parametrize("kernel", ["single", "split", "grouped"])
+    def test_kernel_dots_contract_codes_in_three_bf16_passes(self, kernel):
+        """Activation codes are exact in bf16, the fp32 weights are not:
+        every analog pass of every kernel is three dots of bf16 codes
+        against bf16 parts of the weights, with fp32 results - the three
+        of the six passes of an fp32 dot at full precision that do not
+        multiply by zero."""
         a, w, gain, off = _mvm_inputs(8, 256, 64)
         if kernel == "single":
-            fn = lambda: analog_mvm_pallas(a, w, gain, off)
+            fn, passes = lambda: analog_mvm_pallas(a, w, gain, off), 1
+        elif kernel == "split":
+            fn, passes = (lambda: analog_mvm_split_pallas(a, a, w, gain,
+                                                          off)), 2
         else:
-            fn = lambda: analog_mvm_split_pallas(a, a, w, gain, off)
+            te, lv = jnp.zeros((1,), jnp.int32), jnp.ones((1,), jnp.int32)
+            fn, passes = (lambda: expert_mvm_pallas(
+                a, a, w[None], gain[None], off[None], te, lv,
+                block_m=8)), 2
         dots = [e for e in _eqns(jax.make_jaxpr(fn)().jaxpr)
                 if e.primitive.name == "dot_general"]
-        assert len(dots) == (1 if kernel == "single" else 2)
+        assert len(dots) == 3 * passes
         for e in dots:
-            hi = jax.lax.Precision.HIGHEST
-            assert e.params["precision"] == (hi, hi)
-            assert all(v.aval.dtype == jnp.float32 for v in e.invars)
+            assert [v.aval.dtype for v in e.invars] == [jnp.bfloat16] * 2
+            assert e.outvars[0].aval.dtype == jnp.float32
+            assert e.params["preferred_element_type"] == jnp.float32
 
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     def test_input_dtypes(self, dtype):

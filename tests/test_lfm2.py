@@ -114,6 +114,37 @@ def test_control_fails(weights, lowered):
     assert float(jnp.abs(logits - got[0]).max()) > 10 * ATOL
 
 
+def test_serve_runs_three_pass_dots(lowered):
+    """Every analog kernel of a serve compile, dense and grouped, makes
+    three bf16 dots per signed-split pass, and tracing them counts."""
+    from repro.obs import metrics as obs_metrics
+
+    def eqns(jaxpr):
+        for e in jaxpr.eqns:
+            yield e
+            for v in e.params.values():
+                for sub in v if isinstance(v, (list, tuple)) else (v,):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        yield from eqns(sub)
+
+    obs_metrics.reset_metrics()
+    jaxpr = jax.make_jaxpr(lambda t: _prefill(lowered, t, 11)[0])(
+        _prompts(3, 11, seed=5))
+    names = []
+    for e in eqns(jaxpr.jaxpr):
+        if e.primitive.name == "pallas_call":
+            names.append(e.params["name"])
+            dots = [d for d in eqns(e.params["jaxpr"])
+                    if d.primitive.name == "dot_general"]
+            assert len(dots) == 2 * 3, e.params["name"]
+            assert {v.aval.dtype for d in dots for v in d.invars} == {
+                jnp.dtype(jnp.bfloat16)}
+    grouped = names.count("expert_mvm_pallas")
+    assert grouped > 0 and len(names) > grouped, names
+    assert obs_metrics.counter("analog.mvm.bf16x3_dots").value > 0
+
+
 def test_decode_through_cache_equals_full_forward(weights, lowered):
     """Left-padded prompts of mixed lengths: prefill, then three decode
     steps through both kinds of state (conv: the last two gated inputs;

@@ -20,9 +20,10 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-# the pattern by which the chip benchmark's trace reader finds the
-# pre-processing kernel (``maxmin_pool_roofline``)
-from benchmarks.chip.chipbench.tracing import MAXMIN_POOL
+# the patterns by which the chip benchmark's trace readers find the
+# pre-processing kernel (``maxmin_pool_roofline``) and the dense analog
+# kernels (``analog_mvm_roofline``)
+from benchmarks.chip.chipbench.tracing import ANALOG_MVM, MAXMIN_POOL
 from repro.kernels.analog_mvm import analog_mvm_pallas, analog_mvm_split_pallas
 from repro.kernels.analog_plan import analog_plan_pallas, default_block_b
 from repro.kernels.preproc import maxmin_pool_2d_pallas, preprocess_pallas
@@ -81,6 +82,33 @@ def test_analog_mvm_compiles(one_chip, shape, split):
     else:
         hlo = _hlo(analog_mvm_pallas, one_chip, (m, k), (k, n), (n,), (c, n))
     assert _kernel_calls(hlo) == 1
+
+
+def _kernel_name(hlo: str) -> str:
+    (call,) = [ln for ln in hlo.split("\nENTRY ", 1)[1].splitlines()
+               if "tpu_custom_call" in ln]
+    return call.strip().removeprefix("ROOT ").split(" = ")[0]
+
+
+# (rows, K, N): LFM2-8B-A1B's in_proj over a 4 x 1024-token prefill and
+# its 65,536-token head at a 4-row decode step
+LFM2_SPLIT_SHAPES = {
+    "in_proj_prefill": (4096, 2048, 6144),
+    "head_decode": (4, 2048, 65536),
+}
+
+
+@pytest.mark.parametrize("shape", list(LFM2_SPLIT_SHAPES))
+def test_lfm2_split_kernel_compiles(one_chip, shape):
+    """The dense signed-split kernel at the ``lfm2-prefill1k`` cell's
+    shapes, its three-pass contraction included, named as the chip
+    benchmark's ``analog_mvm_roofline`` reader finds it."""
+    m, k, n = LFM2_SPLIT_SHAPES[shape]
+    hlo = _hlo(analog_mvm_split_pallas, one_chip,
+               (m, k), (m, k), (k, n), (n,), (k // 128, n))
+    assert _kernel_calls(hlo) == 1
+    name = _kernel_name(hlo)
+    assert ANALOG_MVM.match(name), name
 
 
 @pytest.fixture(scope="module")
@@ -174,8 +202,6 @@ def test_expert_mvm_compiles(one_chip, shape):
     fn = functools.partial(expert_mvm_pallas, block_m=bm)
     hlo = jax.jit(fn).lower(*args).compile().as_text()
     assert _kernel_calls(hlo) == 1
-    (call,) = [ln for ln in hlo.split("\nENTRY ", 1)[1].splitlines()
-               if "tpu_custom_call" in ln]
-    name = call.strip().removeprefix("ROOT ").split(" = ")[0]
+    name = _kernel_name(hlo)
     reader = spec.reader("expert_mvm_roofline.lfm2_prefill1k")
     assert reader.EXPERT_MVM.match(name), name
