@@ -96,8 +96,13 @@ def plan_vs_percall_throughput(iters: int = 10) -> dict:
       w_eff / offsets and dispatches TWO analog passes per layer,
     - ``plan``: lower once, run many - requantization baked, still
       two-pass split,
-    - ``plan_fused``: lower once + the fused signed-split kernel - half
-      the analog dispatches per layer.
+    - ``plan_fused``: lower once + one signed-split dispatch per layer -
+      half the analog dispatches.
+
+    The two-pass variants run the executor's noisy route on a noiseless
+    chip: a readout key splits each signed pair into two independent
+    oracle passes (the arithmetic of ``ref.analog_mvm_split_ref``) that
+    draw no noise.
     """
     import jax
     import jax.numpy as jnp
@@ -117,33 +122,30 @@ def plan_vs_percall_throughput(iters: int = 10) -> dict:
     x = jax.random.normal(jax.random.PRNGKey(9), (m, d)) * 0.3
     macs = 3 * m * d * d
 
+    cfg_two = AnalogConfig(noise=NOISELESS, deterministic=False)
+    cfg_fused = AnalogConfig(noise=NOISELESS)
+    key = jax.random.PRNGKey(0)
+
     def percall(x):
         h = x
         for p in layers:
-            h = jax.nn.relu(apply_linear(
-                p, h, AnalogConfig(noise=NOISELESS, fused_split=False)
-            ))
+            h = jax.nn.relu(apply_linear(p, h, cfg_two, key=key))
         return h
 
-    cfg_two = AnalogConfig(noise=NOISELESS, fused_split=False)
-    cfg_fused = AnalogConfig(noise=NOISELESS)
     plan_two = lower_stack(layers, cfg_two)
     plan_fused = lower_stack(layers, cfg_fused)
 
     variants = {
-        "percall": jax.jit(percall),
-        "plan": jax.jit(lambda x: run_plan(plan_two, x)),
-        "plan_fused": jax.jit(lambda x: run_plan(plan_fused, x)),
+        "percall": percall,
+        "plan": lambda x: run_plan(plan_two, x, key=key),
+        "plan_fused": lambda x: run_plan(plan_fused, x),
     }
     dispatches = {}
-    for name, cfg in (("percall", None), ("plan", plan_two),
-                      ("plan_fused", plan_fused)):
+    for name, f in variants.items():
         reset_dispatch_count()
-        if cfg is None:
-            percall(x)
-        else:
-            run_plan(cfg, x)
+        f(x)
         dispatches[name] = dispatch_count()
+    variants = {name: jax.jit(f) for name, f in variants.items()}
 
     out = {"shape": f"3x[{m}x{d}x{d}]", "dispatches": dispatches}
     for name, f in variants.items():
@@ -227,9 +229,10 @@ def megakernel_vs_per_layer_throughput(iters: int = 10) -> dict:
       per-layer executor pays one chunk-scan per layer and the megakernel
       replaces all of it with one fused unrolled program.
 
-    Each runs twice through the SAME lowered plan: ``megakernel=False``
-    (layer-by-layer, N dispatches) vs ``megakernel=True`` (ONE dispatch,
-    inter-layer codes never reach HBM as separate kernel round-trips).
+    Each runs twice through the SAME lowered plan: ``run_layers``
+    (layer-by-layer, N dispatches) vs ``run`` (the megakernel route, ONE
+    dispatch, inter-layer codes never reach HBM as separate kernel
+    round-trips).
     Outputs are bit-exact by construction (gated in tests); the ``chain``
     speedup is the CI-gated entry.
     """
@@ -241,16 +244,18 @@ def megakernel_vs_per_layer_throughput(iters: int = 10) -> dict:
     from repro.exec.lower import lower_stack
     from repro.exec.run import dispatch_count, reset_dispatch_count
     from repro.exec.run import run as run_plan
+    from repro.exec.run import run_layers
     from repro.models import ecg as ECG
 
     def entry(plan, x):
         out = {}
-        for name, mk in (("per_layer", False), ("megakernel", True)):
+        for name, replay in (("per_layer", run_layers),
+                             ("megakernel", run_plan)):
             reset_dispatch_count()
-            run_plan(plan, x, megakernel=mk)
+            replay(plan, x)
             out[f"{name}_dispatches"] = dispatch_count()
             out[f"{name}_us"] = _best_of(
-                jax.jit(lambda c, mk=mk: run_plan(plan, c, megakernel=mk)),
+                jax.jit(lambda c, replay=replay: replay(plan, c)),
                 x, iters=iters, label=f"megakernel.{name}",
             )
         out["speedup"] = out["per_layer_us"] / out["megakernel_us"]
@@ -294,8 +299,8 @@ def attention_block_megakernel_throughput(iters: int = 10) -> dict:
     - ``megakernel``: ONE ``pallas_call`` - fused QKV, RoPE+causal
       attention, o, residual+RMSNorm, up/gate, SwiGLU, down all inside
       the kernel (1 dispatch),
-    - ``per_layer``: the 4-dispatch block fallback (same plan,
-      ``megakernel=False``),
+    - ``per_layer``: the 4-dispatch block replay (same plan,
+      ``run_layers``),
     - ``model_path``: the unfused ``_layer_apply`` reference (per-call
       lowering, its own dispatch count recorded) for context.
 
@@ -311,6 +316,7 @@ def attention_block_megakernel_throughput(iters: int = 10) -> dict:
     from repro.exec.lower import lower_block
     from repro.exec.run import dispatch_count, reset_dispatch_count
     from repro.exec.run import run as run_plan
+    from repro.exec.run import run_layers
     from repro.models import transformer as T
 
     cfg = ArchConfig(name="bench", family="dense", n_layers=1, d_model=256,
@@ -326,12 +332,13 @@ def attention_block_megakernel_throughput(iters: int = 10) -> dict:
     x = jax.random.normal(jax.random.PRNGKey(1), (b, seq, cfg.d_model)) * 0.5
 
     out = {"shape": f"block[{b}x{seq}x{cfg.d_model}]ff{cfg.d_ff}"}
-    for name, mk in (("per_layer", False), ("megakernel", True)):
+    for name, replay in (("per_layer", run_layers),
+                         ("megakernel", run_plan)):
         reset_dispatch_count()
-        run_plan(plan, x, megakernel=mk)
+        replay(plan, x)
         out[f"{name}_dispatches"] = dispatch_count()
         out[f"{name}_us"] = _best_of(
-            jax.jit(lambda c, mk=mk: run_plan(plan, c, megakernel=mk)), x,
+            jax.jit(lambda c, replay=replay: replay(plan, c)), x,
             iters=iters,
         )
     run_cfg = RunConfig(analog=acfg, activation_dtype="float32")

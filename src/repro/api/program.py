@@ -87,16 +87,15 @@ class CompiledModel:
 
     # ------------------------------------------------------------- execute
     def apply(self, *args, **kw):
-        """Run the compiled program.  Stacks take
-        ``(x, *, key=None, megakernel="auto")`` - ``megakernel`` selects
-        the whole-plan single-dispatch Pallas route for code-domain
-        chains ("auto" uses it when eligible, True requires it, False
-        forces the layer-by-layer replay); tree specs forward to the host
+        """Run the compiled program.  Stacks take ``(x, *, key=None)``
+        and replay as the whole-plan single-dispatch megakernel when the
+        plan and the call allow it, layer by layer otherwise
+        (:func:`repro.exec.run.run`); tree specs forward to the host
         program declared by the spec (``spec.apply_fn(model, *args,
         **kw)``).  Block specs take ``(x [batch, seq, d_model], *,
-        key=None, megakernel="auto")`` and replay the whole
-        attention+MLP block - single ``pallas_call`` when routed to the
-        megakernel, 4-dispatch per-layer fallback otherwise."""
+        key=None)`` and replay the whole attention+MLP block - single
+        ``pallas_call`` on the megakernel route, 4 dispatches per layer
+        on noisy calls."""
         if self.spec.apply_fn is not None:
             return self.spec.apply_fn(self, *args, **kw)
         if self.spec.kind not in ("stack", "block"):
@@ -105,18 +104,13 @@ class CompiledModel:
             )
         return self.run_stack(*args, **kw)
 
-    def run_stack(self, x: jax.Array, *, key: Optional[jax.Array] = None,
-                  megakernel="auto") -> jax.Array:
+    def run_stack(self, x: jax.Array, *,
+                  key: Optional[jax.Array] = None) -> jax.Array:
         """Execute the layer chain (plan replay - megakernel-routed when
         eligible - or the digital reference path with the same
         ReLU/flatten inter-layer glue)."""
         if self.lowered is not None:
-            return run_plan(self.lowered, x, key=key, megakernel=megakernel)
-        if megakernel is True:
-            raise ValueError(
-                "megakernel=True, but: digital mode compiles no analog "
-                "plan to megakernel"
-            )
+            return run_plan(self.lowered, x, key=key)
         h = x
         n = len(self.spec.layers)
         for i, l in enumerate(self.spec.layers):

@@ -188,6 +188,6 @@ class RunConfig:
     seq_sp: bool = True          # sequence-shard the inter-group residual
     # shard_map = explicit-collective EP (the §Perf winner); falls back
     # to the GSPMD path automatically when no mesh is active
-    moe_dispatch: str = "shard_map"  # shard_map | gspmd_ep | replicated_buf
+    moe_dispatch: str = "shard_map"  # shard_map | gspmd_ep
     attn_cp: str = "auto"            # context-parallel attn: auto | cp | off
     grad_compression: bool = False

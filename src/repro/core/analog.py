@@ -28,7 +28,6 @@ through the quantized linearization onto the float master weights.
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Optional
 
 import jax
@@ -54,10 +53,9 @@ class AnalogConfig:
     act_rms_codes: float = 9.0      # assumed RMS of activation codes (calib.)
     noise: NoiseConfig = dataclasses.field(default_factory=NoiseConfig)
     deterministic: bool = True      # no temporal readout noise (standalone mode)
-    use_pallas: bool = False        # dispatch hot loop to the Pallas kernel
-    fused_split: bool = True        # one fused kernel for signed-split pairs
-    fused_epilogue: bool = False    # emit ADC epilogues inside the kernel
-    #                                 (inference-only; needs use_pallas)
+    # the analog VMM's implementation: None = Pallas on TPU, the jnp
+    # oracle elsewhere; True/False force one (repro.kernels.ops)
+    use_pallas: Optional[bool] = None
 
     def replace(self, **kw) -> "AnalogConfig":
         return dataclasses.replace(self, **kw)
@@ -67,8 +65,8 @@ DIGITAL = AnalogConfig(mode="digital", noise=noise_lib.NOISELESS)
 
 
 # --------------------------------------------------------------------------
-# core emulation op (pure-jnp path; the Pallas kernel in repro.kernels
-# implements the identical chunk loop and is tested against this)
+# core emulation op: the deterministic VMM is repro.kernels.ops.analog_mvm
+# (Pallas kernel or jnp oracle); only readout noise is emulated here
 # --------------------------------------------------------------------------
 def _pad_to_chunks(a_code: jax.Array, w_eff: jax.Array, chunk_rows: int):
     k = a_code.shape[-1]
@@ -94,23 +92,34 @@ def analog_matmul(
     w_eff:  [K, N] effective analog weights (quantized codes x fp gain)
     gain:   scalar or [N] analog gain (code domain)
     chunk_offset: [C, N] fixed-pattern ADC offsets or None
+
+    Without temporal readout noise (``cfg.deterministic``, no key, or no
+    readout spread) this is :func:`repro.kernels.ops.analog_mvm` under
+    its HIL gradient.  With noise the chunk loop is emulated here: no
+    kernel draws readout noise.
     """
     a_code, w_eff, n_chunks = _pad_to_chunks(a_code, w_eff, cfg.chunk_rows)
     n = w_eff.shape[-1]
     batch_shape = a_code.shape[:-1]
+    fast = cfg.mode == "analog_fast"
+    rn = None
+    if not cfg.deterministic:
+        rn = noise_lib.readout_noise(
+            readout_key,
+            batch_shape + ((n,) if fast else (n_chunks, n)), cfg.noise,
+        )
 
-    if cfg.use_pallas and (cfg.deterministic or readout_key is None):
-        # dispatch the hot loop to the Pallas kernel (HIL custom-vjp wrapper)
+    if rn is None:
         from repro.kernels import ops as kernel_ops
 
-        a2 = a_code.reshape(-1, a_code.shape[-1])
         y2 = kernel_ops.analog_mvm(
-            a2, w_eff, jnp.broadcast_to(jnp.asarray(gain, jnp.float32), (n,)),
-            chunk_offset, cfg.chunk_rows, cfg.mode != "analog_fast", True,
+            a_code.reshape(-1, a_code.shape[-1]), w_eff,
+            jnp.broadcast_to(jnp.asarray(gain, jnp.float32), (n,)),
+            chunk_offset, cfg.chunk_rows, not fast, cfg.use_pallas,
         )
         return y2.reshape(batch_shape + (n,))
 
-    if cfg.mode == "analog_fast":
+    if fast:
         # beyond-paper: one fused matmul, single final saturation with the
         # accumulated range (C * [-128, 127]).
         total = jnp.einsum(
@@ -121,37 +130,12 @@ def analog_matmul(
         v = total * gain
         if chunk_offset is not None:
             v = v + chunk_offset.sum(axis=0)
-        rn = noise_lib.readout_noise(
-            readout_key, batch_shape + (n,), cfg.noise
-        )
-        if rn is not None:
-            v = v + rn * jnp.sqrt(float(n_chunks))
+        v = v + rn * jnp.sqrt(float(n_chunks))
         lo = float(BSS2.adc_min) * n_chunks
         hi = float(BSS2.adc_max) * n_chunks
         return jnp.clip(quant._round_ste(v), lo, hi)
 
-    # faithful: per-chunk ADC before digital accumulation.
-    # Memory note (§Perf cell 3): naively materializing all chunk partials
-    # [..., C, N] costs C x the activation memory (measured 526 GiB temp on
-    # glm4/train_4k), and a naive scan re-saves the carry per chunk for the
-    # backward.  The deterministic path therefore runs a chunk-scan inside a
-    # custom VJP whose backward is the HIL linearization (paper §III-B:
-    # backward never differentiates the hardware) - O([..., N]) memory,
-    # exactly like the Pallas kernel's VMEM accumulator.
-    rn = noise_lib.readout_noise(
-        readout_key, batch_shape + (n_chunks, n), cfg.noise
-    )
-    if rn is None:
-        off = (
-            chunk_offset
-            if chunk_offset is not None
-            else jnp.zeros((n_chunks, 1), jnp.float32)
-        )
-        return _faithful_mm(
-            a_code, w_eff, jnp.asarray(gain, jnp.float32), off,
-            cfg.chunk_rows,
-        )
-
+    # faithful: per-chunk ADC before digital accumulation
     a_c = a_code.reshape(batch_shape + (n_chunks, cfg.chunk_rows))
     w_c = w_eff.reshape(n_chunks, cfg.chunk_rows, n)
     v = jnp.einsum(
@@ -164,53 +148,6 @@ def analog_matmul(
     v = v + rn
     adc = quant.adc_readout(v)
     return adc.sum(axis=-2)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _faithful_mm(a_code, w_eff, gain, chunk_offset, chunk_rows):
-    """Chunk-scanned faithful analog VMM with HIL backward."""
-    k = a_code.shape[-1]
-    n = w_eff.shape[-1]
-    n_chunks = k // chunk_rows
-    batch_shape = a_code.shape[:-1]
-    a_c = a_code.reshape(batch_shape + (n_chunks, chunk_rows))
-    nd = a_c.ndim - 2
-    a_s = jnp.moveaxis(a_c, nd, 0)                 # [C, ..., chunk_rows]
-    w_c = w_eff.reshape(n_chunks, chunk_rows, n)
-
-    def chunk_step(acc, inp):
-        a_i, w_i, off_i = inp
-        v = jnp.einsum(
-            "...k,kn->...n", a_i, w_i, precision=quant.ANALOG_PRECISION,
-            preferred_element_type=jnp.float32,
-        ) * gain + off_i
-        return acc + quant.adc_readout(v), None
-
-    acc0 = jnp.zeros(batch_shape + (n,), jnp.float32)
-    out, _ = jax.lax.scan(chunk_step, acc0, (a_s, w_c, chunk_offset))
-    return out
-
-
-def _faithful_mm_fwd(a_code, w_eff, gain, chunk_offset, chunk_rows):
-    out = _faithful_mm(a_code, w_eff, gain, chunk_offset, chunk_rows)
-    return out, (a_code, w_eff, gain, chunk_offset)
-
-
-def _faithful_mm_bwd(chunk_rows, res, g):
-    # HIL gradient (paper §III-B): backward through the linearization
-    # y ~= gain * (a @ w); saturation/rounding are not differentiated.
-    a_code, w_eff, gain, chunk_offset = res
-    gg = (g * gain).astype(jnp.float32)
-    da = gg @ w_eff.T
-    a2 = a_code.reshape(-1, a_code.shape[-1])
-    g2 = gg.reshape(-1, gg.shape[-1])
-    dw = a2.T @ g2
-    dgain = jnp.zeros_like(gain)       # frozen calibration state
-    d_off = jnp.zeros_like(chunk_offset)
-    return da.astype(a_code.dtype), dw.astype(w_eff.dtype), dgain, d_off
-
-
-_faithful_mm.defvjp(_faithful_mm_fwd, _faithful_mm_bwd)
 
 
 # --------------------------------------------------------------------------

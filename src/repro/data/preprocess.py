@@ -13,6 +13,7 @@ profiler session is recording.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -25,12 +26,15 @@ POOL_WINDOW = 32
 
 
 def preprocess(raw: jax.Array, *, window: int = POOL_WINDOW,
-               quant_shift: int = 4, use_pallas: bool = False) -> jax.Array:
+               quant_shift: int = 4,
+               use_pallas: Optional[bool] = None) -> jax.Array:
     """raw: [..., C, T] 12-bit sample values -> [..., C, (T-1)//window]
     5-bit activation codes (integer-valued float32).
 
     ``quant_shift``: right-shift applied by the FPGA quantizer; 4 bits maps
     the typical max-min derivative range (<512 counts) onto [0, 31].
+    ``use_pallas``: the fused Pallas kernel or the jnp chain; ``None``
+    is the platform's choice (:func:`repro.kernels.ops.resolve_pallas`).
 
     The host stage around the jitted chain: while a collector or a
     profiler session is recording (``obs.observed()``), an eager call
@@ -38,6 +42,7 @@ def preprocess(raw: jax.Array, *, window: int = POOL_WINDOW,
     span's host time in the histogram ``ecg.preprocess_us``.  Otherwise,
     and inside an outer trace, it is the chain alone.
     """
+    use_pallas = kernel_ops.resolve_pallas(use_pallas)
     if not obs.observed() or isinstance(raw, jax.core.Tracer):
         return _preprocess(raw, window=window, quant_shift=quant_shift,
                            use_pallas=use_pallas)

@@ -51,4 +51,5 @@ from repro.exec.run import (  # noqa: F401
     run_expert_stack,
     run_group,
     run_layer,
+    run_layers,
 )

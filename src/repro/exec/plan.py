@@ -318,9 +318,8 @@ class GroupPlan:
     @property
     def expected_dispatches(self) -> int:
         """A fusion group replays as ONE analog dispatch by construction
-        (split-pair members still dispatch twice without
-        ``cfg.fused_split``; see :class:`AnalogPlan.expected_dispatches`
-        for the counting contract)."""
+        (see :class:`AnalogPlan.expected_dispatches` for the counting
+        contract)."""
         return 1
 
 
@@ -517,19 +516,10 @@ class AnalogPlan:
         if self.block is not None:
             # a fused attention+MLP block's canonical replay IS the
             # megakernel: one dispatch for the whole block (the
-            # per-layer fallback costs 4; see run._run_block_fallback)
+            # per-layer replay costs 4; see run._run_block_fallback)
             return 1
-        is_codes = self.expects_codes
-        n = 0
-        last = len(self.layers) - 1
-        for i, lp in enumerate(self.layers):
-            signed = "none" if is_codes else lp.signed_input
-            n += 2 if (signed == "split" and not self.cfg.fused_split) else 1
-            if lp.epilogue == EPILOGUE_NONE and i < last:
-                is_codes = False
-            else:
-                is_codes = lp.epilogue == EPILOGUE_RELU_SHIFT
-        return n
+        # one dispatch a layer: a signed-split pair is one fused pass
+        return len(self.layers)
 
 
 jax.tree_util.register_dataclass(

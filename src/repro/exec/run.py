@@ -6,30 +6,29 @@ Responsibilities left at run time (everything else was baked by
 - dynamic activation calibration (per-call abs-max, the FPGA right-shift
   choice) when ``cfg.act_calib == "dynamic"``,
 - signed-input encoding of the incoming activations (split/offset/none),
-- dispatch of the analog passes - ONE fused signed-split kernel per split
-  layer (``cfg.fused_split``, default) instead of the legacy two
-  ``analog_matmul`` calls, halving weight streaming and dispatches,
+- dispatch of the analog passes - ONE signed-split dispatch per split
+  layer (:func:`repro.kernels.ops.analog_mvm_split`); only noisy passes,
+  which need independent readout keys, run as two,
 - the inter-layer ADC epilogue: ReLU + right-shift requantization to
-  5-bit codes (paper §II-A).  In the differentiable path it runs as
-  elementwise STE ops; on the deterministic inference path with
-  ``cfg.use_pallas`` and ``cfg.fused_epilogue`` it is emitted INSIDE the
-  Pallas kernel, so a stacked plan (the ECG conv->fc1->fc2 chain) runs as
-  one jitted analog program with no float glue between layers,
+  5-bit codes (paper §II-A), as elementwise STE ops,
 - temporal readout noise keys (mock-mode training),
-- megakernel routing: an eligible plan (packed at lower time, see
-  ``exec.lower.pack_megakernel``) replays as ONE dispatch - the whole
-  chain in a single ``pallas_call`` with VMEM-resident inter-layer
-  activations (``cfg.use_pallas``), or as one fused jnp chain otherwise.
-  Code-domain chains, static-calib float/mixed chains and fused
+- megakernel routing: :func:`run` replays an eligible plan (packed at
+  lower time, see ``exec.lower.pack_megakernel``) as ONE dispatch - the
+  whole chain in a single ``pallas_call`` with VMEM-resident inter-layer
+  activations, or as one fused jnp chain where the kernels run their
+  oracle.  Code-domain chains, static-calib float/mixed chains and fused
   attention+MLP block plans (``plan.block``) all take this route; noisy
-  replay, dynamic-calib float hand-offs and stacked plans fall back to
-  the layer-by-layer path; ``run(..., megakernel=True)`` raises with the
-  first offending layer instead of silently falling back.
+  replay, dynamic-calib float hand-offs, stacked plans and inputs the
+  packed schedule cannot take replay layer by layer (:func:`run_layers`;
+  :func:`megakernel_fallback_reason` says why).
+
+Which implementation computes each analog pass - a Pallas kernel or its
+jnp oracle - is :mod:`repro.kernels.ops`' choice, from ``cfg.use_pallas``.
 
 Dispatch accounting: every analog pass issued by the executor bumps
 :data:`ANALOG_DISPATCHES` at trace time - tests and benchmarks use
-:func:`reset_dispatch_count` / :func:`dispatch_count` to verify the fused
-path issues half the dispatches of the two-pass path.
+:func:`reset_dispatch_count` / :func:`dispatch_count` to check a replay
+against :attr:`repro.exec.plan.AnalogPlan.expected_dispatches`.
 """
 from __future__ import annotations
 
@@ -54,17 +53,6 @@ from repro.exec.plan import (
 from repro.obs import metrics as _obs_metrics
 
 ANALOG_DISPATCHES = 0
-
-# Small-batch guard for megakernel="auto": route calls with fewer final
-# batch rows than this to the per-layer replay.  After the bounded
-# rows-per-grid-step fix (kernels.analog_plan.default_block_b) the
-# megakernel measures FASTER than the per-layer replay at every batch
-# size on this target (b=1: 6.4x .. b=64: 1.5x on the ECG chain), so the
-# default threshold of 1 never fires - the knob exists so a target where
-# tiny batches lose can raise it without code changes (megakernel=True
-# always overrides it).
-MEGAKERNEL_MIN_ROWS = 1
-
 
 def reset_dispatch_count() -> None:
     global ANALOG_DISPATCHES
@@ -93,7 +81,8 @@ def _pad_codes(a: jax.Array, k_pad: int) -> jax.Array:
 def _epilogue_ste(y_int: jax.Array, shift: int) -> jax.Array:
     """Elementwise ADC epilogue with straight-through gradients: ReLU at
     the (offset-aligned) readout, then right-shift requantization onto the
-    5-bit activation range.  Value-identical to the in-kernel epilogue."""
+    5-bit activation range.  Value-identical to the megakernel's code
+    hand-off."""
     return quant.requantize_5bit(jnp.maximum(y_int, 0.0), shift)
 
 
@@ -145,7 +134,7 @@ def run_layer(
     elif signed == "split":
         a_pos = _pad_codes(quant.quantize_act(x, a_scale), k_pad)
         a_neg = _pad_codes(quant.quantize_act(-x, a_scale), k_pad)
-        if cfg.fused_split and rk is None:
+        if rk is None:
             # ONE dispatch over shared weight tiles for both passes
             from repro.kernels import ops as kernel_ops
 
@@ -155,11 +144,10 @@ def run_layer(
                 a_pos.reshape(-1, k_pad), a_neg.reshape(-1, k_pad),
                 lp.w_eff, jnp.broadcast_to(gain, (lp.n,)), lp.chunk_offset,
                 lp.chunk_rows, cfg.mode != "analog_fast", cfg.use_pallas,
-                True,
             )
             y_int = y2.reshape(batch_shape + (lp.n,))
         else:
-            # two-pass oracle (kept: noisy passes need independent keys)
+            # noisy passes need independent readout keys: two dispatches
             k1, k2 = (None, None) if rk is None else tuple(
                 jax.random.split(rk)
             )
@@ -389,27 +377,6 @@ def run_group(
     raise ValueError(f"unknown group kind {gp.kind!r}")
 
 
-def _run_layer_fused_infer(
-    lp: LayerPlan, codes: jax.Array, cfg: AnalogConfig
-) -> jax.Array:
-    """Deterministic code-domain layer with the epilogue fused into the
-    Pallas kernel (no custom VJP - inference only)."""
-    from repro.kernels import ops as kernel_ops
-
-    a = _pad_codes(codes.astype(jnp.float32), lp.k_pad)
-    batch_shape = a.shape[:-1]
-    epi = (EPILOGUE_RELU_SHIFT, lp.shift) \
-        if lp.epilogue == EPILOGUE_RELU_SHIFT else None
-    _count()
-    y = kernel_ops.analog_mvm_infer(
-        a.reshape(-1, a.shape[-1]), None, lp.w_eff,
-        jnp.broadcast_to(lp.gain, (lp.n,)), lp.chunk_offset,
-        chunk_rows=lp.chunk_rows, faithful=cfg.mode != "analog_fast",
-        use_pallas=cfg.use_pallas, epilogue=epi,
-    )
-    return y.reshape(batch_shape + (lp.n,))
-
-
 def _megakernel_batch_shape(plan: AnalogPlan, x: jax.Array):
     """Resolve the megakernel's output batch shape from ``x``'s leading
     dims, or return a reason string when the shapes cannot feed the packed
@@ -474,14 +441,12 @@ def _megakernel_route(
     cfg: AnalogConfig,
     key: Optional[jax.Array],
     x_is_codes: bool,
-    forced: bool = False,
 ):
     """Resolve the megakernel route for one ``run`` call: the output
     batch-shape tuple when it can be taken, else a reason string.
     Structural ineligibility is decided at lower time (no ``mega``
-    packing baked); noisy replay, entry-domain mismatches, sub-threshold
-    batches (``megakernel="auto"`` only) and batch-shape mismatches keep
-    the layer-by-layer path."""
+    packing baked); noisy replay, entry-domain mismatches and batch-shape
+    mismatches keep the layer-by-layer path."""
     if plan.mega is None:
         from repro.exec.lower import megakernel_ineligible_reason
 
@@ -499,20 +464,7 @@ def _megakernel_route(
         )
     if key is not None and not cfg.deterministic:
         return "noisy replay (readout-noise keys) is layer-by-layer"
-    lead = _megakernel_batch_shape(plan, x)
-    if isinstance(lead, str):
-        return lead
-    if not forced:
-        rows = 1
-        for d in lead:
-            rows *= int(d)
-        if rows < MEGAKERNEL_MIN_ROWS:
-            return (
-                f"batch rows {rows} < MEGAKERNEL_MIN_ROWS "
-                f"({MEGAKERNEL_MIN_ROWS}); tiny batches replay per-layer "
-                "(megakernel=True overrides)"
-            )
-    return lead
+    return _megakernel_batch_shape(plan, x)
 
 
 def megakernel_fallback_reason(
@@ -563,14 +515,12 @@ def _run_block_fallback(
 def _run_block(
     plan: AnalogPlan,
     x: jax.Array,
-    *,
     key: Optional[jax.Array],
-    megakernel,
 ) -> jax.Array:
     """Execute a block plan (:func:`repro.exec.lower.lower_block`):
     ``x [batch, seq, d_model]`` -> same shape, the whole attention+MLP
     block as ONE analog dispatch (5 on the unlowered model path, 4 on the
-    per-layer fallback)."""
+    per-layer replay, which noisy calls take)."""
     from repro.kernels import ops as kernel_ops
 
     bg, cfg, mega = plan.block, plan.cfg, plan.mega
@@ -585,16 +535,8 @@ def _run_block(
             f"seq={bg.seq}, got seq={x.shape[1]}; re-lower for this "
             "length (the in-kernel attention bakes its positions)"
         )
-    reason = None
-    if megakernel is False:
-        reason = "megakernel=False"
-    elif key is not None and not cfg.deterministic:
-        reason = "noisy replay (readout-noise keys) is layer-by-layer"
-    if reason is not None:
-        if megakernel is True:
-            raise ValueError(f"megakernel=True, but: {reason}")
-        _obs_metrics.counter("exec.run.per_layer").inc()
-        return _run_block_fallback(plan, x, key)
+    if key is not None and not cfg.deterministic:
+        return run_layers(plan, x, key=key)
     _obs_metrics.counter("exec.run.megakernel").inc()
     b, s, d = x.shape
     _count()
@@ -614,7 +556,6 @@ def run(
     *,
     key: Optional[jax.Array] = None,
     x_is_codes: Optional[bool] = None,
-    megakernel="auto",
 ) -> jax.Array:
     """Execute a whole lowered stack: one jitted analog program.
 
@@ -624,46 +565,46 @@ def run(
     ``input_domain``; plans built without one fall back to the legacy
     first-layer-epilogue inference).
 
-    ``megakernel`` selects the whole-plan single-dispatch route for
-    eligible chains (code-domain, static-calib float/mixed, and fused
-    attention+MLP blocks): ``"auto"`` (default) uses it whenever the plan
-    and call are eligible and the batch clears
-    :data:`MEGAKERNEL_MIN_ROWS`, ``False`` forces the layer-by-layer
-    replay, ``True`` requires it (raises ``ValueError`` naming the first
-    offending layer / fallback reason when the plan or call cannot take
-    it, and overrides the small-batch threshold).
+    The call takes the whole-plan single-dispatch megakernel whenever the
+    plan and the call allow it (code-domain, static-calib float/mixed
+    chains and fused attention+MLP blocks), else the per-layer replay of
+    :func:`run_layers`; :func:`megakernel_fallback_reason` names why.
     """
-    cfg = plan.cfg
-    n = len(plan.layers)
-    if megakernel not in (True, False, "auto"):
-        raise ValueError(f"megakernel must be 'auto'|True|False, "
-                         f"got {megakernel!r}")
     if plan.block is not None:
-        return _run_block(plan, x, key=key, megakernel=megakernel)
+        return _run_block(plan, x, key)
     if x_is_codes is None:
         x_is_codes = plan.expects_codes
-    if megakernel is True or megakernel == "auto":
-        route = _megakernel_route(plan, x, cfg, key, x_is_codes,
-                                  forced=megakernel is True)
-        if not isinstance(route, str):
-            _obs_metrics.counter("exec.run.megakernel").inc()
-            return _run_megakernel(plan, x, route)
-        if megakernel is True:
-            raise ValueError(f"megakernel=True, but: {route}")
+    route = _megakernel_route(plan, x, plan.cfg, key, x_is_codes)
+    if isinstance(route, str):
+        return run_layers(plan, x, key=key, x_is_codes=x_is_codes)
+    _obs_metrics.counter("exec.run.megakernel").inc()
+    return _run_megakernel(plan, x, route)
+
+
+def run_layers(
+    plan: AnalogPlan,
+    x: jax.Array,
+    *,
+    key: Optional[jax.Array] = None,
+    x_is_codes: Optional[bool] = None,
+) -> jax.Array:
+    """Replay a lowered plan layer by layer: one analog dispatch per layer
+    (per pass, for noisy split layers), the route :func:`run` takes when
+    the megakernel's cannot be taken.  A block plan replays as its 4
+    dispatches with the digital glue in jnp (:func:`_run_block_fallback`).
+    Bit-exact against the megakernel route where both can run."""
     _obs_metrics.counter("exec.run.per_layer").inc()
+    if plan.block is not None:
+        return _run_block_fallback(plan, x, key)
+    cfg = plan.cfg
+    n = len(plan.layers)
+    if x_is_codes is None:
+        x_is_codes = plan.expects_codes
     ks = list(jax.random.split(key, n)) if key is not None else [None] * n
     is_codes = x_is_codes
     h = x
     for i, (lp, k) in enumerate(zip(plan.layers, ks)):
-        fuse_in_kernel = (
-            cfg.fused_epilogue and cfg.use_pallas and k is None
-            and is_codes and lp.signed_input == "none"
-            and lp.epilogue == EPILOGUE_RELU_SHIFT
-        )
-        if fuse_in_kernel:
-            h = _run_layer_fused_infer(lp, h, cfg)
-        else:
-            h = run_layer(lp, h, cfg, key=k, x_is_codes=is_codes)
+        h = run_layer(lp, h, cfg, key=k, x_is_codes=is_codes)
         if lp.epilogue == EPILOGUE_NONE and i < n - 1:
             # float hand-off between layers: ReLU in the float domain,
             # next layer re-quantizes (legacy inter-layer glue semantics)

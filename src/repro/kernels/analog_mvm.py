@@ -133,24 +133,8 @@ def block_cols(n: int, block_m: int) -> int:
     return div if 2 * div >= cap else cap
 
 
-def _apply_epilogue(acc, epilogue):
-    """ADC epilogue (paper §II-A), applied to the digitally accumulated ADC
-    codes before they leave VMEM: ReLU at the readout followed by a bitwise
-    right-shift requantization onto the 5-bit input-activation range.  The
-    next stacked analog layer consumes the result directly as event codes,
-    so the inter-layer glue never touches HBM as floats."""
-    if epilogue is None:
-        return acc
-    kind, shift = epilogue
-    if kind != "relu_shift":
-        raise ValueError(f"unknown epilogue {epilogue!r}")
-    acc = jnp.maximum(acc, 0.0)
-    acc = jnp.floor(acc / float(1 << shift))
-    return jnp.clip(acc, 0.0, float(BSS2.a_max))
-
-
 def _kernel(a_ref, w_ref, gain_ref, off_ref, o_ref, acc_ref, *,
-            n_chunks: int, faithful: bool, epilogue=None):
+            n_chunks: int, faithful: bool):
     c = pl.program_id(2)
 
     @pl.when(c == 0)
@@ -171,14 +155,13 @@ def _kernel(a_ref, w_ref, gain_ref, off_ref, o_ref, acc_ref, *,
             lo = float(BSS2.adc_min) * n_chunks
             hi = float(BSS2.adc_max) * n_chunks
             acc = jnp.clip(jnp.round(acc), lo, hi)
-        o_ref[...] = _apply_epilogue(acc, epilogue)
+        o_ref[...] = acc
 
 
 @functools.partial(
     jax.jit,
     static_argnames=(
         "chunk_rows", "faithful", "block_m", "block_n", "interpret",
-        "epilogue",
     ),
 )
 def analog_mvm_pallas(
@@ -192,7 +175,6 @@ def analog_mvm_pallas(
     block_m: Optional[int] = None,        # default: block_rows(M)
     block_n: Optional[int] = None,        # default: block_cols(N, block_m)
     interpret: bool = False,
-    epilogue=None,                        # None | ("relu_shift", shift)
 ) -> jax.Array:
     """Chunked saturating analog VMM; bit-exact vs the fp32 oracle in
     interpret mode.  ``a_code`` holds integer codes of magnitude at most
@@ -220,10 +202,7 @@ def analog_mvm_pallas(
 
     grid = (mp // block_m, np_ // block_n, n_chunks)
     out = pl.pallas_call(
-        functools.partial(
-            _kernel, n_chunks=n_chunks, faithful=faithful,
-            epilogue=epilogue,
-        ),
+        functools.partial(_kernel, n_chunks=n_chunks, faithful=faithful),
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_m, chunk_rows), lambda i, j, c: (i, c)),
@@ -249,8 +228,7 @@ def analog_mvm_pallas(
 # fused signed-split kernel
 # --------------------------------------------------------------------------
 def _split_kernel(ap_ref, an_ref, w_ref, gain_ref, off_ref, o_ref,
-                  accp_ref, accn_ref, *, n_chunks: int, faithful: bool,
-                  epilogue=None):
+                  accp_ref, accn_ref, *, n_chunks: int, faithful: bool):
     """One grid pass over the shared weight tiles evaluates BOTH analog
     passes of the signed-split encoding (paper §II-A: positive and negative
     activation parts on the same synapse columns).  Each (bm, bn, c) step
@@ -287,14 +265,13 @@ def _split_kernel(ap_ref, an_ref, w_ref, gain_ref, off_ref, o_ref,
             hi = float(BSS2.adc_max) * n_chunks
             accp = jnp.clip(jnp.round(accp), lo, hi)
             accn = jnp.clip(jnp.round(accn), lo, hi)
-        o_ref[...] = _apply_epilogue(accp - accn, epilogue)
+        o_ref[...] = accp - accn
 
 
 @functools.partial(
     jax.jit,
     static_argnames=(
         "chunk_rows", "faithful", "block_m", "block_n", "interpret",
-        "epilogue",
     ),
 )
 def analog_mvm_split_pallas(
@@ -309,7 +286,6 @@ def analog_mvm_split_pallas(
     block_m: Optional[int] = None,        # default: block_rows(M)
     block_n: Optional[int] = None,        # default: block_cols(N, block_m)
     interpret: bool = False,
-    epilogue=None,                        # None | ("relu_shift", shift)
 ) -> jax.Array:
     """Fused signed-split analog VMM: ``mvm(a_pos) - mvm(a_neg)`` in one
     kernel launch with single weight streaming.  Bit-exact (fp32) against
@@ -340,10 +316,7 @@ def analog_mvm_split_pallas(
 
     grid = (mp // block_m, np_ // block_n, n_chunks)
     out = pl.pallas_call(
-        functools.partial(
-            _split_kernel, n_chunks=n_chunks, faithful=faithful,
-            epilogue=epilogue,
-        ),
+        functools.partial(_split_kernel, n_chunks=n_chunks, faithful=faithful),
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_m, chunk_rows), lambda i, j, c: (i, c)),

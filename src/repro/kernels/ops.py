@@ -1,6 +1,11 @@
 """Jitted public wrappers around the Pallas kernels with automatic
 platform dispatch and a custom-VJP HIL gradient.
 
+This is the one module that chooses how an analog VMM is computed: a
+Pallas kernel or its jnp oracle in :mod:`repro.kernels.ref`, by the
+``use_pallas`` argument every wrapper takes (:func:`resolve_pallas`;
+``None`` is the platform's choice).
+
 - On TPU the Mosaic kernels run natively.
 - On CPU ``interpret=True`` executes the kernel bodies in Python for
   bit-level validation against :mod:`repro.kernels.ref`.  No other
@@ -27,9 +32,7 @@ from repro.kernels import ref as ref_lib
 from repro.kernels.analog_mvm import (
     analog_mvm_pallas,
     analog_mvm_split_pallas,
-    code_dot,
     expert_mvm_pallas,
-    split_bf16x3,
 )
 from repro.kernels import analog_plan
 from repro.kernels.analog_plan import analog_plan_pallas
@@ -38,6 +41,13 @@ from repro.kernels.preproc import maxmin_pool_2d_pallas, preprocess_pallas
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def resolve_pallas(use_pallas: Optional[bool] = None) -> bool:
+    """Whether a wrapper runs its Pallas kernel: ``None`` takes the
+    platform's choice (Pallas on TPU, the jnp oracle elsewhere); a bool
+    forces one (``True`` on CPU runs the kernel interpreted)."""
+    return _on_tpu() if use_pallas is None else use_pallas
 
 
 def _interpret() -> bool:
@@ -79,42 +89,6 @@ def _column_parallel(kernel, acts, w_eff, gain, chunk_offset, n_chunks):
     )(*acts, w_eff, gain, chunk_offset)
 
 
-def _mvm_split_chunk_scan(a_pos, a_neg, w_eff, gain, chunk_offset,
-                          chunk_rows):
-    """Faithful fused-split VMM as one chunk scan: both passes share each
-    weight chunk while it is live and their ADC codes subtract into a
-    single [M, N] accumulator - no [2M, K] activation concat, no
-    [2M, C, N] per-chunk tensor.  Per-pass arithmetic is identical to
-    the two-pass oracle and the codes are integer-valued f32, so the
-    per-chunk subtraction order is bit-exact against ``yp - yn``."""
-    m, k = a_pos.shape
-    n = w_eff.shape[1]
-    assert k % chunk_rows == 0, (k, chunk_rows)
-    c = k // chunk_rows
-    a_p = jnp.moveaxis(
-        a_pos.reshape(m, c, chunk_rows).astype(jnp.float32), 1, 0
-    )
-    a_n = jnp.moveaxis(
-        a_neg.reshape(m, c, chunk_rows).astype(jnp.float32), 1, 0
-    )
-    w_c = w_eff.reshape(c, chunk_rows, n).astype(jnp.float32)
-    off = (jnp.zeros((c, 1), jnp.float32) if chunk_offset is None
-           else chunk_offset.astype(jnp.float32))
-
-    def step(acc, xs):
-        ap_i, an_i, w_i, o_i = xs
-        w_i = split_bf16x3(w_i)
-        vp = code_dot(ap_i, w_i) * gain + o_i
-        vn = code_dot(an_i, w_i) * gain + o_i
-        adc_p = jnp.clip(jnp.round(vp), BSS2.adc_min, BSS2.adc_max)
-        adc_n = jnp.clip(jnp.round(vn), BSS2.adc_min, BSS2.adc_max)
-        return acc + (adc_p - adc_n), None
-
-    y, _ = jax.lax.scan(step, jnp.zeros((m, n), jnp.float32),
-                        (a_p, a_n, w_c, off))
-    return y
-
-
 @functools.partial(
     jax.custom_vjp, nondiff_argnums=(4, 5, 6)
 )
@@ -128,8 +102,7 @@ def analog_mvm(
     use_pallas: Optional[bool] = None,
 ) -> jax.Array:
     """[M, K] x [K, N] chunked saturating analog VMM (forward = hardware)."""
-    use = _on_tpu() if use_pallas is None else use_pallas
-    if use:
+    if resolve_pallas(use_pallas):
         return _column_parallel(
             functools.partial(analog_mvm_pallas, chunk_rows=chunk_rows,
                               faithful=faithful, interpret=_interpret()),
@@ -154,7 +127,7 @@ def _analog_mvm_bwd(chunk_rows, faithful, use_pallas, res, g):
     # backpropagate through that linearization (STE across round/clip).
     # The gain is frozen calibration state (paper §III-B: only the float
     # master weights train; gain/offsets come from per-layer calibration,
-    # Weis et al.) - same semantics as core.analog._faithful_mm_bwd.
+    # Weis et al.).
     a_code, w_eff, gain, chunk_offset = res
     g_scaled = g * gain                      # [M, N] * [N]
     da = g_scaled @ w_eff.T
@@ -169,7 +142,7 @@ analog_mvm.defvjp(_analog_mvm_fwd, _analog_mvm_bwd)
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8)
+    jax.custom_vjp, nondiff_argnums=(5, 6, 7)
 )
 def analog_mvm_split(
     a_pos: jax.Array,
@@ -180,50 +153,21 @@ def analog_mvm_split(
     chunk_rows: int = BSS2.signed_rows,
     faithful: bool = True,
     use_pallas: Optional[bool] = None,
-    fused: bool = True,
 ) -> jax.Array:
-    """Signed-split analog VMM ``mvm(a_pos) - mvm(a_neg)`` as ONE dispatch.
-
-    ``fused=True`` (default) shares the weight tiles between the two
-    passes: on the Pallas path via the single-grid split kernel, on the
-    jnp path (faithful) via a chunk scan that subtracts the two passes'
-    integer ADC codes in place (:func:`_mvm_split_chunk_scan` - the fix
-    for the fused dispatch benching SLOWER than per-call).  The code-
-    domain arithmetic is exact under any accumulation order; per-chunk
-    pre-round products carry the usual fp32 contraction-order
-    sensitivity at exact round boundaries (same caveat the Pallas kernel
-    documents), which the pinned bit-exactness tests bound.  Fast mode
-    sums pre-round reals and keeps the stacked-batch oracle matmul,
-    bit-exact against the two-pass oracle by construction.
-    """
-    use = _on_tpu() if use_pallas is None else use_pallas
-    if not fused:
-        return ref_lib.analog_mvm_split_ref(
-            a_pos, a_neg, w_eff, gain, chunk_offset,
-            chunk_rows=chunk_rows, faithful=faithful,
-        )
-    if use:
+    """Signed-split analog VMM ``mvm(a_pos) - mvm(a_neg)`` as ONE
+    dispatch: the Pallas split kernel streams each weight tile once for
+    both passes; the jnp path is the two-pass oracle."""
+    if resolve_pallas(use_pallas):
         return _column_parallel(
             functools.partial(analog_mvm_split_pallas, chunk_rows=chunk_rows,
                               faithful=faithful, interpret=_interpret()),
             (a_pos, a_neg), w_eff, gain, chunk_offset,
             a_pos.shape[1] // chunk_rows,
         )
-    # fused jnp path, faithful: stream the chunks through a scan that
-    # shares each weight chunk between the pos/neg passes and subtracts
-    # their integer ADC codes in place (bit-exact vs the two-pass
-    # oracle; see _mvm_split_chunk_scan).  Fast mode sums pre-round
-    # reals - accumulation order matters at the ulp there - and keeps
-    # the oracle's stacked [2M, K] chunked matmul.
-    if faithful:
-        return _mvm_split_chunk_scan(a_pos, a_neg, w_eff, gain,
-                                     chunk_offset, chunk_rows)
-    m = a_pos.shape[0]
-    y2 = ref_lib.analog_mvm_ref(
-        jnp.concatenate([a_pos, a_neg], axis=0), w_eff, gain, chunk_offset,
+    return ref_lib.analog_mvm_split_ref(
+        a_pos, a_neg, w_eff, gain, chunk_offset,
         chunk_rows=chunk_rows, faithful=faithful,
     )
-    return y2[:m] - y2[m:]
 
 
 def expert_mvm(
@@ -248,8 +192,7 @@ def expert_mvm(
     (:func:`repro.kernels.analog_mvm.expert_mvm_pallas`); the jnp path
     runs every expert over every row and keeps each row's own - the
     oracle, for small shapes."""
-    use = _on_tpu() if use_pallas is None else use_pallas
-    if use:
+    if resolve_pallas(use_pallas):
         return expert_mvm_pallas(
             a_pos, a_neg, w_eff, gain, chunk_offset, tile_expert,
             live_tiles, chunk_rows=chunk_rows, faithful=faithful,
@@ -258,25 +201,21 @@ def expert_mvm(
     row_expert = jnp.repeat(tile_expert, block_m)
     y = jnp.zeros((a_pos.shape[0], w_eff.shape[-1]), jnp.float32)
     for e in range(w_eff.shape[0]):
-        if faithful:
-            ye = _mvm_split_chunk_scan(a_pos, a_neg, w_eff[e], gain[e],
-                                       chunk_offset[e], chunk_rows)
-        else:
-            ye = ref_lib.analog_mvm_split_ref(
-                a_pos, a_neg, w_eff[e], gain[e], chunk_offset[e],
-                chunk_rows=chunk_rows, faithful=faithful)
+        ye = ref_lib.analog_mvm_split_ref(
+            a_pos, a_neg, w_eff[e], gain[e], chunk_offset[e],
+            chunk_rows=chunk_rows, faithful=faithful)
         y = jnp.where((row_expert == e)[:, None], ye, y)
     return y
 
 
 def _analog_mvm_split_fwd(a_pos, a_neg, w_eff, gain, chunk_offset,
-                          chunk_rows, faithful, use_pallas, fused):
+                          chunk_rows, faithful, use_pallas):
     y = analog_mvm_split(a_pos, a_neg, w_eff, gain, chunk_offset,
-                         chunk_rows, faithful, use_pallas, fused)
+                         chunk_rows, faithful, use_pallas)
     return y, (a_pos, a_neg, w_eff, gain, chunk_offset)
 
 
-def _analog_mvm_split_bwd(chunk_rows, faithful, use_pallas, fused, res, g):
+def _analog_mvm_split_bwd(chunk_rows, faithful, use_pallas, res, g):
     # HIL linearization of the split pair: y ~= gain * ((a_pos - a_neg) @ w)
     # with frozen gain/offset calibration state.
     a_pos, a_neg, w_eff, gain, chunk_offset = res
@@ -289,49 +228,6 @@ def _analog_mvm_split_bwd(chunk_rows, faithful, use_pallas, fused, res, g):
 
 
 analog_mvm_split.defvjp(_analog_mvm_split_fwd, _analog_mvm_split_bwd)
-
-
-def analog_mvm_infer(
-    a_pos: jax.Array,
-    a_neg: Optional[jax.Array],
-    w_eff: jax.Array,
-    gain: jax.Array,
-    chunk_offset: Optional[jax.Array],
-    *,
-    chunk_rows: int = BSS2.signed_rows,
-    faithful: bool = True,
-    use_pallas: Optional[bool] = None,
-    epilogue=None,
-) -> jax.Array:
-    """Inference-only analog VMM with the ADC epilogue fused INTO the
-    kernel (plan executor hot path; no custom VJP - the differentiable
-    path applies the epilogue as elementwise STE ops instead, which is
-    bit-identical in value).  ``a_neg=None`` selects the unsigned
-    single-pass kernel, otherwise the fused signed-split kernel."""
-    use = _on_tpu() if use_pallas is None else use_pallas
-    if use:
-        kw = dict(chunk_rows=chunk_rows, faithful=faithful,
-                  epilogue=epilogue, interpret=_interpret())
-        kernel, acts = ((analog_mvm_pallas, (a_pos,)) if a_neg is None
-                        else (analog_mvm_split_pallas, (a_pos, a_neg)))
-        return _column_parallel(
-            functools.partial(kernel, **kw), acts, w_eff, gain,
-            chunk_offset, a_pos.shape[1] // chunk_rows,
-        )
-    if a_neg is None:
-        y = ref_lib.analog_mvm_ref(a_pos, w_eff, gain, chunk_offset,
-                                   chunk_rows=chunk_rows, faithful=faithful)
-    elif faithful:
-        y = _mvm_split_chunk_scan(a_pos, a_neg, w_eff, gain,
-                                  chunk_offset, chunk_rows)
-    else:
-        m = a_pos.shape[0]
-        y2 = ref_lib.analog_mvm_ref(
-            jnp.concatenate([a_pos, a_neg], axis=0), w_eff, gain,
-            chunk_offset, chunk_rows=chunk_rows, faithful=faithful,
-        )
-        y = y2[:m] - y2[m:]
-    return ref_lib.adc_epilogue_ref(y, epilogue)
 
 
 def analog_plan_codes(
@@ -376,8 +272,7 @@ def analog_plan_codes(
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
 def _plan_codes(x_in, w_cat, gain_all, off_cat, extras, schedule,
                 chunk_rows, faithful, use_pallas, block_b, block):
-    use = _on_tpu() if use_pallas is None else use_pallas
-    if use:
+    if resolve_pallas(use_pallas):
         b = x_in.shape[0] // schedule[0].m_mult
         # bounded ROWS per grid step (block_b * m_mult0), not batch
         # elements: keeps the x/scratch working set flat across chain
@@ -430,10 +325,9 @@ _plan_codes.defvjp(_plan_codes_fwd, _plan_codes_bwd)
 def maxmin_pool(x: jax.Array, window: int = 32,
                 use_pallas: Optional[bool] = None) -> jax.Array:
     """[..., T] -> [..., T/window] max-min pooling (preprocessing chain)."""
-    use = _on_tpu() if use_pallas is None else use_pallas
     shape = x.shape
     x2 = x.reshape(-1, shape[-1])
-    if use:
+    if resolve_pallas(use_pallas):
         y = maxmin_pool_2d_pallas(x2, window=window, interpret=_interpret())
     else:
         y = ref_lib.maxmin_pool_ref(x2, window=window)

@@ -1,6 +1,7 @@
-"""Pure-jnp oracles for the Pallas kernels.  Forward-only reference
-semantics; bit-identical to the hot paths in :mod:`repro.core.analog` and
-:mod:`repro.data.preprocess` (tested)."""
+"""Pure-jnp oracles for the Pallas kernels, forward-only reference
+semantics.  They are also the one jnp implementation of the analog VMM:
+:mod:`repro.kernels.ops` runs them wherever it does not run a kernel, and
+the interpreted kernels are tested against them bit for bit."""
 from __future__ import annotations
 
 from typing import Optional
@@ -73,19 +74,6 @@ def analog_mvm_split_ref(
     return yp - yn
 
 
-def adc_epilogue_ref(y_int: jax.Array, epilogue) -> jax.Array:
-    """Forward-only ADC epilogue oracle (paper §II-A): ReLU at the readout +
-    right-shift requantization onto 5-bit codes.  Matches the in-kernel
-    epilogue of :mod:`repro.kernels.analog_mvm` bit-exactly."""
-    if epilogue is None:
-        return y_int
-    kind, shift = epilogue
-    assert kind == "relu_shift", epilogue
-    y = jnp.maximum(y_int, 0.0)
-    y = jnp.floor(y / float(1 << shift))
-    return jnp.clip(y, 0.0, float(BSS2.a_max))
-
-
 def analog_plan_ref(
     x_in: jax.Array,             # [B * m_mult0, k0_pad] codes or floats
     w_cat: jax.Array,            # [sum(k_pad), n_max] packed weights
@@ -107,7 +95,7 @@ def analog_plan_ref(
     Gradient contract (HIL, paper §III-B): the saturating ADC is applied
     as a pure straight-through term (``v + sg(adc(v) - v)``), gain and
     offsets are frozen via ``stop_gradient`` - exactly the linearized
-    backward of ``core.analog._faithful_mm``.  Float-domain glue follows
+    backward of :func:`repro.kernels.ops.analog_mvm`.  Float-domain glue follows
     the per-layer executor's gradient semantics: in-kernel encoding uses
     the STE quantizer (:func:`repro.core.quant.quantize_act`), the
     ``"relu"`` hand-off uses ``jax.nn.relu`` (zero gradient at exactly-0

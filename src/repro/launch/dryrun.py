@@ -244,7 +244,7 @@ def main():
     ap.add_argument("--no-fsdp", action="store_true")
     ap.add_argument("--no-seq-sp", action="store_true")
     ap.add_argument("--moe-dispatch", default="shard_map",
-                    choices=["gspmd_ep", "replicated_buf", "shard_map"])
+                    choices=["gspmd_ep", "shard_map"])
     ap.add_argument("--optim-bf16", action="store_true")
     ap.add_argument("--signed", default="split",
                     choices=["split", "offset", "none"])

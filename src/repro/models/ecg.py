@@ -97,23 +97,20 @@ def ecg_module_spec(cfg: ECGConfig = ECGConfig(), *,
       (the pre-plan module-by-module semantics, bit-compatible).
     - "relu_shift": the hardware chain of paper §II-A - ReLU at the ADC +
       right-shift requantization to 5-bit codes, so the whole stack runs
-      in the code domain with no float glue (and, with
-      ``acfg.use_pallas`` + ``acfg.fused_epilogue``, the epilogue is
-      emitted inside the Pallas kernel).  The code-domain chain also
+      in the code domain with no float glue.  The code-domain chain also
       declares ``input_domain="codes"`` (the preprocessed 5-bit input
       activations feed the conv directly) and is therefore megakernel-
       eligible: the compiled model replays conv->fc1->fc2 as ONE analog
-      dispatch (``model.apply(x, megakernel="auto")``, the default) - the
-      paper's single-program inference.  The "none" float-glue chain
+      dispatch (``model.apply(x)``) - the paper's single-program
+      inference.  The "none" float-glue chain
       keeps the legacy float input treatment (re-quantized on entry).
     """
     from repro import api
 
-    def _apply(model, x, *, train: bool = False, key=None,
-               megakernel="auto"):
+    def _apply(model, x, *, train: bool = False, key=None):
         cols = _im2col(x, cfg.conv_taps, cfg.conv_stride)
         with jax.named_scope("ecg.analog_chain"):
-            out = model.run_stack(cols, key=key, megakernel=megakernel)
+            out = model.run_stack(cols, key=key)
         return _pool_class_copies(out, cfg, train)
 
     return api.ModuleSpec(

@@ -352,11 +352,7 @@ def moe_apply(params, x, *, acfg: AnalogConfig, top_k: int,
                 )
 
             buf = jax.vmap(scatter_one)(x, st_, se, pos_c, keep)
-            if dispatch == "replicated_buf":
-                # (refuted variant, kept for the §Perf log)
-                buf = constrain(buf, "batch", None, None, None)
-            else:
-                buf = constrain(buf, "batch", "expert", "capacity", None)
+            buf = constrain(buf, "batch", "expert", "capacity", None)
             ye = _expert_ffn(params, buf, act, acfg)      # [B, E, C, d]
             ye = constrain(ye, "batch", "expert", "capacity", None)
 

@@ -44,11 +44,11 @@ def _work(lp: LayerPlan, split: bool) -> LayerWork:
 
 
 def plan_layer_works(plan: AnalogPlan) -> list[LayerWork]:
-    """LayerWorks of one stack replay, mirroring the signed-input chain of
-    :meth:`AnalogPlan.expected_dispatches` — except that a split pair is
-    ALWAYS two physical passes: ``cfg.fused_split`` folds the pair into
-    one *dispatch*, but the hardware still drives both vectors.
-    Code-domain inputs (unsigned event codes) need no split pair."""
+    """LayerWorks of one stack replay, following the signed-input chain
+    of :func:`repro.exec.run.run_layers`.  A split pair is two physical
+    passes: the executor folds it into one *dispatch*, but the hardware
+    still drives both vectors.  Code-domain inputs (unsigned event codes)
+    need no split pair."""
     from repro.exec.plan import EPILOGUE_NONE, EPILOGUE_RELU_SHIFT
 
     works: list[LayerWork] = []

@@ -71,11 +71,11 @@ class ServeEngine:
         # over repro.exec) so the jitted prefill/decode steps replay the
         # baked plans instead of re-deriving them per forward.  Weight
         # updates (not a serve concern) would require model.relower().
-        # Plan replays default to megakernel="auto": any stack plan the
-        # engine serves that is a pure code-domain chain (eligibility in
-        # exec.lower.pack_megakernel) executes as ONE pallas_call with
-        # VMEM-resident inter-layer codes; LM tree plans (split-encoded
-        # float activations) keep the per-layer fused-split dispatch.
+        # Any stack plan the engine serves that is a pure code-domain
+        # chain (eligibility in exec.lower.pack_megakernel) replays as
+        # ONE pallas_call with VMEM-resident inter-layer codes; LM tree
+        # plans (split-encoded float activations) keep one signed-split
+        # dispatch per layer.
         # Calibration (ISSUE 4): `calibration` bakes a measured
         # CalibrationSnapshot instead of the oracle fixed pattern;
         # `drift_monitor` (repro.calib.DriftMonitor) is probed between

@@ -5,8 +5,7 @@ packing.
 
 Before ISSUE 7 this knowledge lived implicitly in
 :func:`repro.exec.lower.pack_megakernel` /
-:func:`repro.exec.lower.megakernel_ineligible_reason` (and a second copy
-in :meth:`repro.exec.plan.AnalogPlan.expected_dispatches`).  Both now
+:func:`repro.exec.lower.megakernel_ineligible_reason`.  Both now
 consume THIS table, and so do the static verifier rules
 (:mod:`repro.verify.invariants`) - eligibility logic exists exactly once.
 
@@ -16,7 +15,7 @@ without a cycle.
 """
 from __future__ import annotations
 
-from typing import List, Optional, Sequence
+from typing import List, Optional
 
 from repro.exec.plan import (
     EPILOGUE_NONE,
@@ -89,29 +88,6 @@ def handoff_tag(epilogue: str, is_last: bool) -> str:
     if is_last:
         return "raw"
     return "codes" if epilogue == EPILOGUE_RELU_SHIFT else "relu"
-
-
-def expected_dispatches(
-    input_domain: str,
-    epilogues: Sequence[str],
-    signed_inputs: Sequence[str],
-    *,
-    fused_split: bool,
-) -> int:
-    """Analog dispatches one layer-by-layer deterministic replay issues,
-    derived from the transition table alone: one per layer, plus a second
-    pass for float-consuming signed-split layers without the fused-split
-    kernel (codes-consuming layers are never re-encoded, so their signed
-    mode is moot)."""
-    n = 0
-    d = input_domain
-    last = len(epilogues) - 1
-    for i, (epi, signed) in enumerate(zip(epilogues, signed_inputs)):
-        eff = "none" if d == DOMAIN_CODES else signed
-        n += 2 if (eff == "split" and not fused_split) else 1
-        if i < last:
-            d = DOMAIN_AFTER.get((d, epi), DOMAIN_FLOAT)
-    return n
 
 
 def chain_ineligible_reason(plan: AnalogPlan) -> Optional[str]:

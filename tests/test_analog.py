@@ -58,6 +58,29 @@ class TestChunkSaturation:
         assert float(jnp.abs(y1 - y2).max()) <= 2.0
 
 
+class TestKernelDispatch:
+    @pytest.mark.parametrize("mode", ["analog_faithful", "analog_fast"])
+    def test_matches_oracle_without_readout_keys(self, mode):
+        """A call without readout noise is the kernels' oracle, bit for
+        bit: batch dims flatten, K pads to whole chunks."""
+        from repro.kernels import ref as R
+
+        k1, k2, k3 = jax.random.split(KEY, 3)
+        a = jnp.round(jax.random.uniform(k1, (2, 3, 200)) * 31)
+        w = jnp.round(jax.random.normal(k2, (200, 40)) * 20)
+        w = w * (1 + 0.02 * jax.random.normal(k3, (200, 40)))
+        off = jax.random.normal(k3, (2, 40))
+        gain = jnp.full((40,), 0.05)
+        cfg = AnalogConfig(mode=mode, noise=NOISELESS)
+        got = analog_matmul(a, w, gain, off, None, cfg)
+        a2 = jnp.pad(a.reshape(6, 200), ((0, 0), (0, 56)))
+        want = R.analog_mvm_ref(a2, jnp.pad(w, ((0, 56), (0, 0))), gain,
+                                off, faithful=mode == "analog_faithful")
+        assert got.shape == (2, 3, 40)
+        np.testing.assert_array_equal(np.asarray(got).reshape(6, 40),
+                                      np.asarray(want))
+
+
 class TestAnalogLinear:
     def test_tracks_digital_within_quant_error(self):
         p = _mk()

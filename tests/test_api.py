@@ -272,8 +272,8 @@ class TestMeshShardedPlans:
 
 
 class TestMegakernelKnob:
-    """CompiledModel.apply(..., megakernel=...) - the api surface of the
-    whole-plan megakernel (ISSUE 3)."""
+    """CompiledModel.apply - the api surface of the whole-plan
+    megakernel, against the layer-by-layer replay of the same plan."""
 
     def _model(self, acfg=None):
         cfg = ECG.ECGConfig()
@@ -294,15 +294,18 @@ class TestMegakernelKnob:
 
     def test_apply_knob_bit_exact_and_single_dispatch(self):
         model, x = self._model()
+        cfg = ECG.ECGConfig()
+        cols = ECG._im2col(x, cfg.conv_taps, cfg.conv_stride)
         reset_dispatch_count()
-        y_auto = model.apply(x)                       # default: "auto"
+        model.apply(x)
         assert dispatch_count() == 1                  # ONE analog program
         reset_dispatch_count()
-        y_off = model.apply(x, megakernel=False)
+        y_mk = model.run_stack(cols)
+        assert dispatch_count() == 1
+        reset_dispatch_count()
+        y_per = E.run_layers(model.lower(), cols)
         assert dispatch_count() == model.lower().expected_dispatches == 3
-        y_on = model.apply(x, megakernel=True)
-        np.testing.assert_array_equal(np.asarray(y_auto), np.asarray(y_off))
-        np.testing.assert_array_equal(np.asarray(y_auto), np.asarray(y_on))
+        np.testing.assert_array_equal(np.asarray(y_mk), np.asarray(y_per))
 
     def test_megakernel_runs_no_three_pass_dot(self):
         """The ECG chain's megakernel keeps its own in-kernel dots: a

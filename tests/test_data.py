@@ -139,6 +139,17 @@ class TestPreprocess:
         assert got.dtype == want.dtype and got.shape == want.shape
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
+    def test_default_flag_runs_jnp_chain_on_cpu(self):
+        """``use_pallas=None`` is the platform's choice: the jnp chain on
+        the CPU, bit for bit."""
+        raw = jnp.asarray(ramp_windows(3, 2, 4033, 32, 4, "fraction", 5))
+        assert jax.default_backend() == "cpu"
+        got = preprocess(raw)
+        want = preprocess(raw, use_pallas=False)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        np.testing.assert_array_equal(np.asarray(got),
+                                      fig7_codes(np.asarray(raw)))
+
     @pytest.mark.parametrize("use_pallas", [False, True])
     def test_call_moves_nothing_but_its_samples(self, use_pallas):
         """A call on samples already on the device transfers nothing

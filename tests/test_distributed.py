@@ -156,8 +156,7 @@ g = jnp.full((96,), 0.02)
 off = jax.random.normal(jax.random.fold_in(k, 3), (2, 96))
 def both(a_p, a_n, w, g, off):
     return (ops.analog_mvm_split(a_p, a_n, w, g, off, 128, True, True),
-            ops.analog_mvm_infer(a_p, None, w, g, off, use_pallas=True,
-                                 epilogue=("relu_shift", 3)))
+            ops.analog_mvm(a_p, w, g, off, 128, True, True))
 one = both(a_p, a_n, w, g, off)
 for shape in ((1, 4), (2, 2), (4, 1)):
     mesh = make_mesh(shape, ("data", "model"))

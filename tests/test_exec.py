@@ -1,6 +1,7 @@
-"""Tests for the exec subsystem (ISSUE 1): plan lowering/reuse, the fused
-signed-split kernel vs the two-pass oracle, the ADC epilogue fusion, and
-HIL gradient parity between the Pallas-dispatch and pure-jnp paths."""
+"""Tests for the exec subsystem: plan lowering/reuse, the fused
+signed-split kernel vs the two-pass oracle, megakernel routing, the ADC
+epilogue, and HIL gradient parity between the Pallas-dispatch and
+pure-jnp paths."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -21,6 +22,9 @@ from repro.models import ecg as ECG
 
 KEY = jax.random.PRNGKey(7)
 SPLIT_CFG = AnalogConfig(noise=NOISELESS, signed_input="split")
+# the noisy route on a noiseless chip: readout keys split each signed
+# pair into two independent passes that draw no noise
+SPLIT_TWO_PASS = SPLIT_CFG.replace(deterministic=False)
 
 
 def _mk(in_dim=256, out_dim=64, noise=NOISELESS, seed=0):
@@ -76,33 +80,23 @@ class TestFusedSplitKernel:
         assert float(jnp.abs(got - want).max()) <= 2.0 * (256 // 128)
 
     def test_fused_jnp_path_bit_exact(self):
-        """The stacked-batch jnp fusion equals the two-pass oracle too."""
+        """The split wrapper's jnp path is the two-pass oracle."""
         a_pos, a_neg, w, gain, off = _split_inputs(16, 256, 96)
         got = ops.analog_mvm_split(a_pos, a_neg, w, gain, off,
-                                   128, True, False, True)
-        want = ops.analog_mvm_split(a_pos, a_neg, w, gain, off,
-                                    128, True, False, False)
+                                   128, True, False)
+        want = R.analog_mvm_split_ref(a_pos, a_neg, w, gain, off,
+                                      chunk_rows=128, faithful=True)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
     def test_module_level_fused_matches_two_pass(self):
+        """One split dispatch == the two independent passes the noisy
+        route issues, when the passes draw no noise."""
         p = _mk()
         x = jax.random.normal(KEY, (8, 256)) * 0.2
         y_fused = apply_linear(p, x, SPLIT_CFG)
-        y_two = apply_linear(p, x, SPLIT_CFG.replace(
-            fused_split=False))
+        y_two = apply_linear(p, x, SPLIT_TWO_PASS, key=KEY)
         np.testing.assert_array_equal(np.asarray(y_fused),
                                       np.asarray(y_two))
-
-    def test_epilogue_in_kernel_matches_reference(self):
-        a_pos, a_neg, w, gain, off = _split_inputs(8, 256, 64)
-        epi = ("relu_shift", 3)
-        got = analog_mvm_split_pallas(a_pos, a_neg, w, gain, off,
-                                      interpret=True, epilogue=epi)
-        want = R.adc_epilogue_ref(
-            R.analog_mvm_split_ref(a_pos, a_neg, w, gain, off), epi
-        )
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-        assert float(got.min()) >= 0.0 and float(got.max()) <= 31.0
 
 
 class TestAnalogPlan:
@@ -237,20 +231,20 @@ class TestAnalogPlan:
 
 
 class TestDispatchCounts:
-    def test_fused_split_halves_dispatches(self):
+    def test_split_pair_is_one_dispatch(self):
         p = _mk()
         x = jax.random.normal(KEY, (8, 256)) * 0.2
         reset_dispatch_count()
         apply_linear(p, x, SPLIT_CFG)
         fused = dispatch_count()
         reset_dispatch_count()
-        apply_linear(p, x, SPLIT_CFG.replace(fused_split=False))
+        apply_linear(p, x, SPLIT_TWO_PASS, key=KEY)
         two_pass = dispatch_count()
         assert (fused, two_pass) == (1, 2)
 
     def test_ecg_split_stack_halves_dispatches(self):
         """ECG-shaped 3-layer stack in split encoding: plan executor = 3
-        fused dispatches, per-call two-pass path = 6."""
+        fused dispatches, the noisy two-pass route = 6."""
         cfg = ECG.ECGConfig(noise=NOISELESS)
         params = ECG.ecg_init(jax.random.PRNGKey(0), cfg)
         x = jnp.round(
@@ -264,15 +258,14 @@ class TestDispatchCounts:
         reset_dispatch_count()
         E.run(plan, cols)
         fused = dispatch_count()
-        plan2 = lower_stack(stack, SPLIT_CFG.replace(fused_split=False))
+        plan2 = lower_stack(stack, SPLIT_TWO_PASS)
         reset_dispatch_count()
-        E.run(plan2, cols)
+        E.run(plan2, cols, key=KEY)
         two_pass = dispatch_count()
         assert fused * 2 == two_pass
         assert fused == 3
-        # the static plan metadata agrees with the traced counts
-        assert plan.expected_dispatches == 3
-        assert plan2.expected_dispatches == 6
+        # the static plan metadata (deterministic replay) agrees
+        assert plan.expected_dispatches == plan2.expected_dispatches == 3
 
     def test_cached_jit_replay_counts_zero_but_plan_knows(self):
         """The ANALOG_DISPATCHES counter bumps at TRACE time only: a
@@ -309,31 +302,6 @@ class TestECGPlanExecutor:
         np.testing.assert_array_equal(np.asarray(y_plan),
                                       np.asarray(y_mod))
 
-    def test_adc_chain_runs_in_code_domain(self):
-        """relu_shift lowering: inter-layer activations are 5-bit codes;
-        in-kernel fused epilogue == elementwise STE epilogue bit-exact."""
-        cfg = ECG.ECGConfig()
-        params = ECG.ecg_init(jax.random.PRNGKey(0), cfg)
-        x = jnp.round(
-            jax.random.uniform(jax.random.PRNGKey(1), (4, 2, 126)) * 31
-        )
-        from repro import api
-
-        acfg = AnalogConfig()
-        spec = ECG.ecg_module_spec(cfg, epilogue="relu_shift")
-        plan_ste = api.compile(
-            spec, params, acfg.replace(use_pallas=True)
-        ).lower()
-        plan_fused = api.compile(
-            spec, params, acfg.replace(use_pallas=True, fused_epilogue=True)
-        ).lower()
-        y_ste = ECG.ecg_apply_plan(plan_ste, x, cfg)
-        y_fused = ECG.ecg_apply_plan(plan_fused, x, cfg)
-        np.testing.assert_array_equal(np.asarray(y_ste),
-                                      np.asarray(y_fused))
-        # the classifier still separates something (not all-equal logits)
-        assert float(jnp.abs(y_ste).max()) > 0.0
-
 
 def _ecg_code_plan(acfg, seed=0):
     cfg = ECG.ECGConfig()
@@ -352,37 +320,38 @@ def _ecg_code_plan(acfg, seed=0):
 
 
 class TestMegakernel:
-    """The whole-plan megakernel (ISSUE 3): one dispatch per code-domain
-    stack, bit-exact vs the layer-by-layer replay."""
+    """The whole-plan megakernel: one dispatch per code-domain stack,
+    bit-exact vs the layer-by-layer replay (:func:`repro.exec.run_layers`)."""
 
     @pytest.mark.parametrize("acfg", [
         AnalogConfig(),                                 # jnp chain
         AnalogConfig(mode="analog_fast"),
         AnalogConfig(use_pallas=True),                  # Pallas interpret
-        AnalogConfig(use_pallas=True, fused_epilogue=True),
-    ], ids=["jnp", "jnp_fast", "pallas", "pallas_fused_epi"])
+    ], ids=["jnp", "jnp_fast", "pallas"])
     def test_bit_exact_vs_per_layer_ecg_chain(self, acfg):
         """Acceptance bar: the ECG conv->fc1->fc2 chain through ONE
         kernel equals the layer-by-layer plan replay bit for bit (fp32,
         interpret mode on the Pallas path), fpn noise on."""
         plan, cols, _ = _ecg_code_plan(acfg)
         assert plan.mega is not None
-        y_per = E.run(plan, cols, megakernel=False)
-        y_mk = E.run(plan, cols, megakernel=True)
+        assert E.megakernel_fallback_reason(
+            plan, cols, acfg, None, True) is None
+        y_per = E.run_layers(plan, cols)
+        y_mk = E.run(plan, cols)
         np.testing.assert_array_equal(np.asarray(y_per), np.asarray(y_mk))
 
     def test_single_dispatch_and_expected_count(self):
         plan, cols, _ = _ecg_code_plan(AnalogConfig())
         reset_dispatch_count()
-        E.run(plan, cols, megakernel=False)
+        E.run_layers(plan, cols)
         assert dispatch_count() == plan.expected_dispatches == 3
         reset_dispatch_count()
-        E.run(plan, cols, megakernel=True)
+        E.run(plan, cols)
         assert dispatch_count() == 1
 
     def test_auto_routes_code_chain_through_megakernel(self):
-        """The default megakernel='auto' takes the single-dispatch route
-        for an eligible plan and falls back for a float-glue plan."""
+        """``run`` takes the single-dispatch route for an eligible plan
+        and falls back for a float-glue plan."""
         plan, cols, params = _ecg_code_plan(AnalogConfig())
         reset_dispatch_count()
         E.run(plan, cols)
@@ -406,22 +375,28 @@ class TestMegakernel:
             [params["conv"], params["fc1"], params["fc2"]], AnalogConfig(),
             flatten_outs=[True, False, False],
         )
-        with pytest.raises(ValueError, match="megakernel=True"):
-            E.run(plan_f, cols, megakernel=True)
+        acfg = AnalogConfig()
+        reason = E.megakernel_fallback_reason(plan_f, cols, acfg, None,
+                                              False)
+        assert reason == E.megakernel_ineligible_reason(plan_f)
+        assert reason.startswith("layer 0 (consumes 'float'")
         # shape mismatch: flatten expects the position axis
-        with pytest.raises(ValueError, match="megakernel=True"):
-            E.run(plan, cols.reshape(-1, cols.shape[-1]), megakernel=True)
+        reason = E.megakernel_fallback_reason(
+            plan, cols.reshape(-1, cols.shape[-1]), acfg, None, True)
+        assert reason.startswith("flatten layer expects a trailing batch "
+                                 "dim of")
 
     def test_noisy_replay_falls_back(self):
         """Readout-noise replay (key given, deterministic off) keeps the
-        layer-by-layer path under 'auto' and raises under True."""
-        plan, cols, _ = _ecg_code_plan(AnalogConfig(deterministic=False))
+        layer-by-layer path and says why."""
+        acfg = AnalogConfig(deterministic=False)
+        plan, cols, _ = _ecg_code_plan(acfg)
         key = jax.random.PRNGKey(3)
         reset_dispatch_count()
         E.run(plan, cols, key=key)
         assert dispatch_count() == plan.expected_dispatches
-        with pytest.raises(ValueError, match="noisy"):
-            E.run(plan, cols, key=key, megakernel=True)
+        reason = E.megakernel_fallback_reason(plan, cols, acfg, key, True)
+        assert reason == "noisy replay (readout-noise keys) is layer-by-layer"
 
     @pytest.mark.parametrize("use_pallas", [False, True])
     def test_hil_gradients_match_per_layer(self, use_pallas):
@@ -434,15 +409,15 @@ class TestMegakernel:
         _, cols, params = _ecg_code_plan(acfg)
         stack = [params["conv"], params["fc1"], params["fc2"]]
 
-        def loss(ps, mk):
+        def loss(ps, replay):
             plan = lower_stack(
                 ps, acfg, epilogues=["relu_shift", "relu_shift", "none"],
                 flatten_outs=[True, False, False], input_domain="codes",
             )
-            return (E.run(plan, cols, megakernel=mk) ** 2).mean()
+            return (replay(plan, cols) ** 2).mean()
 
-        g_per = jax.grad(loss)(stack, False)
-        g_mk = jax.grad(loss)(stack, True)
+        g_per = jax.grad(loss)(stack, E.run_layers)
+        g_mk = jax.grad(loss)(stack, E.run)
         for i, (gp, gm) in enumerate(zip(g_per, g_mk)):
             # Each dW entry sums the STE backward over the batch rows, and
             # the two routes sum in different orders (per-layer custom-VJP
@@ -477,14 +452,14 @@ class TestMegakernel:
         @jax.jit
         def f(plan, x):
             traces.append(1)
-            return E.run(plan, x, megakernel=True)
+            return E.run(plan, x)
 
         y1 = f(plan, cols)
         y2 = f(plan, cols)
         assert len(traces) == 1
         np.testing.assert_array_equal(np.asarray(y1), np.asarray(y2))
         np.testing.assert_array_equal(
-            np.asarray(y1), np.asarray(E.run(plan, cols, megakernel=False))
+            np.asarray(y1), np.asarray(E.run_layers(plan, cols))
         )
 
     def test_flatten_factor_one_consumes_position_dim(self):
@@ -503,29 +478,17 @@ class TestMegakernel:
         assert plan.mega is not None
         assert plan.mega.schedule[0].flatten == 1
         x = jnp.round(jax.random.uniform(KEY, (5, 1, 128)) * 31)
-        y_per = E.run(plan, x, megakernel=False)
-        y_mk = E.run(plan, x)                     # default "auto" routes
+        assert E.megakernel_fallback_reason(
+            plan, x, plan.cfg, None, True) is None
+        y_per = E.run_layers(plan, x)
+        y_mk = E.run(plan, x)
         assert y_per.shape == y_mk.shape == (5, 32)
         np.testing.assert_array_equal(np.asarray(y_per), np.asarray(y_mk))
         # without the position axis the shapes cannot feed the flatten
-        with pytest.raises(ValueError, match="trailing batch dim"):
-            E.run(plan, jnp.round(jax.random.uniform(KEY, (5, 128)) * 31),
-                  megakernel=True)
-
-    def test_digital_compile_rejects_forced_megakernel(self):
-        """megakernel=True must raise in digital mode too (no analog plan
-        exists), not silently run the reference path."""
-        from repro import api
-
-        p = {"a": _mk(seed=1, out_dim=256), "b": _mk(seed=2)}
-        spec = api.ModuleSpec(name="2fc", kind="stack", layers=(
-            api.LayerSpec("a", 256, 256), api.LayerSpec("b", 256, 64),
-        ))
-        model = api.compile(spec, p, AnalogConfig(mode="digital"))
-        x = jax.random.normal(KEY, (4, 256)) * 0.2
-        model.apply(x, megakernel=False)          # reference path fine
-        with pytest.raises(ValueError, match="megakernel=True"):
-            model.apply(x, megakernel=True)
+        reason = E.megakernel_fallback_reason(
+            plan, jnp.round(jax.random.uniform(KEY, (5, 128)) * 31),
+            plan.cfg, None, True)
+        assert "trailing batch dim" in reason
 
     def test_uniform_chain_and_batch_shapes(self):
         """Megakernel on a flatten-free chain: unbatched and multi-dim
@@ -540,14 +503,16 @@ class TestMegakernel:
             input_domain="codes",
         )
         x = jnp.round(jax.random.uniform(KEY, (2, 3, 256)) * 31)
-        y = E.run(plan, x, megakernel=False)
+        y = E.run_layers(plan, x)
         assert y.shape == (2, 3, 256)
+        for xi in (x, x[0, 0]):                        # unbatched [K] too
+            assert E.megakernel_fallback_reason(
+                plan, xi, plan.cfg, None, True) is None
         np.testing.assert_array_equal(
-            np.asarray(E.run(plan, x, megakernel=True)), np.asarray(y)
+            np.asarray(E.run(plan, x)), np.asarray(y)
         )
-        np.testing.assert_array_equal(                 # unbatched [K]
-            np.asarray(E.run(plan, x[0, 0], megakernel=True)),
-            np.asarray(y[0, 0]),
+        np.testing.assert_array_equal(
+            np.asarray(E.run(plan, x[0, 0])), np.asarray(y[0, 0]),
         )
 
 
@@ -620,23 +585,18 @@ class TestFlattenOut:
 
 class TestEpiloguePinning:
     def test_ste_epilogue_matches_in_kernel_and_ref(self):
-        """The three ADC-epilogue implementations (elementwise STE, the
-        in-kernel Pallas epilogue, the jnp oracle) are pinned to the same
-        floor-shift semantics - including the negative-code edge, where
-        the ReLU must clamp BEFORE the shift (a float divide of a
-        negative code would round toward zero, not floor)."""
+        """The per-layer ADC epilogue (elementwise STE) is pinned to the
+        floor-shift semantics the megakernel's code hand-off implements
+        (bit-exactness of the two routes is tested above) - including
+        the negative-code edge, where the ReLU must clamp BEFORE the
+        shift (a float divide of a negative code would round toward
+        zero, not floor)."""
         from repro.exec.run import _epilogue_ste
-        from repro.kernels.analog_mvm import _apply_epilogue
 
         y = jnp.asarray([-300.0, -17.0, -1.0, 0.0, 1.0, 7.0, 8.0, 9.0,
                          63.0, 64.0, 255.0, 256.0, 1000.0])
         for shift in (0, 1, 3, 5):
-            epi = ("relu_shift", shift)
             a = np.asarray(_epilogue_ste(y, shift))
-            b = np.asarray(_apply_epilogue(y, epi))
-            c = np.asarray(R.adc_epilogue_ref(y, epi))
-            np.testing.assert_array_equal(a, b)
-            np.testing.assert_array_equal(a, c)
             # floor-shift: 5-bit codes, negatives clamp to 0
             want = np.clip(np.floor(np.maximum(np.asarray(y), 0.0)
                                     / (1 << shift)), 0.0, 31.0)
@@ -698,14 +658,11 @@ class TestHILGradientParity:
         p = _mk()
         x = jax.random.normal(KEY, (16, 256)) * 0.3
 
-        def loss(params, fused):
-            y = apply_linear(
-                params, x, SPLIT_CFG.replace(fused_split=fused)
-            )
-            return (y ** 2).mean()
+        def loss(params, cfg, key):
+            return (apply_linear(params, x, cfg, key=key) ** 2).mean()
 
-        g_fused = jax.grad(loss)(p, True)
-        g_two = jax.grad(loss)(p, False)
+        g_fused = jax.grad(loss)(p, SPLIT_CFG, None)
+        g_two = jax.grad(loss)(p, SPLIT_TWO_PASS, KEY)
         np.testing.assert_allclose(
             np.asarray(g_fused["w"]), np.asarray(g_two["w"]),
             rtol=1e-5, atol=1e-7,

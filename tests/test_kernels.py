@@ -311,6 +311,25 @@ class TestAnalogPlanMegakernel:
         np.testing.assert_array_equal(y, np.round(y))
 
 
+class TestImplementationChoice:
+    """``kernels.ops.resolve_pallas`` is the one place that picks a Pallas
+    kernel or the jnp oracle: ``None`` is the platform's choice."""
+
+    @pytest.mark.parametrize("use_pallas,backend,want", [
+        (None, "cpu", False), (None, "tpu", True),
+        (True, "cpu", True), (False, "tpu", False),
+    ])
+    def test_resolve(self, monkeypatch, use_pallas, backend, want):
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        assert ops.resolve_pallas(use_pallas) is want
+
+    def test_default_runs_oracle_on_cpu(self):
+        a, w, gain, off = _mvm_inputs(8, 256, 64)
+        got = ops.analog_mvm(a, w, gain, off)
+        want = R.analog_mvm_ref(a, w, gain, off)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 class TestMaxMinPoolKernel:
     @pytest.mark.parametrize("b,t,window", [(1, 128, 32), (5, 4096, 32),
                                             (16, 1024, 16), (3, 96, 32)])

@@ -3,7 +3,6 @@ fused attention+MLP block replay as ONE ``pallas_call``, bit-exact against
 the per-layer executor, with HIL gradients flowing through the per-layer
 reference chain and drift hot-swaps keeping the compiled executable."""
 import functools
-import importlib
 
 import jax
 import jax.numpy as jnp
@@ -16,10 +15,6 @@ from repro.core.analog import AnalogConfig, analog_linear_init
 from repro.core.noise import NOISELESS
 from repro.exec.run import dispatch_count, reset_dispatch_count
 from repro.models import transformer as T
-
-# the run module object (``repro.exec.run`` the MODULE is shadowed by the
-# ``run`` function re-exported at the package level)
-RUN = importlib.import_module("repro.exec.run")
 
 KEY = jax.random.PRNGKey(7)
 
@@ -85,8 +80,9 @@ def test_mixed_chain_megakernel_bitexact(faithful, use_pallas):
     plan = _mixed_stack(_acfg(faithful, use_pallas))
     assert E.megakernel_ineligible_reason(plan) is None
     x = _codes(5, 32)
-    y_mega = E.run(plan, x, megakernel=True)
-    y_ref = E.run(plan, x, megakernel=False)
+    assert E.megakernel_fallback_reason(plan, x, plan.cfg, None, True) is None
+    y_mega = E.run(plan, x)
+    y_ref = E.run_layers(plan, x)
     assert y_mega.shape == y_ref.shape
     assert jnp.array_equal(y_mega, y_ref)
 
@@ -95,13 +91,13 @@ def test_mixed_chain_gradient_parity():
     plan = _mixed_stack(_acfg())
     x = _codes(4, 32) / 31.0  # keep the loss surface smooth-ish
 
-    def loss(x, mk):
+    def loss(x, replay):
         # codes-domain entry expects integer codes; re-scale inside so the
         # grad w.r.t. the float input is well-defined through the STE chain
-        return (E.run(plan, jnp.round(x * 31), megakernel=mk) ** 2).mean()
+        return (replay(plan, jnp.round(x * 31)) ** 2).mean()
 
-    g_m = jax.grad(lambda x: loss(x, True))(x)
-    g_f = jax.grad(lambda x: loss(x, False))(x)
+    g_m = jax.grad(lambda x: loss(x, E.run))(x)
+    g_f = jax.grad(lambda x: loss(x, E.run_layers))(x)
     assert jnp.allclose(g_m, g_f, atol=1e-6)
 
 
@@ -111,8 +107,8 @@ def test_mixed_chain_gradient_parity():
 def test_block_megakernel_bitexact(faithful, use_pallas):
     plan = _block_plan(_acfg(faithful, use_pallas))
     x = _block_x()
-    y_mega = E.run(plan, x, megakernel=True)
-    y_fall = E.run(plan, x, megakernel=False)
+    y_mega = E.run(plan, x)
+    y_fall = E.run_layers(plan, x)
     assert y_mega.shape == x.shape
     assert jnp.array_equal(y_mega, y_fall)
 
@@ -123,22 +119,22 @@ def test_block_single_dispatch():
     assert plan.expected_dispatches == 1
     x = _block_x(b=2)
     reset_dispatch_count()
-    E.run(plan, x, megakernel=True)
+    E.run(plan, x)
     assert dispatch_count() == 1        # ONE pallas_call for the block
     reset_dispatch_count()
-    E.run(plan, x, megakernel=False)
-    assert dispatch_count() == 4        # per-layer fallback: qkv/o/ug/down
+    E.run_layers(plan, x)
+    assert dispatch_count() == 4        # per-layer replay: qkv/o/ug/down
 
 
 def test_block_hil_gradient_parity():
     plan = _block_plan(_acfg())
     x = _block_x(b=2)
 
-    def loss(x, mk):
-        return (E.run(plan, x, megakernel=mk) ** 2).mean()
+    def loss(x, replay):
+        return (replay(plan, x) ** 2).mean()
 
-    g_m = jax.grad(lambda x: loss(x, True))(x)
-    g_f = jax.grad(lambda x: loss(x, False))(x)
+    g_m = jax.grad(lambda x: loss(x, E.run))(x)
+    g_f = jax.grad(lambda x: loss(x, E.run_layers))(x)
     assert float(jnp.linalg.norm(g_m)) > 0.0
     assert jnp.allclose(g_m, g_f, atol=1e-6)
 
@@ -166,7 +162,7 @@ def test_block_drift_hot_swap_keeps_executable():
 
     @jax.jit
     def f(pl, x):
-        return E.run(pl, x, megakernel=True)
+        return E.run(pl, x)
 
     x = _block_x(b=2)
     y1 = f(plan, x)
@@ -190,25 +186,9 @@ def test_ineligible_reason_names_layer_and_domain():
     assert "layer 0" in reason
     assert "'float'" in reason and "'none'" in reason
     assert "act_calib" in reason
-    with pytest.raises(ValueError, match="megakernel=True, but: layer 0"):
-        E.run(bad, jax.random.normal(KEY, (4, 16)), megakernel=True)
-
-
-def test_small_batch_threshold_routes_per_layer(monkeypatch):
-    plan = _mixed_stack(_acfg())
-    x = _codes(2, 32)
-    monkeypatch.setattr(RUN, "MEGAKERNEL_MIN_ROWS", 64)
-    reason = E.megakernel_fallback_reason(
-        plan, x, plan.cfg, None, True
-    )
-    assert reason is not None and "MEGAKERNEL_MIN_ROWS" in reason
-    reset_dispatch_count()
-    y_auto = E.run(plan, x)                       # auto -> per-layer replay
-    assert dispatch_count() == len(plan.layers)
-    reset_dispatch_count()
-    y_mega = E.run(plan, x, megakernel=True)      # True overrides threshold
-    assert dispatch_count() == 1
-    assert jnp.array_equal(y_auto, y_mega)        # no silent regression
+    x = jax.random.normal(KEY, (4, 16))
+    assert E.megakernel_fallback_reason(bad, x, bad.cfg, None,
+                                        False) == reason
 
 
 # --------------------------------------------------------------------- api
@@ -220,7 +200,7 @@ def test_compile_block_applies_and_lowers():
     )
     x = _block_x(b=2)
     y = m.apply(x)
-    assert jnp.array_equal(y, m.apply(x, megakernel=False))
+    assert jnp.array_equal(y, E.run_layers(m.lower(), x))
     plan = m.lower()
     assert plan.block is not None and plan.expected_dispatches == 1
     # relower round-trips through the BLOCK compile branch

@@ -34,6 +34,9 @@ ARCH = ArchConfig(name="t-store", family="dense", n_layers=2, d_model=64,
                   n_heads=2, n_kv_heads=2, d_ff=96, vocab_size=64,
                   remat=False)
 SEQ = 8
+# the plan's two replays: the megakernel route and the per-layer one
+REPLAYS = pytest.mark.parametrize(
+    "replay", [E.run, E.run_layers], ids=["run", "run_layers"])
 
 
 def _cfg(mode, pallas, **kw):
@@ -73,7 +76,8 @@ def _codes(b, k, seed=9):
 
 # ------------------------------------------------------------ stack plans
 class TestStackRoundTrip:
-    def test_stack_roundtrip_bit_exact(self, tmp_path):
+    @REPLAYS
+    def test_stack_roundtrip_bit_exact(self, tmp_path, replay):
         acfg = AnalogConfig(mode="analog_faithful", act_calib="static",
                             noise=NoiseConfig())
         plan = _mixed_stack(acfg)
@@ -89,11 +93,9 @@ class TestStackRoundTrip:
         _assert_tree_bitexact(loaded, plan)
 
         x = _codes(5, 32)
-        for mk in (True, False):
-            np.testing.assert_array_equal(
-                np.asarray(E.run(loaded, x, megakernel=mk)),
-                np.asarray(E.run(plan, x, megakernel=mk)),
-            )
+        np.testing.assert_array_equal(
+            np.asarray(replay(loaded, x)), np.asarray(replay(plan, x)),
+        )
 
     def test_codes_stay_int8_on_disk(self, tmp_path):
         plan = _mixed_stack(AnalogConfig(act_calib="static",
@@ -179,7 +181,8 @@ class TestTreeRoundTrip:
 
 # ------------------------------------------------------------- block plans
 class TestBlockRoundTrip:
-    def test_block_roundtrip_megakernel_replay(self, tmp_path):
+    @REPLAYS
+    def test_block_roundtrip_megakernel_replay(self, tmp_path, replay):
         acfg = AnalogConfig(mode="analog_faithful", act_calib="static")
         plan = E.lower_block(
             T._layer_init(jax.random.PRNGKey(0), "attn_mlp", ARCH), acfg,
@@ -197,11 +200,9 @@ class TestBlockRoundTrip:
 
         x = jax.random.normal(jax.random.PRNGKey(1),
                               (3, SEQ, ARCH.d_model)) * 0.5
-        for mk in (True, False):
-            np.testing.assert_array_equal(
-                np.asarray(E.run(loaded, x, megakernel=mk)),
-                np.asarray(E.run(plan, x, megakernel=mk)),
-            )
+        np.testing.assert_array_equal(
+            np.asarray(replay(loaded, x)), np.asarray(replay(plan, x)),
+        )
 
 
 # ----------------------------------------------- hot-swaps AFTER the load
@@ -268,7 +269,8 @@ class TestPostLoadHotSwap:
 # -------------------------------------- packed == fp32 bake, every backend
 class TestPackedMatchesFp32Bake:
     @pytest.mark.parametrize("mode,pallas", MODES)
-    def test_dequant_on_load_matches_baked_fp32(self, mode, pallas):
+    @REPLAYS
+    def test_dequant_on_load_matches_baked_fp32(self, mode, pallas, replay):
         """Replace every packed store with a legacy-style one whose
         ``codes`` ARE the materialized fp32 ``w_eff`` (gain tables
         nulled): the executor must produce bitwise-identical outputs, so
@@ -290,11 +292,9 @@ class TestPackedMatchesFp32Bake:
         assert plan.layers[0].store.codes.dtype == jnp.int8
 
         x = _codes(5, 32)
-        for mk in (True, False):
-            np.testing.assert_array_equal(
-                np.asarray(E.run(plan, x, megakernel=mk)),
-                np.asarray(E.run(baked, x, megakernel=mk)),
-            )
+        np.testing.assert_array_equal(
+            np.asarray(replay(plan, x)), np.asarray(replay(baked, x)),
+        )
 
 
 # --------------------------------------------------- serve-side plan cache

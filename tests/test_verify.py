@@ -26,7 +26,6 @@ from repro.verify import (
     assert_no_retrace,
     captured_constants,
     check,
-    domains as dom,
     run_lint,
     verify_plan,
     verify_spec,
@@ -307,9 +306,8 @@ class TestDriftSwap:
 
     def test_static_metadata_change_flagged(self):
         plan = self._offset_plan()
-        other = dataclasses.replace(
-            plan, cfg=plan.cfg.replace(fused_split=not plan.cfg.fused_split)
-        )
+        cfg = plan.cfg.replace(gain_headroom=plan.cfg.gain_headroom + 1)
+        other = dataclasses.replace(plan, cfg=cfg)
         hits = verify_swap(plan, other)
         assert hits and "static metadata" in hits[0].message
 
@@ -619,19 +617,18 @@ class TestIneligibilityMessageParity:
 # the full product space.
 GRID = [
     {"n_layers": n, "epilogues": epis, "input_domain": ind,
-     "act_calib": ac, "signed": sg, "fused_split": fs}
-    for n, epis, ind, ac, sg, fs in [
-        (2, ["relu_shift", "none"], "codes", "static", "none", True),
-        (2, ["none", "none"], None, "static", "split", False),
+     "act_calib": ac, "signed": sg}
+    for n, epis, ind, ac, sg in [
+        (2, ["relu_shift", "none"], "codes", "static", "none"),
+        (2, ["none", "none"], None, "static", "split"),
         (3, ["relu_shift", "relu_shift", "none"], "codes", "dynamic",
-         "none", True),
-        (3, ["relu_shift", "none", "none"], "codes", "static", "split",
-         True),
-        (3, ["none", "relu_shift", "none"], None, "static", "none", True),
-        (2, ["relu_shift", "none"], None, "dynamic", "offset", False),
+         "none"),
+        (3, ["relu_shift", "none", "none"], "codes", "static", "split"),
+        (3, ["none", "relu_shift", "none"], None, "static", "none"),
+        (2, ["relu_shift", "none"], None, "dynamic", "offset"),
         (4, ["relu_shift", "relu_shift", "relu_shift", "none"], "codes",
-         "static", "offset", True),
-        (2, ["none", "none"], None, "dynamic", "split", True),
+         "static", "offset"),
+        (2, ["none", "none"], None, "dynamic", "split"),
     ]
 ]
 DIMS = (16, 24, 32, 48, 24)
@@ -641,7 +638,7 @@ def _build(cfg):
     n = cfg["n_layers"]
     acfg = AnalogConfig(
         noise=NOISELESS, act_calib=cfg["act_calib"],
-        signed_input=cfg["signed"], fused_split=cfg["fused_split"],
+        signed_input=cfg["signed"],
     )
     return _chain(dims=DIMS[: n + 1], epilogues=cfg["epilogues"][:n],
                   acfg=acfg, input_domain=cfg["input_domain"])
@@ -666,30 +663,23 @@ def _check_dispatches(cfg):
     else:
         x = jax.random.normal(jax.random.PRNGKey(3), (b, k0)) * 0.3
     reset_dispatch_count()
-    y = E.run(plan, x, megakernel=False)       # layer-by-layer replay
+    y = E.run_layers(plan, x)                  # layer-by-layer replay
     assert np.asarray(y).shape[0] == b
-    assert dispatch_count() == plan.expected_dispatches
-    # the domain-table recount agrees with the plan's own property
-    want = dom.expected_dispatches(
-        dom.DOMAIN_CODES if plan.expects_codes else dom.DOMAIN_FLOAT,
-        [lp.epilogue for lp in plan.layers],
-        [lp.signed_input for lp in plan.layers],
-        fused_split=plan.cfg.fused_split,
-    )
-    assert want == plan.expected_dispatches
+    # one dispatch a layer, whatever the domains and signed encodings
+    assert dispatch_count() == plan.expected_dispatches == len(plan.layers)
 
 
 class TestGridProperties:
     @pytest.mark.parametrize("cfg", GRID, ids=lambda c: (
         f"L{c['n_layers']}-{c['input_domain']}-{c['act_calib']}-"
-        f"{c['signed']}-fs{int(c['fused_split'])}"
+        f"{c['signed']}"
     ))
     def test_verdict_agrees_with_packing_and_verifier(self, cfg):
         _check_verdict(cfg)
 
     @pytest.mark.parametrize("cfg", GRID, ids=lambda c: (
         f"L{c['n_layers']}-{c['input_domain']}-{c['act_calib']}-"
-        f"{c['signed']}-fs{int(c['fused_split'])}"
+        f"{c['signed']}"
     ))
     def test_expected_dispatches_matches_actual(self, cfg):
         _check_dispatches(cfg)
@@ -717,7 +707,6 @@ if HAVE_HYPOTHESIS:
         "input_domain": st.sampled_from(["codes", None]),
         "act_calib": st.sampled_from(["static", "dynamic"]),
         "signed": st.sampled_from(["none", "split", "offset"]),
-        "fused_split": st.booleans(),
     })
 
     class TestHypothesisProperties:
